@@ -261,11 +261,14 @@ class Scenario(Observable):
             for i in np.flatnonzero(self.malicious):
                 y[i] = flip_labels(y[i], self.dataset.num_classes)
         tr = self.transport
+        # host arrays go to the mesh as they are: a jnp.asarray here
+        # would land every stacked array whole on the default device
+        # first (MeshTransport._place)
         self._data_args = tuple(
-            tr.put_stacked(jnp.asarray(a)) for a in (x, y, smask, nsamp)
+            tr.put_stacked(a) for a in (x, y, smask, nsamp)
         )
-        self._x_test = tr.put_replicated(jnp.asarray(self.dataset.x_test))
-        self._y_test = tr.put_replicated(jnp.asarray(self.dataset.y_test))
+        self._x_test = tr.put_replicated(self.dataset.x_test)
+        self._y_test = tr.put_replicated(self.dataset.y_test)
         self.sparse_transport = self._choose_sparse()
         # ONE wire-precision knob (config.wire_dtype) across planes:
         # on the SPMD plane the exchange is device math, so bf16 is the
@@ -530,9 +533,9 @@ class Scenario(Observable):
                 mix = mix * self._stale_scale[None, :]
             tr = self.transport
             return (
-                tr.put_stacked(jnp.asarray(mix)),
-                tr.put_stacked(jnp.asarray(plan.adopt)),
-                tr.put_stacked(jnp.asarray(trains)),
+                tr.put_stacked(mix),
+                tr.put_stacked(plan.adopt),
+                tr.put_stacked(trains),
             )
         key = (
             self.leader,
@@ -553,9 +556,9 @@ class Scenario(Observable):
                 mix = mix.astype(np.float32) * self._stale_scale[None, :]
             tr = self.transport
             self._plan_cache[key] = (
-                tr.put_stacked(jnp.asarray(mix)),
-                tr.put_stacked(jnp.asarray(plan.adopt)),
-                tr.put_stacked(jnp.asarray(trains)),
+                tr.put_stacked(mix),
+                tr.put_stacked(plan.adopt),
+                tr.put_stacked(trains),
             )
         else:
             self._plan_cache[key] = self._plan_cache.pop(key)  # LRU touch
@@ -660,7 +663,7 @@ class Scenario(Observable):
                 alive = self._advance_membership(r)
                 self._rotate_leader(alive)
                 self.fed = self.fed.replace(
-                    alive=self.transport.put_stacked(jnp.asarray(alive))
+                    alive=self.transport.put_stacked(alive)
                 )
                 trains_vote = self._voted_trains(alive, r)
                 with tracer.span("scenario.round", args={"round": r}):
@@ -898,10 +901,8 @@ class CrossDeviceScenario(Observable):
         self.crossdev_last: dict[str, Any] = {}
         self.devprof_last: dict[str, Any] = {}
         self._devprof_flops: float | None | bool = False
-        self._x_test = self.transport.put_replicated(
-            jnp.asarray(self.data.x_test))
-        self._y_test = self.transport.put_replicated(
-            jnp.asarray(self.data.y_test))
+        self._x_test = self.transport.put_replicated(self.data.x_test)
+        self._y_test = self.transport.put_replicated(self.data.y_test)
         # test introspection: the last round's draw and its liveness
         self.last_sampled: np.ndarray | None = None
         self.last_cohorts: np.ndarray | None = None
@@ -943,7 +944,7 @@ class CrossDeviceScenario(Observable):
         sizes = data.cohort_sizes(cohorts)
         wn, got_any = self._wn_fn(jnp.asarray(sizes),
                                   jnp.asarray(c_alive))
-        alive_dev = self.transport.put_replicated(jnp.asarray(c_alive))
+        alive_dev = self.transport.put_replicated(c_alive)
         prefetch_bytes = 0
         stall_s = 0.0
         sh = self.transport.replicated
@@ -977,9 +978,17 @@ class CrossDeviceScenario(Observable):
             # so the next gather below overlaps this step's compute
             carry, loss_t = self._stream_step(
                 params0, carry, x_t, y_t, m_t, alive_dev[t], wn[t])
-            if t + 1 < c:
-                buf = gather_put(t + 1)
             losses.append(loss_t)
+            if t + 1 < c:
+                if t >= 1:
+                    # the buffer about to be refilled was read by step
+                    # t-1, and the CPU backend's device_put ALIASES a
+                    # 64-byte-aligned numpy buffer instead of copying
+                    # it: that step must have finished, not merely its
+                    # transfer, or it trains on half-rewritten data
+                    # (seen as a ~1-in-3 parity failure on cold caches)
+                    jax.block_until_ready(losses[t - 1])
+                buf = gather_put(t + 1)
         self.fed = self._stream_finalize(self.fed, carry, got_any)
         self.crossdev_last["crossdev_prefetch_mb"] = round(
             prefetch_bytes / 1e6, 2)
@@ -1056,10 +1065,9 @@ class CrossDeviceScenario(Observable):
                 # slot axis — replicate; the per-slot split happens
                 # inside the compiled round
                 args = tuple(
-                    tr.put_replicated(jnp.asarray(a.reshape(
-                        shape2 + a.shape[1:])))
+                    tr.put_replicated(a.reshape(shape2 + a.shape[1:]))
                     for a in (x, y, mask, sizes)
-                ) + (tr.put_replicated(jnp.asarray(c_alive)),)
+                ) + (tr.put_replicated(c_alive),)
                 self.fed, metrics = self._round_fn(self.fed, *args)
             jax.block_until_ready(self.fed.states.params)
             dt = time.monotonic() - t0

@@ -423,6 +423,9 @@ class SharedTrainer:
     def __init__(self, model, objective="classification", optimizer="sgd",
                  learning_rate=0.1, momentum=0.9, weight_decay=0.0,
                  momentum_dtype=None, batch_size=32):
+        # socket-plane learners run one node per program: the kernel
+        # gate must measure that width, not the last federation's vmap
+        pallas_gemm.set_nodes_hint(1)
         self.fns = make_step_fns(
             model, objective=objective, optimizer=optimizer,
             learning_rate=learning_rate, momentum=momentum,
@@ -490,6 +493,7 @@ class JaxLearner(NodeLearner):
             self._eval_jit = self._shared.eval_jit
             self._init_jit = self._shared.init_jit
             return
+        pallas_gemm.set_nodes_hint(1)  # as SharedTrainer: one node wide
         self.fns = make_step_fns(
             self.model, objective=self.objective,
             optimizer=self.optimizer_name, learning_rate=self.learning_rate,

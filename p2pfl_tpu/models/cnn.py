@@ -9,8 +9,6 @@ bfloat16 compute.
 
 from __future__ import annotations
 
-import math
-
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -27,7 +25,8 @@ from p2pfl_tpu.ops import pallas_gemm
 #: form at n=64, b=224 — scripts/exp_op_breakdown.py). Patches cost a
 #: contraction-fold memory inflation, so only small contractions
 #: qualify (conv2's 800-wide patches sank whole-model im2col,
-#: scripts/exp_im2col.py).
+#: scripts/exp_im2col.py — and, as a Pallas stream, asked a 16 GB v5e
+#: for a 34.5 GB patches array at the 64-node north star, PERF.md).
 PATCH_CONV_MAX_CONTRACTION = 64
 
 
@@ -45,13 +44,6 @@ class PatchConv(nn.Module):
     dtype: jnp.dtype | None = None  # None = inherit x.dtype (nn.Conv
     # semantics — a drop-in must not silently downcast f32 inputs)
     param_dtype: jnp.dtype = jnp.float32
-    # which measured gate kind owns the GEMM: "patches" (conv1's
-    # small-contraction class — this module asks the gate itself) or
-    # "conv2" (round 17: big contractions, where SmallCNN asks the
-    # gate BEFORE instantiating — the XLA incumbent there is the
-    # grouped-conv lowering, not an XLA patches matmul, so the
-    # fallback lives outside this module)
-    gate_kind: str = "patches"
 
     @nn.compact
     def __call__(self, x):
@@ -75,14 +67,8 @@ class PatchConv(nn.Module):
         # the pooling pass, so the kernel saves nothing by absorbing
         # them.
         flat = patches.reshape(-1, cin * kh * kw)
-        if self.gate_kind == "conv2":
-            # the gate already chose pallas upstream (SmallCNN measures
-            # patches+kernel against the grouped conv end to end);
-            # dgrad stays XLA inside conv2_matmul's VJP — §6.2 has it
-            # at its floor
-            out = pallas_gemm.conv2_matmul(flat, wf)
-        elif pallas_gemm.choose("patches", (flat.shape, wf.shape),
-                                dtype) == "pallas":
+        if pallas_gemm.choose("patches", (flat.shape, wf.shape),
+                              dtype) == "pallas":
             out = pallas_gemm.patches_matmul(flat, wf)
         else:
             out = flat @ wf
@@ -149,23 +135,6 @@ class SmallCNN(nn.Module):
             if contraction <= PATCH_CONV_MAX_CONTRACTION:
                 x = PatchConv(c, k, dtype=self.dtype,
                               param_dtype=self.param_dtype,
-                              name=f"Conv_{i}")(x)
-            elif pallas_gemm.choose(
-                "conv2",
-                ((math.prod(x.shape[:-1]), contraction),
-                 (contraction, c), tuple(x.shape), k),
-                self.dtype,
-            ) == "pallas":
-                # big-contraction convs (conv2 of the LEAF CNN: K=800)
-                # whose grouped-conv lowering the gate MEASURED as
-                # slower than patches + the streamed Pallas GEMM end to
-                # end (including the 25× im2col inflation — the reason
-                # this is a measured gate, not a threshold). Same param
-                # tree either way, so init/apply taking different
-                # branches at different batch sizes is checkpoint-safe.
-                x = PatchConv(c, k, dtype=self.dtype,
-                              param_dtype=self.param_dtype,
-                              gate_kind="conv2",
                               name=f"Conv_{i}")(x)
             else:
                 x = nn.Conv(c, k, padding="SAME", dtype=self.dtype,

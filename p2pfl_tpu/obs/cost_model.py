@@ -49,10 +49,13 @@ ENV_PEAK = "P2PFL_PEAK_FLOPS"  # per-chip override (tests, odd parts)
 
 
 def peak_flops(device: Any | None = None) -> float | None:
-    """Per-chip bf16 peak FLOP/s, or None off the table (CPU dev
-    boxes). ``P2PFL_PEAK_FLOPS`` overrides — how tests exercise the
-    MFU arithmetic without a TPU, and how an unlisted part gets a
-    denominator without a code change."""
+    """Per-chip bf16 peak FLOP/s; None on a CPU dev box, which has no
+    table entry and no MFU. A TPU whose ``device_kind`` is not in
+    ``PEAKS`` raises: a utilization against a guessed or missing peak
+    would be reported as a device number. ``P2PFL_PEAK_FLOPS``
+    overrides — how tests exercise the MFU arithmetic without a TPU,
+    and how an unlisted part gets a denominator without a code
+    change."""
     env = os.environ.get(ENV_PEAK)
     if env:
         try:
@@ -70,6 +73,11 @@ def peak_flops(device: Any | None = None) -> float | None:
     for key, peak in PEAKS.items():
         if key in kind:
             return peak
+    if getattr(device, "platform", None) == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s for TPU device_kind {device.device_kind!r}: "
+            f"add it to cost_model.PEAKS with its source, or set "
+            f"{ENV_PEAK}")
     return None
 
 
@@ -78,8 +86,6 @@ def compiled_flops(compiled: Any) -> float | None:
     None when the backend publishes no analysis (some CPU builds)."""
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax wraps in a list
-            cost = cost[0] if cost else None
         flops = cost.get("flops") if isinstance(cost, dict) else None
         return float(flops) if flops else None
     except Exception:

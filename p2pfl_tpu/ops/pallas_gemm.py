@@ -32,14 +32,6 @@ kernels are written 2-D):
   VMEM-stationary, producing ``dx`` and ``dw`` tiles from one pass
   over ``x`` and ``w`` (one HBM read of each instead of XLA's two
   independent GEMMs).
-- ``conv2_matmul`` (round 17): the same stationary-weight stream
-  recipe applied to conv2's ``[M, 800] @ [800, 64]`` patches GEMM
-  (fwd via ``stream_gemm``, wgrad via ``stream_wgrad`` with the
-  ragged-tile mask; dgrad stays XLA — §6.2 measures it AT its floor).
-  The gate's "conv2" kind measures the whole per-node conv end to
-  end — patch formation + kernel vs the grouped-conv lowering — so
-  the im2col memory inflation that sank whole-model XLA im2col
-  (scripts/exp_im2col.py) is priced into the decision.
 - ``sgd_accum`` (round 17): fused SGD(+momentum) update — and
   optionally a weighted FedAvg accumulate — as one M-streamed
   elementwise pass: params, momentum and grads are read once and the
@@ -49,20 +41,31 @@ kernels are written 2-D):
   (same promotion order, accumulator-dtype cast last).
 
 Selection: every call site asks :func:`choose`, which measures the
-Pallas and XLA variants at the actual (vmapped) shape on the real
-backend — scan-slope timing, same methodology as
-``scripts/exp_ceiling.py`` — caches the verdict per shape, and falls
-back to XLA whenever Pallas does not win. ``P2PFL_PALLAS_GEMM``
+Pallas and XLA variants at the actual per-node shape, vmapped as wide
+as the federation or as a 1 GiB operand budget allows
+(:func:`_measure_width`), on the real backend — scan-slope timing,
+same methodology as
+``scripts/exp_ceiling.py`` — caches the verdict per shape, and takes
+XLA whenever Pallas does not win. "XLA measured faster" is a decision;
+"the kernel broke" is not: on a TPU a kernel that fails to lower,
+compile or launch raises out of the gate. ``P2PFL_PALLAS_GEMM``
 (auto|on|off) forces either path; non-TPU backends always take XLA
 (interpret-mode Pallas is a correctness tool, not a fast path). The
 decision table is exported into the bench output
 (``pallas_gemm_decisions``) so every headline run records the
 before/after per-op numbers that justified its path.
+
+Block shapes are what Mosaic accepts on a v5e (compiled at the
+north-star shapes, PERF.md "Bring-up"): a block's last dimension is the
+whole axis or a multiple of the 128-lane tile, and every streamed tile
+is sized against the 16 MiB scoped-VMEM default, double buffering
+included.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import os
 import time
 
@@ -72,7 +75,6 @@ import jax.numpy as jnp
 __all__ = [
     "patches_matmul",
     "dense_matmul",
-    "conv2_matmul",
     "sgd_accum",
     "fedavg_accum",
     "stream_gemm",
@@ -88,8 +90,13 @@ __all__ = [
 #: "off"/"xla" (force XLA). Documented in README + docs/perf.md §6.4.
 ENV_KNOB = "P2PFL_PALLAS_GEMM"
 
-_BLOCK_M = 2048  # M rows per grid step (conv1: 129 tiles of 263424)
-_BLOCK_D = 448   # d_in rows per dense-bwd grid step (7 x 448 = 3136)
+_BLOCK_M = 2048  # M rows per grid step of 16-bit operands (conv1:
+# 129 tiles of 263424); wider dtypes stream proportionally fewer rows
+_BLOCK_D = 256   # d_in columns per dense-bwd grid step of 16-bit
+# operands: a multiple of the 128-lane tile (3136 = 12 x 256 + 64, the
+# ragged edge is masked on write)
+_SGD_TILE = 128 * 1024  # elements per streamed sgd tile (512 KiB f32)
+_SGD_COLS = 2048        # widest sgd tile; wider leaves tile columns too
 
 
 def _interp(interpret):
@@ -101,6 +108,14 @@ def _interp(interpret):
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, (dims, ((), ())),
                                preferred_element_type=jnp.float32)
+
+
+def _rows(block: int, total: int, dtype) -> int:
+    """Rows per grid step. ``block`` counts rows of 16-bit operands; a
+    32-bit operand streams half as many, so the tile's VMEM footprint
+    stays the one that compiled ([2048, 800] f32 tiles overflow the
+    scoped limit, [1024, 800] fit)."""
+    return min(max(block * 2 // jnp.dtype(dtype).itemsize, 1), total)
 
 
 # ---------------------------------------------------------------------------
@@ -118,7 +133,7 @@ def _stream_gemm(x, w, block_m, interpret):
 
     m, k = x.shape
     n = w.shape[1]
-    bm = min(block_m, m)
+    bm = _rows(block_m, m, x.dtype)
     out = pl.pallas_call(
         _gemm_kernel,
         grid=(pl.cdiv(m, bm),),
@@ -173,7 +188,7 @@ def _stream_wgrad(x, g, block_m, interpret):
 
     m, k = x.shape
     n = g.shape[1]
-    bm = min(block_m, m)
+    bm = _rows(block_m, m, x.dtype)
     out = pl.pallas_call(
         functools.partial(_wgrad_kernel, m_total=m, block_m=bm),
         grid=(pl.cdiv(m, bm),),
@@ -251,7 +266,11 @@ def _dense_bwd(x, w, g, block_d, interpret):
 
     b, d_in = x.shape
     h = w.shape[1]
-    bd = min(block_d, d_in)
+    bd = _rows(block_d, d_in, x.dtype)
+    if bd < d_in:
+        # d_in is the LAST dimension of the x/dx blocks: Mosaic takes
+        # the whole axis or a multiple of the 128-lane tile there
+        bd = max(bd // 128, 1) * 128
     dx, dw = pl.pallas_call(
         _dense_bwd_kernel,
         grid=(pl.cdiv(d_in, bd),),
@@ -310,43 +329,6 @@ def dense_matmul(x, w, *, block_d: int = _BLOCK_D,
 
 
 # ---------------------------------------------------------------------------
-# conv2_matmul: stream_gemm fwd + stream_wgrad, XLA dgrad (conv2 hot path)
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _conv2_mm(x, w, block_m, interpret):
-    return _stream_gemm(x, w, block_m, interpret)
-
-
-def _conv2_mm_fwd(x, w, block_m, interpret):
-    return _conv2_mm(x, w, block_m, interpret), (x, w)
-
-
-def _conv2_mm_bwd(block_m, interpret, res, g):
-    x, w = res
-    # dgrad stays XLA: §6.2 measures conv2's dgrad AT its derived
-    # floor (2.0 ms vs 2.0), so a kernel has nothing to win there —
-    # only fwd (5.9 vs 4.9) and wgrad (7.3 vs 4.9) are over-floor
-    dx = _dot(g, w, ((1,), (1,))).astype(x.dtype)
-    dw = _stream_wgrad(x, g, block_m, interpret).astype(w.dtype)
-    return dx, dw
-
-
-_conv2_mm.defvjp(_conv2_mm_fwd, _conv2_mm_bwd)
-
-
-def conv2_matmul(x, w, *, block_m: int = _BLOCK_M,
-                 interpret: bool | None = None):
-    """``x [M, K] @ w [K, N]`` for the conv2 shape class (K up to
-    ~1024 — one stationary VMEM tile pair, e.g. the LEAF CNN's
-    ``[M, 800] @ [800, 64]``): Pallas fwd and wgrad, XLA dgrad."""
-    if x.ndim != 2 or w.ndim != 2:
-        raise ValueError(f"2-D operands required, got {x.shape} @ {w.shape}")
-    return _conv2_mm(x, w, int(block_m), _interp(interpret))
-
-
-# ---------------------------------------------------------------------------
 # sgd_accum: fused SGD(+momentum) update + optional weighted accumulate
 # ---------------------------------------------------------------------------
 
@@ -380,20 +362,38 @@ def _sgd_accum_kernel(p_ref, m_ref, g_ref, lr_ref, acc_ref, w_ref,
     acc_out[:] = acc_ref[:] + w_ref[0, 0] * p_new.astype(jnp.float32)
 
 
+def _sgd_specs(shape, block_m):
+    """``(grid, tile spec, scalar spec)`` of the sgd kernels. One
+    streamed tile holds at most ``_SGD_TILE`` elements, so the
+    accumulate kernel's seven streams, double buffered, stay under the
+    scoped-VMEM default at f32. (The tile as first written, 2048 rows by
+    the leaf's full width, asked for 160 MB of the v5e's 128 on
+    ``Dense_0``'s [3136, 2048].) Rows come in multiples of 32 — the
+    sublane tile of every dtype down to int8 — and columns in multiples
+    of the 128-lane tile, or the whole axis."""
+    import jax.experimental.pallas as pl
+
+    rows, cols = shape
+    bc = min(cols, _SGD_COLS)
+    lanes = -(-bc // 128) * 128
+    bm = min(block_m, max(_SGD_TILE // lanes // 32, 1) * 32, rows)
+    grid = (pl.cdiv(rows, bm), pl.cdiv(cols, bc))
+    # elementwise: a ragged edge tile only reads garbage into outputs
+    # the BlockSpec masks off on write — nothing crosses elements, so
+    # no operand masking is needed (unlike the wgrad reduce)
+    tile = pl.BlockSpec((bm, bc), lambda i, j: (i, j))
+    one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+    return grid, tile, one
+
+
 @functools.partial(jax.jit, static_argnums=(4, 5, 6))
 def _sgd(p, m, g, lr, momentum, block_m, interpret):
     import jax.experimental.pallas as pl
 
-    rows, cols = p.shape
-    bm = min(block_m, rows)
-    tile = pl.BlockSpec((bm, cols), lambda i: (i, 0))
-    one = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    # elementwise over rows: a ragged last tile only reads garbage into
-    # output rows the BlockSpec masks off on write — nothing crosses
-    # rows, so no operand masking is needed (unlike the wgrad reduce)
+    grid, tile, one = _sgd_specs(p.shape, block_m)
     return pl.pallas_call(
         functools.partial(_sgd_kernel, momentum=momentum),
-        grid=(pl.cdiv(rows, bm),),
+        grid=grid,
         in_specs=[tile, tile, tile, one],
         out_specs=[tile, tile],
         out_shape=[
@@ -408,13 +408,10 @@ def _sgd(p, m, g, lr, momentum, block_m, interpret):
 def _sgd_acc(p, m, g, lr, acc, w, momentum, block_m, interpret):
     import jax.experimental.pallas as pl
 
-    rows, cols = p.shape
-    bm = min(block_m, rows)
-    tile = pl.BlockSpec((bm, cols), lambda i: (i, 0))
-    one = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    grid, tile, one = _sgd_specs(p.shape, block_m)
     return pl.pallas_call(
         functools.partial(_sgd_accum_kernel, momentum=momentum),
-        grid=(pl.cdiv(rows, bm),),
+        grid=grid,
         in_specs=[tile, tile, tile, one, tile, one],
         out_specs=[tile, tile, tile],
         out_shape=[
@@ -514,24 +511,35 @@ def clear_cache() -> None:
     _decisions.clear()
 
 
+def _repeat_program(fn, reps: int):
+    """``fn`` repeated ``reps`` times in one jitted scan — the program
+    :func:`_slope_ms` times."""
+
+    @jax.jit
+    def run(x0, *rest):
+        def body(x, _):
+            out = fn(x, *rest)
+            first = jax.tree.leaves(out)[0]
+            # fold one element back into the carry so scan cannot
+            # hoist or elide the repeated call
+            return x + (first.reshape(-1)[0] * 0).astype(x.dtype), None
+
+        return jax.lax.scan(body, x0, None, length=reps)[0]
+
+    return run
+
+
 def _slope_ms(fn, args, r1: int = 2, r2: int = 6) -> float:
     """Per-call ms net of dispatch/sync overhead: time a scan of r2
     repeats minus a scan of r1 repeats over (r2 - r1) — the
     scripts/exp_ceiling.py scan-slope methodology."""
 
     def repeat(reps):
-        @jax.jit
-        def run(x0, *rest):
-            def body(x, _):
-                out = fn(x, *rest)
-                first = jax.tree.leaves(out)[0]
-                # fold one element back into the carry so scan cannot
-                # hoist or elide the repeated call
-                return x + (first.reshape(-1)[0] * 0).astype(x.dtype), None
-
-            return jax.lax.scan(body, x0, None, length=reps)[0]
-
-        run(*args).block_until_ready()  # compile + warm
+        # lowered and compiled ahead of time: a plain call of the jitted
+        # function would be staged into whatever trace encloses the
+        # gate's call site and hand back a tracer, not a timing
+        run = _repeat_program(fn, reps).lower(*args).compile()
+        run(*args).block_until_ready()  # warm
         best = float("inf")
         for _ in range(3):
             t0 = time.perf_counter()
@@ -542,34 +550,60 @@ def _slope_ms(fn, args, r1: int = 2, r2: int = 6) -> float:
     return max((repeat(r2) - repeat(r1)) / (r2 - r1) * 1e3, 0.0)
 
 
-def _measure(kind: str, key: str, pallas_fn, xla_fn, args) -> str:
-    try:
-        p_ms = _slope_ms(pallas_fn, args)
-        x_ms = _slope_ms(xla_fn, args)
-    except Exception as e:  # Mosaic lowering/launch failure -> XLA
-        _decisions[key] = {"kind": kind, "impl": "xla", "forced": False,
-                           "error": f"{type(e).__name__}: {e}"}
-        return "xla"
+_MEASURE_BYTES = 1 << 30  # operand bytes one measurement may allocate
+
+
+def _measure_width(specs) -> int:
+    """How many nodes of the vmap the measurement runs. The node axis
+    is a grid dimension of every kernel and a batch dimension of every
+    XLA candidate, so cost is linear in it and a narrower harness ranks
+    the candidates the same — while the full 64-wide one, at the
+    evaluation batch, asked for 12.25 GB next to a resident federation
+    on a 16 GB v5e. Operands are sized as HBM holds them: the minor
+    dimension padded to the 128-lane tile (a 25-wide patches row
+    occupies 128)."""
+    n = specs[0].shape[0]
+    total = sum(
+        math.prod(s.shape[:-1]) * (-(-s.shape[-1] // 128) * 128)
+        * jnp.dtype(s.dtype).itemsize for s in specs)
+    return max(1, min(n, _MEASURE_BYTES * n // total))
+
+
+def _measure(kind: str, key: str, pallas_fn, xla_fn, specs) -> str:
+    # every call site sits inside the round's jit/vmap/scan traces, and
+    # a timing needs concrete arrays on the device whatever trace
+    # encloses us: operands are built under compile-time eval, the
+    # candidates run as ahead-of-time compiled programs (_slope_ms).
+    # No except: a kernel Mosaic refuses, or one that faults at launch,
+    # is a defect to surface, not a reason to answer "xla".
+    width = _measure_width(specs)
+    with jax.ensure_compile_time_eval():
+        args = tuple(jnp.zeros((width,) + s.shape[1:], s.dtype)
+                     for s in specs)
+    p_ms = _slope_ms(pallas_fn, args)
+    x_ms = _slope_ms(xla_fn, args)
     impl = "pallas" if p_ms < x_ms else "xla"
     _decisions[key] = {"kind": kind, "impl": impl, "forced": False,
-                       "pallas_ms": round(p_ms, 4), "xla_ms": round(x_ms, 4)}
+                       "pallas_ms": round(p_ms, 4), "xla_ms": round(x_ms, 4),
+                       "nodes_measured": width}
     return impl
+
+
+def _backend() -> str:
+    return jax.default_backend()
 
 
 def choose(kind: str, shapes: tuple, dtype) -> str:
     """Pick "pallas" or "xla" for one op instance.
 
     ``kind``: "patches" (conv1 fwd+bwd GEMM), "dense_bwd" (dense1
-    fused backward), "conv2" (big-contraction conv as patches stream
-    vs grouped conv — ``shapes`` carries ``((M, K), (K, N), x_4d,
-    (kh, kw))`` so the measurement can rebuild the whole conv, patch
-    formation included), or "sgd_accum" (fused optimizer stream).
+    fused backward), or "sgd_accum" (fused optimizer stream).
     ``shapes``: the per-node operand shapes as seen at the call site.
     Measured decisions are cached per (kind, shapes, dtype, nodes,
     backend); env/backend forcings are recorded too so the bench
     table shows WHY a path ran.
     """
-    backend = jax.default_backend()
+    backend = _backend()
     dt = jnp.dtype(dtype).name
     n = _nodes_hint
     key = f"{kind} n{n} {'x'.join(map(str, shapes[0]))}@" \
@@ -595,7 +629,7 @@ def choose(kind: str, shapes: tuple, dtype) -> str:
         _decisions[key] = {"kind": kind, "impl": "xla", "forced": True,
                            "reason": "below measurement threshold"}
     else:
-        return _measure_kind(kind, key, shapes, dtype, n)
+        return _measure(kind, key, *_candidates(kind, shapes, dtype, n))
     return _decisions[key]["impl"]
 
 
@@ -613,11 +647,14 @@ def _flops(kind, shapes) -> float:
     return 2.0 * m * k * n_out * mult
 
 
-def _measure_kind(kind: str, key: str, shapes, dtype, n) -> str:
+def _candidates(kind: str, shapes, dtype, n):
+    """``(pallas_fn, xla_fn, operand specs)`` of one gate kind at the
+    ``n``-wide vmapped shape the round runs — the two programs
+    :func:`_measure` times."""
+    S = jax.ShapeDtypeStruct
     if kind == "patches":
         (m, k), (_, out_n) = shapes
-        x = jnp.zeros((n, m, k), dtype)
-        w = jnp.zeros((n, k, out_n), dtype)
+        specs = (S((n, m, k), dtype), S((n, k, out_n), dtype))
 
         def pallas_fn(x, w):
             f = lambda a, b: patches_matmul(a, b)
@@ -627,11 +664,10 @@ def _measure_kind(kind: str, key: str, shapes, dtype, n) -> str:
             f = lambda a, b: _dot(a, b, ((1,), (0,))).astype(a.dtype)
             return _grad_through(jax.vmap(f))(x, w)
 
-        return _measure(kind, key, pallas_fn, xla_fn, (x, w))
+        return pallas_fn, xla_fn, specs
     if kind == "dense_bwd":
         (b, d_in), (_, h) = shapes
-        x = jnp.zeros((n, b, d_in), dtype)
-        w = jnp.zeros((n, d_in, h), dtype)
+        specs = (S((n, b, d_in), dtype), S((n, d_in, h), dtype))
 
         def pallas_fn(x, w):
             f = lambda a, b: dense_matmul(a, b)
@@ -641,45 +677,11 @@ def _measure_kind(kind: str, key: str, shapes, dtype, n) -> str:
             f = lambda a, b: _dot(a, b, ((1,), (0,))).astype(a.dtype)
             return _grad_through(jax.vmap(f))(x, w)
 
-        return _measure(kind, key, pallas_fn, xla_fn, (x, w))
-    if kind == "conv2":
-        (_, kk), (_, f_out) = shapes[0], shapes[1]
-        b, hh, ww, cin = shapes[2]
-        kh, kw = shapes[3]
-        x = jnp.zeros((n, b, hh, ww, cin), dtype)
-        kern = jnp.zeros((n, kh, kw, cin, f_out), dtype)
-
-        def pallas_fn(x, kern):
-            def one(a, kr):
-                patches = jax.lax.conv_general_dilated_patches(
-                    a, (kh, kw), (1, 1), "SAME",
-                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                )
-                wf = kr.transpose(2, 0, 1, 3).reshape(kk, f_out)
-                return conv2_matmul(patches.reshape(-1, kk), wf)
-
-            return _grad_through(jax.vmap(one))(x, kern)
-
-        def xla_fn(x, kern):
-            # the incumbent is the grouped-conv lowering, NOT an XLA
-            # patches matmul: patch materialization at K=800 is a 25x
-            # memory inflation (scripts/exp_im2col.py), so the fair
-            # fight is end-to-end conv vs end-to-end patches+kernel
-            def one(a, kr):
-                return jax.lax.conv_general_dilated(
-                    a, kr, (1, 1), "SAME",
-                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                )
-
-            return _grad_through(jax.vmap(one))(x, kern)
-
-        return _measure(kind, key, pallas_fn, xla_fn, (x, kern))
+        return pallas_fn, xla_fn, specs
     if kind == "sgd_accum":
         (m_rows, cols) = shapes[0]
-        p = jnp.zeros((n, m_rows, cols), dtype)
-        mom = jnp.zeros((n, m_rows, cols), dtype)
-        g = jnp.zeros((n, m_rows, cols), dtype)
-        lr = jnp.full((n,), 0.1, jnp.float32)
+        leaf = S((n, m_rows, cols), dtype)
+        specs = (leaf, leaf, leaf, S((n,), jnp.float32))
 
         def pallas_fn(p, mom, g, lr):
             f = lambda a, b, c, l: sgd_accum(a, b, c, l, momentum=0.9)
@@ -692,7 +694,7 @@ def _measure_kind(kind: str, key: str, shapes, dtype, n) -> str:
 
             return jax.vmap(f)(p, mom, g, lr)
 
-        return _measure(kind, key, pallas_fn, xla_fn, (p, mom, g, lr))
+        return pallas_fn, xla_fn, specs
     raise ValueError(f"unknown gate kind: {kind!r}")
 
 

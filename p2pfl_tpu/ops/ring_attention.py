@@ -41,12 +41,6 @@ def reference_attention(q, k, v):
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
-def _axis_size(axis_name: str) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)  # 0.4.x spelling; folds to a constant
-
-
 def _block_attn(q, k, v, m, l, o, scale):
     """One blockwise-softmax accumulation step (flash-attention update).
 
@@ -69,19 +63,14 @@ def ring_self_attention(q, k, v, axis_name: str):
     """Ring attention: q/k/v are this device's sequence shards
     [batch, seq_shard, heads, head_dim]; returns the local output shard.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, sq, h, d = q.shape
     scale = 1.0 / (d**0.5)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     # mark accumulators device-varying so the fori_loop carry types match
-    # the collective-produced outputs (JAX >= 0.8 vma tracking)
-    if hasattr(jax.lax, "pcast"):
-        vary = lambda x: jax.lax.pcast(x, (axis_name,), to="varying")
-    elif hasattr(jax.lax, "pvary"):  # pragma: no cover - pre-0.9 spelling
-        vary = lambda x: jax.lax.pvary(x, axis_name)
-    else:  # 0.4.x: no vma tracking, carries already type-match
-        vary = lambda x: x
+    # the collective-produced outputs (vma tracking)
+    vary = lambda x: jax.lax.pcast(x, (axis_name,), to="varying")
     m = vary(jnp.full((b, h, sq), -jnp.inf, jnp.float32))
     l = vary(jnp.zeros((b, h, sq), jnp.float32))
     o = vary(jnp.zeros((b, h, sq, d), jnp.float32))
@@ -104,7 +93,7 @@ def ulysses_attention(q, k, v, axis_name: str):
     Requires heads divisible by the axis size. q/k/v: sequence shards
     [b, s_shard, h, d]; attention itself sees [b, s_full, h_shard, d].
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, s, h, d = q.shape
     if h % n:
         raise ValueError(f"heads ({h}) must divide over axis size ({n})")

@@ -22,6 +22,7 @@ import contextlib
 import dataclasses
 import json
 import math
+import os
 import pathlib
 import socket
 import subprocess
@@ -38,6 +39,7 @@ from p2pfl_tpu.obs import flight
 from p2pfl_tpu.obs import trace as obs_trace
 from p2pfl_tpu.p2p.node import P2PNode
 from p2pfl_tpu.topology.topology import generate_topology
+from p2pfl_tpu.utils import compile_cache
 
 
 def _trace_setup(cfg: ScenarioConfig) -> obs_trace.Tracer:
@@ -867,6 +869,64 @@ def run_simulation(cfg: ScenarioConfig, timeout: float = 600) -> dict:
                            debug=sanitize.asyncio_debug())
 
 
+class ChipContention(RuntimeError):
+    """More node processes than TPU chips: refused before any child
+    starts."""
+
+
+_CHIP_PROBE = (
+    "import jax; print('P2PFL_TPU_CHIPS', "
+    "jax.device_count() if jax.default_backend() == 'tpu' else 0)"
+)
+
+
+def _tpu_chips() -> int:
+    """How many TPU chips a child of this process would find; 0 when
+    its default backend is not a TPU. Asked of a throwaway subprocess
+    that has exited — and released the device — before any child
+    starts, so the parent itself never initialises a backend: a chip
+    belongs to one process at a time, and a parent holding it would
+    starve every child."""
+    res = subprocess.run([sys.executable, "-c", _CHIP_PROBE],
+                         capture_output=True, text=True, timeout=300)
+    for line in res.stdout.splitlines():
+        if line.startswith("P2PFL_TPU_CHIPS "):
+            return int(line.split()[1])
+    raise RuntimeError(
+        f"device probe failed (rc={res.returncode}): "
+        f"{res.stderr.strip()[-400:]}")
+
+
+def _child_envs(n_groups: int, n_nodes: int,
+                platform: str | None) -> list[dict | None]:
+    """One environment per child process (None = inherit): on a TPU
+    host each child is pinned to its own chip, or the launch is refused
+    — N processes left to race for one chip end with one winner and
+    N-1 start-up failures or hangs."""
+    if platform not in (None, "tpu"):
+        return [None] * n_groups  # children are told their platform
+    chips = _tpu_chips()
+    if chips == 0:
+        return [None] * n_groups
+    if n_groups > chips:
+        raise ChipContention(
+            f"{n_groups} node processes would contend for {chips} TPU "
+            f"chip(s), and a chip belongs to one process at a time. Run "
+            f"the children on the CPU (--platform cpu), or pack the nodes "
+            f"into at most {chips} process(es) (--nodes-per-proc "
+            f"{math.ceil(n_nodes / chips)}).")
+    return [
+        dict(os.environ,
+             TPU_VISIBLE_CHIPS=str(i),
+             TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+             TPU_PROCESS_BOUNDS="1,1,1",
+             # several single-chip runtimes on one host: libtpu's
+             # one-load-per-host lock would refuse the second child
+             ALLOW_MULTIPLE_LIBTPU_LOAD="true")
+        for i in range(n_groups)
+    ]
+
+
 def launch(cfg: ScenarioConfig, config_path: str | pathlib.Path,
            platform: str | None = None,
            nodes_per_proc: int = 1,
@@ -886,10 +946,12 @@ def launch(cfg: ScenarioConfig, config_path: str | pathlib.Path,
     6×4, … — the layouts the multi-process bench compares against the
     all-in-one-loop simulation mode.
 
-    ``platform="cpu"`` forces the children onto the CPU backend — N
-    processes cannot share one TPU chip, so multi-process mode on a
-    single-chip host runs compute on CPU (on a pod each host pins its
-    own chips).
+    ``platform="cpu"`` forces the children onto the CPU backend. Left
+    to the default backend on a TPU host, the launcher decides from
+    the chip count and the group count (``_child_envs``): each child is
+    pinned to its own chip when there are enough chips, and otherwise
+    :class:`ChipContention` is raised before any child starts. The
+    parent never initialises a backend.
 
     With ``cfg.encrypt`` the parent mints a scenario CA + per-node
     certificates next to the config file and every connection runs
@@ -906,10 +968,12 @@ def launch(cfg: ScenarioConfig, config_path: str | pathlib.Path,
     k = max(int(nodes_per_proc), 1)
     groups = [list(range(i, min(i + k, cfg.n_nodes)))
               for i in range(0, cfg.n_nodes, k)]
+    envs = _child_envs(len(groups), cfg.n_nodes, platform)
 
-    def _spawn(cmd: list[str]) -> subprocess.Popen:
+    def _spawn(gi: int, cmd: list[str]) -> subprocess.Popen:
         return subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                                stderr=subprocess.STDOUT, text=True)
+                                stderr=subprocess.STDOUT, text=True,
+                                env=envs[gi])
 
     cmds, procs = [], []
     for group in groups:
@@ -921,7 +985,7 @@ def launch(cfg: ScenarioConfig, config_path: str | pathlib.Path,
         if tls_dir:
             cmd += ["--tls-dir", tls_dir]
         cmds.append(cmd)
-        procs.append(_spawn(cmd))
+        procs.append(_spawn(len(procs), cmd))
 
     def _supervise(gi: int) -> str:
         """Wait out one group, restarting it (with ``--resume``) on
@@ -934,6 +998,11 @@ def launch(cfg: ScenarioConfig, config_path: str | pathlib.Path,
             out, _ = p.communicate(timeout=900)
             chunks.append(out)
             if p.returncode == 0 or attempt >= max_restarts:
+                if p.returncode != 0:
+                    # a dead child leaves no result line: say why
+                    print(f"p2pfl_tpu.p2p.launch: child for nodes "
+                          f"{groups[gi]} exited rc={p.returncode}:\n"
+                          f"{out[-2000:]}", file=sys.stderr, flush=True)
                 return "".join(chunks)
             attempt += 1
             delay = min(restart_backoff_s * (2.0 ** (attempt - 1)), 30.0)
@@ -941,7 +1010,7 @@ def launch(cfg: ScenarioConfig, config_path: str | pathlib.Path,
                           attempt=attempt, rc=p.returncode,
                           backoff_s=round(delay, 3))
             time.sleep(delay)
-            p = _spawn(cmds[gi] + ["--resume"])
+            p = _spawn(gi, cmds[gi] + ["--resume"])
 
     if max_restarts > 0:
         # supervise groups concurrently: a crashed group must respawn
@@ -990,6 +1059,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="base of the exponential restart backoff "
                          "(doubles per attempt, capped at 30 s)")
     args = ap.parse_args(argv)
+    compile_cache.enable()  # parent and child: the child inherits it
     if args.platform:
         import jax
 
@@ -1004,12 +1074,16 @@ def main(argv: list[str] | None = None) -> int:
                   resume=args.resume)
         return 0
     cfg = ScenarioConfig.load(args.config)
-    results = launch(cfg, args.config, platform=args.platform,
-                     nodes_per_proc=args.nodes_per_proc,
-                     max_restarts=args.max_restarts,
-                     restart_backoff_s=args.restart_backoff_s)
+    try:
+        results = launch(cfg, args.config, platform=args.platform,
+                         nodes_per_proc=args.nodes_per_proc,
+                         max_restarts=args.max_restarts,
+                         restart_backoff_s=args.restart_backoff_s)
+    except ChipContention as e:
+        print(f"p2pfl_tpu.p2p.launch: {e}", file=sys.stderr)
+        return 2
     print(json.dumps({"nodes": results}))
-    return 0
+    return 0 if len(results) == cfg.n_nodes else 1
 
 
 if __name__ == "__main__":

@@ -1496,8 +1496,12 @@ class P2PNode:
                 tr.high_water(f"send_q_depth/peer{peer.idx}",
                               peer.send_q.qsize() + 1)
             await peer.send_q.put(msg)
-        else:
-            # pre-registration writes (none today) fall through direct
+        elif not peer.writer.is_closing():
+            # pre-registration writes (none today) fall through direct.
+            # So does a frame for a peer torn down under us (crash,
+            # evict): that is a delivery error, which never raises here
+            # — and on Python 3.12 a write to its closed transport is a
+            # TypeError out of asyncio, not an OSError
             await write_message(peer.writer, msg)
 
     async def _forward(self, msg: Message, exclude: int | None = None,

@@ -224,6 +224,9 @@ def main(argv: list[str] | None = None) -> int:
                          "surface over the global mesh instead of the "
                          "minimal demo federation")
     args = ap.parse_args(argv)
+    from p2pfl_tpu.utils import compile_cache
+
+    compile_cache.enable()
     if args.platform:
         import jax
 

@@ -72,24 +72,6 @@ def shard_stacked(tree, mesh: Mesh):
     return jax.tree.map(lambda x: jax.device_put(x, sh), tree)
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across JAX versions: the top-level export (and its
-    ``check_vma`` flag) only exist on newer JAX; 0.4.x has
-    ``jax.experimental.shard_map`` with ``check_rep``. Replication
-    checking is disabled on both — the round programs mix collectives
-    the checker rejects spuriously."""
-    try:
-        from jax import shard_map  # new JAX
-
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 def fetch_global(x) -> np.ndarray:
     """Device array -> full host copy, valid on EVERY process of a
     multi-process job — including processes that own no device of the

@@ -24,6 +24,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from p2pfl_tpu.parallel.mesh import (
     NODES_AXIS,
@@ -158,15 +159,16 @@ class MeshTransport:
         self._replicated = replicated_sharding(self.mesh)
 
     def _place(self, x, sharding):
-        """DCN-safe placement: in a multi-process (jax.distributed)
-        job, ``device_put`` cannot target non-addressable devices, so
-        each process fills only the shards it owns via
-        ``make_array_from_callback`` (the dcn.make_global recipe) —
-        straight from the HOST copy, never bouncing through a local
-        device first. Single-process keeps the direct put."""
+        """Placement straight from the HOST copy — each device is sent
+        its own shard, nothing is first materialised whole on the
+        default device (on a four-chip host that is chip 0, for every
+        stacked array of the federation). In a multi-process
+        (jax.distributed) job ``device_put`` cannot target
+        non-addressable devices, so each process fills only the shards
+        it owns via ``make_array_from_callback`` (the dcn.make_global
+        recipe). An array already on a device is resharded
+        device-to-device."""
         if jax.process_count() > 1:
-            import numpy as np
-
             arr = np.asarray(x)
             # explicit dtype: a process whose devices all fall outside
             # the federation mesh fills no shards, and the dtype can't
@@ -174,16 +176,16 @@ class MeshTransport:
             return jax.make_array_from_callback(
                 arr.shape, sharding, lambda idx: arr[idx], dtype=arr.dtype
             )
-        return jax.device_put(jnp.asarray(x), sharding)
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+        return jax.device_put(x, sharding)
 
     def put_stacked(self, tree):
         """Shard each leaf's leading node axis; replicate scalars and
         leaves that don't carry the node axis (e.g. FederatedState.round)."""
 
         def place(x):
-            shape = getattr(x, "shape", None)
-            if shape is None:
-                shape = jnp.asarray(x).shape
+            shape = np.shape(x)
             if len(shape) >= 1 and shape[0] == self.n_nodes:
                 return self._place(x, self._stacked)
             return self._place(x, self._replicated)

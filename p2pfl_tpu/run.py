@@ -24,6 +24,7 @@ from p2pfl_tpu.config.schema import (
     TrainingConfig,
 )
 from p2pfl_tpu.federation.scenario import Scenario
+from p2pfl_tpu.utils import compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -98,6 +99,7 @@ def config_from_args(args: argparse.Namespace) -> ScenarioConfig:
 
 
 def main(argv: list[str] | None = None) -> int:
+    compile_cache.enable()
     parser = build_parser()
     args = parser.parse_args(argv)
     if args.platform:

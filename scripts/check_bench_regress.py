@@ -1,8 +1,9 @@
 #!/usr/bin/env python
 """Bench regression gate: judge a BENCH json against the trajectory.
 
-The checked-in ``BENCH_r01..rNN.json`` files record every round's bench
-envelope (``{"n", "cmd", "rc", "tail", "parsed": {...}}``, plus
+The checked-in ``BENCH_rNN.json`` files (``r04`` on; the older
+records were taken on a machine that no longer exists and are gone)
+record every round's bench envelope (``{"n", "cmd", "rc", "tail", "parsed": {...}}``, plus
 ``parsed.meta`` run stamps since round 12). This script turns that
 history from archaeology into a gate:
 
@@ -11,8 +12,8 @@ history from archaeology into a gate:
 
 For each HEADLINE perf key the baseline is the trajectory's best-ever
 value (min for time-like keys, max for rate-like keys) over rounds
-that actually ran (``rc == 0`` with a non-empty ``parsed``; the
-timed-out r03 is skipped automatically). A candidate worse than
+that actually ran (``rc == 0`` with a non-empty ``parsed``; a round
+that timed out is skipped automatically). A candidate worse than
 baseline by more than the per-key tolerance band (default 15%) fails
 with a nonzero exit.
 
@@ -21,7 +22,7 @@ rounds_to_80pct) moved with benchmark-harness changes across rounds
 (r05 switched the headline run to a surrogate profile), so gating on
 them would false-positive on the checked-in history itself. The
 ``value`` headline is compared only against history rows measuring the
-SAME ``metric`` string — r01's 8-node headline must not serve as the
+SAME ``metric`` string — an 8-node headline must not serve as the
 baseline for the 64-node metric it was replaced by.
 
 A missing headline key in the candidate is reported but does not fail

@@ -18,7 +18,6 @@ Usage: python scripts/exp_bf16_state.py [--quick]
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 from pathlib import Path
@@ -26,9 +25,9 @@ from pathlib import Path
 _REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_REPO))
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(_REPO / ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
+from p2pfl_tpu.utils import compile_cache  # noqa: E402
+
+compile_cache.enable()
 
 
 def main() -> None:

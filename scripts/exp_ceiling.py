@@ -297,51 +297,6 @@ def main() -> None:
               flops=2 * S * 3136 * 2048 * 2,
               bytes_moved=(S * (3136 + 2048) + n * 3136 * 2048) * 2,
               eff=tile_eff(2048, 3136))
-
-        # round 17: conv2 as patches + streamed GEMM vs the grouped
-        # rows above. End-to-end including patch formation — the 25x
-        # im2col inflation is the cost the gate must price in.
-        def _p2(a):
-            return jax.lax.conv_general_dilated_patches(
-                a, (5, 5), (1, 1), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"))
-
-        def c2_pallas_fwd(c):
-            x, w = c
-
-            def one(a, kr):
-                wf = kr.transpose(2, 0, 1, 3).reshape(800, 64)
-                return pallas_gemm.conv2_matmul(
-                    _p2(a).reshape(-1, 800), wf)
-
-            out = jax.vmap(one)(x, w).reshape(n, b, 14, 14, 64)
-            return out.mean(-1, keepdims=True) + x, w
-
-        probe("conv2 fwd pallas", c2_pallas_fwd, (x2, w2),
-              flops=S * 196 * 800 * 64 * 2,
-              bytes_moved=S * 196 * (32 + 800 + 64) * 2,
-              eff=tile_eff(800, 64))
-
-        def c2_pallas_wgrad(c):
-            x, w, cot = c
-
-            def f(ww):
-                def one(a, kr):
-                    wf = kr.transpose(2, 0, 1, 3).reshape(800, 64)
-                    return pallas_gemm.conv2_matmul(
-                        _p2(a).reshape(-1, 800), wf)
-
-                return jax.vmap(one)(x, ww).reshape(n, b, 14, 14, 64)
-
-            _, vjp = jax.vjp(f, w)
-            dw = vjp(cot)[0]
-            return x, dw + w, cot + jnp.broadcast_to(
-                dw.sum((1, 2, 3))[:, None, None, None, :], cot.shape)
-
-        probe("conv2 wgrad pallas", c2_pallas_wgrad, (x2, w2, cot2),
-              flops=S * 196 * 800 * 64 * 2,
-              bytes_moved=S * 196 * (64 + 32) * 2,
-              eff=tile_eff(800, 64))
     else:
         print("(pallas kernel probes skipped: backend is "
               f"{jax.default_backend()}, kernels target TPU Mosaic)",
@@ -441,7 +396,6 @@ def main() -> None:
                   "dense1 dgrad only", "dense1 wgrad only",
                   "conv1 fwd pallas", "conv1 wgrad pallas",
                   "dense1 bwd pallas",
-                  "conv2 fwd pallas", "conv2 wgrad pallas",
                   "sgd update fused pallas")
     per_step = [r for r in rows if r[0] not in diagnostic
                 and not r[0].startswith("lora ")]

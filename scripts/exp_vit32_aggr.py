@@ -18,16 +18,15 @@ Usage: python scripts/exp_vit32_aggr.py [--rounds 20] [--profile easy|hard]
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 from pathlib import Path
 
 _REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_REPO))
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(_REPO / ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
+from p2pfl_tpu.utils import compile_cache  # noqa: E402
+
+compile_cache.enable()
 
 
 def main() -> None:

@@ -15,7 +15,7 @@ crashing combination:
         --layers 2 --nodes 32 --batch 64 --rounds 20 --trips 3
 
 Exit code 0 prints CLEAN; a worker fault kills the process (the
-caller observes the non-zero rc / tunnel error).
+caller observes the non-zero rc).
 """
 
 from __future__ import annotations

@@ -130,6 +130,10 @@ def test_mfu_arithmetic_and_peak_table(monkeypatch):
     from types import SimpleNamespace
     assert cost_model.peak_flops(
         SimpleNamespace(device_kind="TPU v4")) == 275e12
+    # a TPU that is not in the table is an error, not a default
+    with pytest.raises(ValueError, match="TPU v99"):
+        cost_model.peak_flops(
+            SimpleNamespace(device_kind="TPU v99", platform="tpu"))
     # CPU dev box: no table entry -> no denominator -> no MFU
     assert cost_model.peak_flops() is None
     # the env override is how tests/odd parts get a denominator

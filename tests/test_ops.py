@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from p2pfl_tpu.parallel.mesh import shard_map_compat
 
 from p2pfl_tpu.ops import ring_self_attention, ulysses_attention
 
@@ -30,11 +29,12 @@ def _sharded(attn):
     """The attention fn under shard_map with the sequence axis over
     all devices — one wiring shared by the forward and gradient tests."""
     mesh = Mesh(np.asarray(jax.devices()), ("sp",))
-    return shard_map_compat(
+    return jax.shard_map(
         lambda a, b, c: attn(a, b, c, "sp"),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
         out_specs=P(None, "sp"),
+        check_vma=False,
     )
 
 
@@ -77,9 +77,9 @@ def test_vit_with_ring_attention_axis(n_devices):
     x = jnp.zeros((2, 32, 32, 3))
     # init without the mesh (seq_axis only affects attention internals
     # via collectives, so init must also run inside shard_map)
-    fwd = shard_map_compat(
+    fwd = jax.shard_map(
         lambda xx: model.init_with_output(jax.random.PRNGKey(0), xx)[0],
-        mesh=mesh, in_specs=P(), out_specs=P(),
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
     )
     out = jax.jit(fwd)(x)
     assert out.shape == (2, 10)

@@ -123,46 +123,6 @@ def test_rejects_non_2d():
         pallas_gemm.dense_matmul(jnp.zeros((2, 3)), jnp.zeros((1, 3, 4)))
 
 
-# ---- conv2 stream (round 17: big-contraction conv class) -----------------
-
-
-@pytest.mark.parametrize("m", _MS)
-def test_conv2_matmul_forward_parity(m):
-    # conv2 geometry: K=800 (ragged vs the 128 lane), N=64 — exact
-    # where it matters, M scaled down like the other kernels
-    x, w = _mk((m, 800), 20), _mk((800, 64), 21)
-    got = pallas_gemm.conv2_matmul(x, w, block_m=_BLOCK, interpret=True)
-    want = (x.astype(jnp.float32) @ w.astype(jnp.float32)).astype(x.dtype)
-    _close(got, want, 2e-2)
-
-
-@pytest.mark.parametrize("m", [64, 129])  # aligned + ragged edge
-def test_conv2_matmul_grad_parity(m):
-    """fwd + dgrad (XLA inside the VJP) + wgrad (Pallas stream) vs
-    pure-XLA autodiff. The ragged m exercises the wgrad masking — an
-    unmasked garbage row in the last tile would NaN/garble the whole
-    [K, N] accumulator, not one row (cross-row reduction)."""
-    x, w = _mk((m, 800), 22), _mk((800, 64), 23)
-
-    def loss_pallas(x, w):
-        y = pallas_gemm.conv2_matmul(x, w, block_m=_BLOCK, interpret=True)
-        return jnp.sum(y.astype(jnp.float32) ** 2)
-
-    def loss_xla(x, w):
-        return jnp.sum((x @ w).astype(jnp.float32) ** 2)
-
-    (gx, gw) = jax.grad(loss_pallas, argnums=(0, 1))(x, w)
-    (hx, hw) = jax.grad(loss_xla, argnums=(0, 1))(x, w)
-    _close(gx, hx, 0.15)  # bf16 squared-loss cotangents
-    _close(gw, hw, 0.15)
-    assert np.isfinite(np.asarray(gw, np.float32)).all()
-
-
-def test_conv2_matmul_rejects_non_2d():
-    with pytest.raises(ValueError, match="2-D"):
-        pallas_gemm.conv2_matmul(jnp.zeros((2, 3, 4)), jnp.zeros((4, 5)))
-
-
 # ---- sgd_accum stream (round 17: fused optimizer step) --------------------
 
 # interpret mode lowers through XLA:CPU, whose fp-contraction fuses
@@ -297,10 +257,8 @@ def test_gate_decisions_are_json_able():
 
 
 def test_gate_unknown_kind_raises(monkeypatch):
-    # reach _measure_kind by pretending the backend supports measuring
     with pytest.raises(ValueError, match="unknown gate kind"):
-        pallas_gemm._measure_kind("nope", "k", ((8, 8), (8, 8)),
-                                  jnp.float32, 1)
+        pallas_gemm._candidates("nope", ((8, 8), (8, 8)), jnp.float32, 1)
 
 
 # ---- model path ----------------------------------------------------------

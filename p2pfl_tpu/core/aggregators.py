@@ -179,29 +179,31 @@ class Krum(Aggregator):
                 )
                 self._small_cohort_warned = True  # once per instance
 
-        flat = jnp.concatenate(
-            [x.reshape(n, -1).astype(jnp.float32) for x in jax.tree.leaves(stacked)],
-            axis=1,
-        )
-        sq = jnp.sum(flat * flat, axis=1)
-        gram = flat @ flat.T
-        d2 = sq[:, None] + sq[None, :] - 2.0 * gram  # [n, n]
-        big = jnp.float32(jnp.finfo(jnp.float32).max / 4)
-        # distances to self / to absent rows never count as "closest"
-        d2 = jnp.where(jnp.eye(n, dtype=bool), big, d2)
-        d2 = jnp.where(present[None, :], d2, big)
+        with jax.named_scope("krum.gram"):
+            flat = jnp.concatenate(
+                [x.reshape(n, -1).astype(jnp.float32) for x in jax.tree.leaves(stacked)],
+                axis=1,
+            )
+            sq = jnp.sum(flat * flat, axis=1)
+            gram = flat @ flat.T
+            d2 = sq[:, None] + sq[None, :] - 2.0 * gram  # [n, n]
+        with jax.named_scope("krum.select"):
+            big = jnp.float32(jnp.finfo(jnp.float32).max / 4)
+            # distances to self / to absent rows never count as "closest"
+            d2 = jnp.where(jnp.eye(n, dtype=bool), big, d2)
+            d2 = jnp.where(present[None, :], d2, big)
 
-        n_present = jnp.sum(present.astype(jnp.int32))
-        k = jnp.clip(n_present - self.f - 2, 1, n - 1)  # closest-count per Krum
-        d2_sorted = jnp.sort(d2, axis=1)
-        col_mask = jnp.arange(n - 1)[None, :] < k  # static shape, dynamic k
-        scores = jnp.sum(jnp.where(col_mask, d2_sorted[:, : n - 1], 0.0), axis=1)
-        scores = jnp.where(present, scores, jnp.inf)
+            n_present = jnp.sum(present.astype(jnp.int32))
+            k = jnp.clip(n_present - self.f - 2, 1, n - 1)  # closest-count per Krum
+            d2_sorted = jnp.sort(d2, axis=1)
+            col_mask = jnp.arange(n - 1)[None, :] < k  # static shape, dynamic k
+            scores = jnp.sum(jnp.where(col_mask, d2_sorted[:, : n - 1], 0.0), axis=1)
+            scores = jnp.where(present, scores, jnp.inf)
 
-        m = min(self.m, n)
-        _, best = jax.lax.top_k(-scores, m)  # indices of m lowest scores
-        sel = jnp.zeros((n,), jnp.float32).at[best].set(1.0)
-        sel = jnp.where(present, sel, 0.0)
+            m = min(self.m, n)
+            _, best = jax.lax.top_k(-scores, m)  # indices of m lowest scores
+            sel = jnp.zeros((n,), jnp.float32).at[best].set(1.0)
+            sel = jnp.where(present, sel, 0.0)
         return tree_weighted_mean(stacked, sel)
 
 

@@ -88,6 +88,7 @@ class ScenarioResult:
 class Scenario(Observable):
     """Build and drive a federation from a ScenarioConfig."""
 
+    @obs_trace.program_scope()
     def __init__(self, config: ScenarioConfig, dataset: FederatedDataset | None = None):
         super().__init__()
         if config.cross_device.active:
@@ -109,7 +110,8 @@ class Scenario(Observable):
             )
         self.config = config
         n = config.n_nodes
-        self.dataset = dataset or FederatedDataset.make(config.data, n)
+        with obs_trace.stage("scenario.init.data"):
+            self.dataset = dataset or FederatedDataset.make(config.data, n)
         self.model = build_model(config.model)
         if config.lora.active:
             # adapter-only federation: the wrapped model trains (and
@@ -123,16 +125,17 @@ class Scenario(Observable):
                 self.model, config,
                 jnp.asarray(self.dataset.nodes[0].x[:1]),
             )
-        self.fns = make_step_fns(
-            self.model,
-            objective=config.model.objective,
-            optimizer=config.training.optimizer,
-            learning_rate=config.training.learning_rate,
-            momentum=config.training.momentum,
-            weight_decay=config.training.weight_decay,
-            momentum_dtype=config.training.momentum_dtype,
-            batch_size=config.data.batch_size,
-        )
+        with obs_trace.stage("scenario.init.build"):
+            self.fns = make_step_fns(
+                self.model,
+                objective=config.model.objective,
+                optimizer=config.training.optimizer,
+                learning_rate=config.training.learning_rate,
+                momentum=config.training.momentum,
+                weight_decay=config.training.weight_decay,
+                momentum_dtype=config.training.momentum_dtype,
+                batch_size=config.data.batch_size,
+            )
         self.topology = generate_topology(
             config.topology, n, **config.topology_kwargs
         )
@@ -252,76 +255,79 @@ class Scenario(Observable):
                 )
 
         # ---- device-side setup
-        x, y, smask, nsamp = self.dataset.stacked()
-        if self.attack is not None and self.attack.kind == "labelflip":
-            # data poisoning happens at the shard, not the update: flip
-            # the malicious rows of the stacked train labels (identical
-            # math to the socket path flipping its per-node shard)
-            y = np.array(y, copy=True)
-            for i in np.flatnonzero(self.malicious):
-                y[i] = flip_labels(y[i], self.dataset.num_classes)
-        tr = self.transport
-        # host arrays go to the mesh as they are: a jnp.asarray here
-        # would land every stacked array whole on the default device
-        # first (MeshTransport._place)
-        self._data_args = tuple(
-            tr.put_stacked(a) for a in (x, y, smask, nsamp)
-        )
-        self._x_test = tr.put_replicated(self.dataset.x_test)
-        self._y_test = tr.put_replicated(self.dataset.y_test)
-        self.sparse_transport = self._choose_sparse()
-        # ONE wire-precision knob (config.wire_dtype) across planes:
-        # on the SPMD plane the exchange is device math, so bf16 is the
-        # hardware-native reduced precision; int8 (a socket-plane
-        # encoding with per-leaf scales) falls back to bf16 here
-        self._exchange_dtype = (
-            jnp.bfloat16 if config.wire_dtype in ("bf16", "int8") else None
-        )
-        if self.sparse_transport:
-            round_fn = build_round_fn_sparse(
-                self.fns, self.topology, tr.mesh,
-                epochs=config.training.epochs_per_round,
-                exchange_dtype=self._exchange_dtype,
-                exchange_overlap=config.exchange_overlap,
+        with obs_trace.stage("scenario.init.data"):
+            x, y, smask, nsamp = self.dataset.stacked()
+            if self.attack is not None and self.attack.kind == "labelflip":
+                # data poisoning happens at the shard, not the update: flip
+                # the malicious rows of the stacked train labels (identical
+                # math to the socket path flipping its per-node shard)
+                y = np.array(y, copy=True)
+                for i in np.flatnonzero(self.malicious):
+                    y[i] = flip_labels(y[i], self.dataset.num_classes)
+            tr = self.transport
+            # host arrays go to the mesh as they are: a jnp.asarray here
+            # would land every stacked array whole on the default device
+            # first (MeshTransport._place)
+            self._data_args = tuple(
+                tr.put_stacked(a) for a in (x, y, smask, nsamp)
             )
-        else:
-            # one shared robust aggregate when every aggregating row is
-            # identical (single-leader CFL/SDFL; fully-connected DFL):
-            # the per-row path is O(n) redundant aggregations there
-            adj = self.topology.adjacency
-            fully = bool(
-                np.all(adj | np.eye(n, dtype=bool))
+            self._x_test = tr.put_replicated(self.dataset.x_test)
+            self._y_test = tr.put_replicated(self.dataset.y_test)
+        with obs_trace.stage("scenario.init.build"):
+            self.sparse_transport = self._choose_sparse()
+            # ONE wire-precision knob (config.wire_dtype) across planes:
+            # on the SPMD plane the exchange is device math, so bf16 is the
+            # hardware-native reduced precision; int8 (a socket-plane
+            # encoding with per-leaf scales) falls back to bf16 here
+            self._exchange_dtype = (
+                jnp.bfloat16 if config.wire_dtype in ("bf16", "int8") else None
             )
-            shared = (
-                config.federation in ("CFL", "SDFL")
-                or (config.federation == "DFL" and fully)
-            )
-            round_fn = build_round_fn(
-                self.fns, aggregator=self.aggregator,
-                epochs=config.training.epochs_per_round,
-                exchange_dtype=self._exchange_dtype,
-                shared_aggregate=shared,
-                # DFL plans always adopt their own row (make_round_plan)
-                # -> the agg[adopt] whole-stack gather pass is elided;
-                # CFL/SDFL adopt the leader's row and keep it
-                identity_adopt=config.federation == "DFL",
-                attack=self.attack,
-                malicious=self.malicious,
-                update_stats=self.reputation is not None,
-                exchange_overlap=config.exchange_overlap,
-                dp=self.dp_spec,
-                dp_mask=self.dp_mask,
-            )
-        self._round_fn = tr.compile_round(round_fn)
-        self._eval_fn = tr.compile_eval(build_eval_fn(self.fns))
-        fed0 = init_federation(self.fns, jnp.asarray(x[0, :1]), n,
-                               seed=config.seed)
-        if config.exchange_overlap == "staged":
-            # seed the double buffer at zero weight: staged round 0
-            # reduces to pure local training (with_staged_buffer)
-            fed0 = with_staged_buffer(fed0)
-        self.fed = tr.put_stacked(fed0)
-        self._maybe_resume()
+            if self.sparse_transport:
+                round_fn = build_round_fn_sparse(
+                    self.fns, self.topology, tr.mesh,
+                    epochs=config.training.epochs_per_round,
+                    exchange_dtype=self._exchange_dtype,
+                    exchange_overlap=config.exchange_overlap,
+                )
+            else:
+                # one shared robust aggregate when every aggregating row is
+                # identical (single-leader CFL/SDFL; fully-connected DFL):
+                # the per-row path is O(n) redundant aggregations there
+                adj = self.topology.adjacency
+                fully = bool(
+                    np.all(adj | np.eye(n, dtype=bool))
+                )
+                shared = (
+                    config.federation in ("CFL", "SDFL")
+                    or (config.federation == "DFL" and fully)
+                )
+                round_fn = build_round_fn(
+                    self.fns, aggregator=self.aggregator,
+                    epochs=config.training.epochs_per_round,
+                    exchange_dtype=self._exchange_dtype,
+                    shared_aggregate=shared,
+                    # DFL plans always adopt their own row (make_round_plan)
+                    # -> the agg[adopt] whole-stack gather pass is elided;
+                    # CFL/SDFL adopt the leader's row and keep it
+                    identity_adopt=config.federation == "DFL",
+                    attack=self.attack,
+                    malicious=self.malicious,
+                    update_stats=self.reputation is not None,
+                    exchange_overlap=config.exchange_overlap,
+                    dp=self.dp_spec,
+                    dp_mask=self.dp_mask,
+                )
+            self._round_fn = tr.compile_round(round_fn)
+            self._eval_fn = tr.compile_eval(build_eval_fn(self.fns))
+        with obs_trace.stage("scenario.init.federation"):
+            fed0 = init_federation(self.fns, jnp.asarray(x[0, :1]), n,
+                                   seed=config.seed)
+            if config.exchange_overlap == "staged":
+                # seed the double buffer at zero weight: staged round 0
+                # reduces to pure local training (with_staged_buffer)
+                fed0 = with_staged_buffer(fed0)
+            self.fed = tr.put_stacked(fed0)
+            self._maybe_resume()
         self._steps_per_round = (
             max(x.shape[1] // config.data.batch_size, 1)
             * config.training.epochs_per_round
@@ -611,28 +617,37 @@ class Scenario(Observable):
                 },
             )
 
+    @obs_trace.program_scope()
     def evaluate(self) -> dict[str, Any]:
-        metrics = self._eval_fn(self.fed, self._x_test, self._y_test)
-        acc = self._node_host(metrics["accuracy"]).astype(np.float64)
-        loss = self._node_host(metrics["loss"]).astype(np.float64)
-        alive = self._node_host(self.fed.alive)
-        mean_acc = float(acc[alive].mean()) if alive.any() else 0.0
-        return {
-            "per_node_accuracy": [float(a) for a in acc],
-            "per_node_loss": [float(l) for l in loss],
-            "mean_accuracy": mean_acc,
-            "min_accuracy": float(acc[alive].min()) if alive.any() else 0.0,
-        }
+        tracer = obs_trace.get_tracer()
+        with tracer.span("scenario.evaluate"):
+            with tracer.span("scenario.evaluate.device"):
+                # the fetch below blocks anyway: waiting here only puts
+                # the device pass and the host fetch under separate spans
+                metrics = jax.block_until_ready(
+                    self._eval_fn(self.fed, self._x_test, self._y_test))
+            with tracer.span("scenario.evaluate.fetch"):
+                acc = self._node_host(metrics["accuracy"]).astype(np.float64)
+                loss = self._node_host(metrics["loss"]).astype(np.float64)
+                alive = self._node_host(self.fed.alive)
+                mean_acc = float(acc[alive].mean()) if alive.any() else 0.0
+                return {
+                    "per_node_accuracy": [float(a) for a in acc],
+                    "per_node_loss": [float(l) for l in loss],
+                    "mean_accuracy": mean_acc,
+                    "min_accuracy": (
+                        float(acc[alive].min()) if alive.any() else 0.0),
+                }
 
+    @obs_trace.program_scope()
     def run(self, rounds: int | None = None,
             target_accuracy: float | None = None) -> ScenarioResult:
         cfg = self.config
         rounds = rounds if rounds is not None else cfg.training.rounds
-        # obs: recompile counter + span tracer (P2PFL_TRACE env gate).
-        # The listener is idempotent and the tracer a no-op when off;
-        # a mid-run recompile storm (perf.md §7b) shows up as
+        # obs: span tracer (P2PFL_TRACE, or a live profiler session);
+        # program_scope installed the recompile counter, so a mid-run
+        # recompile storm (perf.md §7b) shows up as
         # xla/backend_compiles > 0 over the steady-state rounds.
-        obs_trace.install_xla_listener()
         tracer = obs_trace.configure_from_env(
             default_dir=(self.logger.dir / "trace")
             if self.logger.dir else None,
@@ -646,9 +661,11 @@ class Scenario(Observable):
         ev_round = -1  # round the last evaluation reflects
         start_round = int(self._node_host(self.fed.round))
         # profile ONE steady-state round (the second of the run when
-        # there is one — the first carries compile time); SURVEY §5.1's
-        # jax.profiler hook. try/finally: an exception mid-profiled-
-        # round must not leave the tracer running.
+        # there is one — the first carries compile time), host work and
+        # all, so the trace holds its scenario.* spans beside the device
+        # ops; SURVEY §5.1's jax.profiler hook. try/finally: an
+        # exception mid-profiled-round must not leave the profiler
+        # running.
         profile_round = None
         if cfg.profile_dir and self._proc0:
             profile_round = start_round + (1 if rounds > 1 else 0)
@@ -659,96 +676,115 @@ class Scenario(Observable):
                 if r == profile_round:
                     jax.profiler.start_trace(cfg.profile_dir)
                     tracing = True
-                self.notify(Events.ROUND_STARTED, {"round": r})
-                alive = self._advance_membership(r)
-                self._rotate_leader(alive)
-                self.fed = self.fed.replace(
-                    alive=self.transport.put_stacked(alive)
-                )
-                trains_vote = self._voted_trains(alive, r)
+                # the parent of everything the host does for round r:
+                # what its children leave (observers, devprof gauges,
+                # checkpoint) is its self time
                 with tracer.span("scenario.round", args={"round": r}):
-                    self.fed, metrics = self._round_fn(
-                        self.fed, *self._data_args,
-                        *self._plan_args(trains_vote),
-                    )
-                    jax.block_until_ready(self.fed.states.params)
+                    self.notify(Events.ROUND_STARTED, {"round": r})
+                    with tracer.span("scenario.plan"):
+                        alive = self._advance_membership(r)
+                        self._rotate_leader(alive)
+                        self.fed = self.fed.replace(
+                            alive=self.transport.put_stacked(alive)
+                        )
+                        trains_vote = self._voted_trains(alive, r)
+                        plan_args = self._plan_args(trains_vote)
+                    with tracer.span("scenario.dispatch"):
+                        self.fed, metrics = self._round_fn(
+                            self.fed, *self._data_args, *plan_args,
+                        )
+                    with tracer.span("scenario.wait"):
+                        jax.block_until_ready(self.fed.states.params)
+                    self.notify(Events.AGGREGATION_FINISHED, {"round": r})
+                    dt = time.monotonic() - t0
+                    round_times.append(dt)
+                    if devprof.enabled():
+                        # the FLOP probe lowers the round program once
+                        # per run (shapes are fixed), AFTER dt is read so
+                        # its compile never bills itself to a round time
+                        if self._devprof_flops is False:
+                            self._devprof_flops = round_flops(
+                                self._round_fn, self.fed, *self._data_args,
+                                *plan_args)
+                        self.devprof_last = devprof.round_gauges(
+                            self._devprof_flops, dt,
+                            self.transport.n_devices)
+                    self.global_step += self._steps_per_round
+
+                    with tracer.span("scenario.fetch"):
+                        train_loss = self._node_host(
+                            metrics["train_loss"]).astype(np.float64)
+                        if (self.reputation is not None
+                                and "trust_obs" in metrics):
+                            # round r ran on trust from round r-1 (one-
+                            # round lag); fold in this round's scores for
+                            # the next. Silent nodes (not training or
+                            # dead) keep their trust — absence is not
+                            # evidence.
+                            contrib = np.logical_and(
+                                self._base_trains if trains_vote is None
+                                else trains_vote,
+                                alive,
+                            )
+                            self.reputation.observe(
+                                self._node_host(metrics["trust_obs"]).astype(
+                                    np.float64),
+                                contrib,
+                            )
+                    if self.accountant is not None:
+                        # ε is a pure function of rounds completed, so a
+                        # resumed run re-reads the same spend (r counts
+                        # from the checkpoint's round, not zero)
+                        self.accountant.steps = r + 1
+                    with tracer.span("scenario.log"):
+                        for i in range(cfg.n_nodes):
+                            rec = {"Train/loss": float(train_loss[i]),
+                                   "Train/round_time_s": dt}
+                            if self.reputation is not None:
+                                rec["Trust/score"] = float(
+                                    self.reputation.trust[i])
+                            self.logger.log_metrics(
+                                rec, step=self.global_step, round=r, node=i,
+                            )
+                    with tracer.span("scenario.status"):
+                        self._publish_statuses(r, alive, train_loss, ev)
+                    if (cfg.training.eval_every
+                            and (r + 1) % cfg.training.eval_every == 0):
+                        ev = self.evaluate()
+                        ev_round = r
+                        for i, (a, l) in enumerate(
+                            zip(ev["per_node_accuracy"], ev["per_node_loss"])
+                        ):
+                            self.logger.log_metrics(
+                                {"Test/accuracy": a, "Test/loss": l},
+                                step=self.global_step, round=r, node=i,
+                            )
+                        self.logger.log_metrics(
+                            {"Test/mean_accuracy": ev["mean_accuracy"],
+                             "Test/min_accuracy": ev["min_accuracy"]},
+                            step=self.global_step, round=r,
+                        )
+                        if (target_accuracy is not None
+                                and rounds_to_target is None
+                                and ev["mean_accuracy"] >= target_accuracy):
+                            rounds_to_target = r + 1
+                    with tracer.span("scenario.log"):
+                        self.logger.log_metrics(resource_snapshot(),
+                                                step=self.global_step,
+                                                round=r)
+                        self.logger.round_marker(r, self.global_step)
+                    if (cfg.checkpoint_every
+                            and (r + 1) % cfg.checkpoint_every == 0):
+                        if cfg.checkpoint_dir:
+                            path = save_checkpoint(cfg.checkpoint_dir,
+                                                   self.fed)
+                            self.notify(Events.CHECKPOINT_SAVED,
+                                        {"path": str(path)})
+                    self.notify(Events.ROUND_FINISHED,
+                                {"round": r, "time_s": dt})
                 if tracing:
                     jax.profiler.stop_trace()
                     tracing = False
-                self.notify(Events.AGGREGATION_FINISHED, {"round": r})
-                dt = time.monotonic() - t0
-                round_times.append(dt)
-                if devprof.enabled():
-                    # the FLOP probe lowers the round program once per
-                    # run (shapes are fixed), AFTER dt is read so its
-                    # compile never bills itself to a round time
-                    if self._devprof_flops is False:
-                        self._devprof_flops = round_flops(
-                            self._round_fn, self.fed, *self._data_args,
-                            *self._plan_args(trains_vote))
-                    self.devprof_last = devprof.round_gauges(
-                        self._devprof_flops, dt, self.transport.n_devices)
-                self.global_step += self._steps_per_round
-
-                train_loss = self._node_host(
-                    metrics["train_loss"]).astype(np.float64)
-                if self.accountant is not None:
-                    # ε is a pure function of rounds completed, so a
-                    # resumed run re-reads the same spend (r counts
-                    # from the checkpoint's round, not zero)
-                    self.accountant.steps = r + 1
-                if self.reputation is not None and "trust_obs" in metrics:
-                    # round r ran on trust from round r-1 (one-round
-                    # lag); fold in this round's scores for the next.
-                    # Silent nodes (not training or dead) keep their
-                    # trust — absence is not evidence.
-                    contrib = np.logical_and(
-                        self._base_trains if trains_vote is None
-                        else trains_vote,
-                        alive,
-                    )
-                    self.reputation.observe(
-                        self._node_host(metrics["trust_obs"]).astype(
-                            np.float64),
-                        contrib,
-                    )
-                for i in range(cfg.n_nodes):
-                    rec = {"Train/loss": float(train_loss[i]),
-                           "Train/round_time_s": dt}
-                    if self.reputation is not None:
-                        rec["Trust/score"] = float(self.reputation.trust[i])
-                    self.logger.log_metrics(
-                        rec, step=self.global_step, round=r, node=i,
-                    )
-                self._publish_statuses(r, alive, train_loss, ev)
-                if cfg.training.eval_every and (r + 1) % cfg.training.eval_every == 0:
-                    ev = self.evaluate()
-                    ev_round = r
-                    for i, (a, l) in enumerate(
-                        zip(ev["per_node_accuracy"], ev["per_node_loss"])
-                    ):
-                        self.logger.log_metrics(
-                            {"Test/accuracy": a, "Test/loss": l},
-                            step=self.global_step, round=r, node=i,
-                        )
-                    self.logger.log_metrics(
-                        {"Test/mean_accuracy": ev["mean_accuracy"],
-                         "Test/min_accuracy": ev["min_accuracy"]},
-                        step=self.global_step, round=r,
-                    )
-                    if (target_accuracy is not None
-                            and rounds_to_target is None
-                            and ev["mean_accuracy"] >= target_accuracy):
-                        rounds_to_target = r + 1
-                self.logger.log_metrics(resource_snapshot(),
-                                        step=self.global_step, round=r)
-                self.logger.round_marker(r, self.global_step)
-                if cfg.checkpoint_every and (r + 1) % cfg.checkpoint_every == 0:
-                    if cfg.checkpoint_dir:
-                        path = save_checkpoint(cfg.checkpoint_dir, self.fed)
-                        self.notify(Events.CHECKPOINT_SAVED,
-                                    {"path": str(path)})
-                self.notify(Events.ROUND_FINISHED, {"round": r, "time_s": dt})
         finally:
             if tracing:  # exception mid-profiled-round
                 jax.profiler.stop_trace()
@@ -798,6 +834,7 @@ class CrossDeviceScenario(Observable):
     per round out of a 10k–1M population.
     """
 
+    @obs_trace.program_scope()
     def __init__(self, config: ScenarioConfig,
                  dataset: CrossDeviceData | None = None):
         super().__init__()
@@ -1018,26 +1055,29 @@ class CrossDeviceScenario(Observable):
             },
         )
 
+    @obs_trace.program_scope()
     def evaluate(self) -> dict[str, Any]:
         """Central-test-set quality of the global model. Every slot
         holds the same aggregate post-round, so slot metrics agree; the
         mean is reported for symmetry with Scenario.evaluate."""
-        metrics = self._eval_fn(self.fed, self._x_test, self._y_test)
-        acc = np.asarray(metrics["accuracy"]).astype(np.float64)
-        loss = np.asarray(metrics["loss"]).astype(np.float64)
-        return {
-            "per_node_accuracy": [float(a) for a in acc],
-            "per_node_loss": [float(l) for l in loss],
-            "mean_accuracy": float(acc.mean()),
-            "min_accuracy": float(acc.min()),
-        }
+        with obs_trace.get_tracer().span("scenario.evaluate"):
+            metrics = self._eval_fn(self.fed, self._x_test, self._y_test)
+            acc = np.asarray(metrics["accuracy"]).astype(np.float64)
+            loss = np.asarray(metrics["loss"]).astype(np.float64)
+            return {
+                "per_node_accuracy": [float(a) for a in acc],
+                "per_node_loss": [float(l) for l in loss],
+                "mean_accuracy": float(acc.mean()),
+                "min_accuracy": float(acc.min()),
+            }
 
+    @obs_trace.program_scope()
     def run(self, rounds: int | None = None,
             target_accuracy: float | None = None) -> ScenarioResult:
         cfg = self.config
         cd = self.cd
         rounds = rounds if rounds is not None else cfg.training.rounds
-        obs_trace.install_xla_listener()
+        tracer = obs_trace.get_tracer()
         round_times: list[float] = []
         rounds_to_target = None
         ev = None
@@ -1046,76 +1086,90 @@ class CrossDeviceScenario(Observable):
         tr = self.transport
         for r in range(start_round, start_round + rounds):
             t0 = time.monotonic()
-            self.notify(Events.ROUND_STARTED, {"round": r})
-            alive = self._advance_membership(r)
-            # row-major cohorts: cohort step t runs clients
-            # sampled[t*n_slots:(t+1)*n_slots] (sample_cohorts pins the
-            # assignment shared by every arm)
-            sampled, cohorts = sample_cohorts(
-                cd.n_clients, cd.clients_per_round, cd.cohort_size, r,
-                seed=cd.seed, weights=self._sample_weights,
-            )
-            c_alive = alive[cohorts]
-            if self._stream:
-                metrics = self._run_streamed_round(cohorts, c_alive)
-            else:
-                x, y, mask, sizes = self.data.cohort_batch(sampled)
-                shape2 = (cd.cohort_size, cd.n_slots)
-                # leading axis is the SCAN axis (cohort_size), not the
-                # slot axis — replicate; the per-slot split happens
-                # inside the compiled round
-                args = tuple(
-                    tr.put_replicated(a.reshape(shape2 + a.shape[1:]))
-                    for a in (x, y, mask, sizes)
-                ) + (tr.put_replicated(c_alive),)
-                self.fed, metrics = self._round_fn(self.fed, *args)
-            jax.block_until_ready(self.fed.states.params)
-            dt = time.monotonic() - t0
-            round_times.append(dt)
-            if devprof.enabled():
-                # streamed rounds have no single round program to cost
-                # (per-step dispatch) — their gauges carry wall + memory
-                # watermarks only; the monolithic scan costs once
-                if self._devprof_flops is False:
-                    self._devprof_flops = (
-                        round_flops(self._round_fn, self.fed, *args)
-                        if not self._stream else None
+            # Scenario.run's spans; the streamed arm dispatches (and
+            # stalls on its prefetch, a gauge) cohort by cohort
+            with tracer.span("scenario.round", args={"round": r}):
+                self.notify(Events.ROUND_STARTED, {"round": r})
+                with tracer.span("scenario.plan"):
+                    alive = self._advance_membership(r)
+                    # row-major cohorts: cohort step t runs clients
+                    # sampled[t*n_slots:(t+1)*n_slots] (sample_cohorts
+                    # pins the assignment shared by every arm)
+                    sampled, cohorts = sample_cohorts(
+                        cd.n_clients, cd.clients_per_round, cd.cohort_size,
+                        r, seed=cd.seed, weights=self._sample_weights,
                     )
-                self.devprof_last = devprof.round_gauges(
-                    self._devprof_flops, dt, tr.n_devices)
-            self.last_sampled = sampled
-            self.last_cohorts = cohorts
-            self.last_cohort_alive = c_alive
-            self.notify(Events.AGGREGATION_FINISHED, {"round": r})
+                    c_alive = alive[cohorts]
+                    if not self._stream:
+                        x, y, mask, sizes = self.data.cohort_batch(sampled)
+                        shape2 = (cd.cohort_size, cd.n_slots)
+                        # leading axis is the SCAN axis (cohort_size),
+                        # not the slot axis — replicate; the per-slot
+                        # split happens inside the compiled round
+                        args = tuple(
+                            tr.put_replicated(
+                                a.reshape(shape2 + a.shape[1:]))
+                            for a in (x, y, mask, sizes)
+                        ) + (tr.put_replicated(c_alive),)
+                with tracer.span("scenario.dispatch"):
+                    if self._stream:
+                        metrics = self._run_streamed_round(cohorts, c_alive)
+                    else:
+                        self.fed, metrics = self._round_fn(self.fed, *args)
+                with tracer.span("scenario.wait"):
+                    jax.block_until_ready(self.fed.states.params)
+                dt = time.monotonic() - t0
+                round_times.append(dt)
+                if devprof.enabled():
+                    # streamed rounds have no single round program to
+                    # cost (per-step dispatch) — their gauges carry wall
+                    # + memory watermarks only; the monolithic scan
+                    # costs once
+                    if self._devprof_flops is False:
+                        self._devprof_flops = (
+                            round_flops(self._round_fn, self.fed, *args)
+                            if not self._stream else None
+                        )
+                    self.devprof_last = devprof.round_gauges(
+                        self._devprof_flops, dt, tr.n_devices)
+                self.last_sampled = sampled
+                self.last_cohorts = cohorts
+                self.last_cohort_alive = c_alive
+                self.notify(Events.AGGREGATION_FINISHED, {"round": r})
 
-            losses = np.asarray(metrics["train_loss"]).astype(np.float64)
-            live = c_alive.astype(bool)
-            mean_loss = float(losses[live].mean()) if live.any() else 0.0
-            # live throughput gauges (round 20): the monitor's cl/s and
-            # prefetch columns; prefetch keys exist only on streamed
-            # rounds (renderers show "-" when absent)
-            self.crossdev_last["crossdev_clients_per_s"] = round(
-                len(sampled) / dt, 2) if dt > 0 else None
-            self._publish_crossdev_status(r, mean_loss)
-            self.logger.log_metrics(
-                {"Train/loss": mean_loss,
-                 "Train/round_time_s": dt,
-                 "CrossDev/clients_sampled": int(len(sampled)),
-                 "CrossDev/clients_alive": int(live.sum())},
-                step=r, round=r,
-            )
-            if cfg.training.eval_every and (r + 1) % cfg.training.eval_every == 0:
-                ev = self.evaluate()
-                ev_round = r
-                self.logger.log_metrics(
-                    {"Test/mean_accuracy": ev["mean_accuracy"]},
-                    step=r, round=r,
-                )
-                if (target_accuracy is not None
-                        and rounds_to_target is None
-                        and ev["mean_accuracy"] >= target_accuracy):
-                    rounds_to_target = r + 1
-            self.notify(Events.ROUND_FINISHED, {"round": r, "time_s": dt})
+                with tracer.span("scenario.fetch"):
+                    losses = np.asarray(
+                        metrics["train_loss"]).astype(np.float64)
+                live = c_alive.astype(bool)
+                mean_loss = float(losses[live].mean()) if live.any() else 0.0
+                # live throughput gauges (round 20): the monitor's cl/s
+                # and prefetch columns; prefetch keys exist only on
+                # streamed rounds (renderers show "-" when absent)
+                self.crossdev_last["crossdev_clients_per_s"] = round(
+                    len(sampled) / dt, 2) if dt > 0 else None
+                with tracer.span("scenario.log"):
+                    self._publish_crossdev_status(r, mean_loss)
+                    self.logger.log_metrics(
+                        {"Train/loss": mean_loss,
+                         "Train/round_time_s": dt,
+                         "CrossDev/clients_sampled": int(len(sampled)),
+                         "CrossDev/clients_alive": int(live.sum())},
+                        step=r, round=r,
+                    )
+                if (cfg.training.eval_every
+                        and (r + 1) % cfg.training.eval_every == 0):
+                    ev = self.evaluate()
+                    ev_round = r
+                    self.logger.log_metrics(
+                        {"Test/mean_accuracy": ev["mean_accuracy"]},
+                        step=r, round=r,
+                    )
+                    if (target_accuracy is not None
+                            and rounds_to_target is None
+                            and ev["mean_accuracy"] >= target_accuracy):
+                        rounds_to_target = r + 1
+                self.notify(Events.ROUND_FINISHED,
+                            {"round": r, "time_s": dt})
 
         last_round = start_round + rounds - 1
         if ev is None or ev_round != last_round:

@@ -321,8 +321,13 @@ def make_step_fns(
         def step(carry, batch):
             st, loss_sum = carry
             xb, yb, mb = batch
-            loss, grads = jax.value_and_grad(batch_loss)(st.params, xb, yb, mb)
-            st = apply_update(st, grads, gate)
+            # named scopes are metadata: they put the step's two halves
+            # into the device ops' names (flax names the modules itself)
+            with jax.named_scope("fit.value_and_grad"):
+                loss, grads = jax.value_and_grad(batch_loss)(
+                    st.params, xb, yb, mb)
+            with jax.named_scope("fit.optimizer_update"):
+                st = apply_update(st, grads, gate)
             return (st, loss_sum + loss), None
 
         (state, loss_sum), _ = jax.lax.scan(step, (state, 0.0), (bx, by, bm))
@@ -360,7 +365,8 @@ def make_step_fns(
         def step(carry, batch):
             loss_sum, correct_sum, count = carry
             xb, yb, mb = batch
-            out = model.apply(params, xb)
+            with jax.named_scope("eval.forward"):
+                out = model.apply(params, xb)
             w = mb.astype(jnp.float32)
             cnt = jnp.sum(w)
             if objective == "autoencoder":

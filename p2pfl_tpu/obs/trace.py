@@ -25,9 +25,16 @@ The process tracer is a singleton that is **configured in place**
 (never replaced): call sites may cache the reference, so
 ``configure()`` mutates the one object everyone holds.
 
-Enablement comes from ``P2PFL_TRACE``: unset/``0`` = off, ``1`` = on
-(the launcher decides the export dir), any other value = on with that
-value as the export directory.
+Enablement comes from ``P2PFL_TRACE``: ``0`` = off, ``1`` = on (the
+launcher decides the export dir), any other value = on with that value
+as the export directory; unset leaves the tracer as the process set it.
+``span()`` ALSO records while a ``jax.profiler`` session is live, and is
+then a ``jax.profiler.TraceAnnotation`` of the same name too: whoever
+starts a profiler finds the program's spans in the xplane's host plane,
+on the clock the device ops are on, and in the ring, without setting
+anything. Per-message sites of the socket plane gate on ``enabled``
+alone. No span name starts with ``bench.``: the benchmark's trace
+reduction takes those for its own.
 
 Export is Chrome trace-event JSON (the ``{"traceEvents": [...]}``
 object form) — loadable in ``chrome://tracing`` / Perfetto directly,
@@ -42,10 +49,12 @@ visible in every bench record instead of needing a hand profile.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
 import pathlib
+import sys
 import threading
 import time
 from collections import deque
@@ -72,22 +81,44 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+# jax.profiler.TraceAnnotation, looked up once jax is in the process:
+# this module stays importable (traceview, the monitor) without jax
+_Annotation = None
+
+
+def _profiling() -> bool:
+    """Is a ``jax.profiler`` session live? One C call."""
+    global _Annotation
+    if _Annotation is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _Annotation = TraceAnnotation
+    return _Annotation.is_enabled()
+
 
 class _Span:
     """One live span. Closing appends (name, lane, t0, dur, args) to
-    the owning tracer's ring — a single deque.append, no lock."""
+    the owning tracer's ring — a single deque.append, no lock. With
+    ``annotate`` it is also the profiler's TraceAnnotation of that name
+    (args as its keywords)."""
 
-    __slots__ = ("_tracer", "name", "lane", "args", "t0")
+    __slots__ = ("_tracer", "name", "lane", "args", "t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, lane: str | None,
-                 args: dict | None):
+                 args: dict | None, annotate: bool = False):
         self._tracer = tracer
         self.name = name
         self.lane = lane
         self.args = args
         self.t0 = 0.0
+        self._annotation = (
+            _Annotation(name, **(args or {})) if annotate else None)
 
     def __enter__(self) -> "_Span":
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -96,6 +127,8 @@ class _Span:
             (self.name, self.lane, self.t0,
              time.perf_counter() - self.t0, self.args)
         )
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -153,13 +186,15 @@ class Tracer:
     # -- recording ------------------------------------------------------
     def span(self, name: str, lane: str | None = None,
              args: dict | None = None):
-        """Context manager timing one operation. Disabled: returns the
-        shared NULL_SPAN — no allocation. Hot per-frame sites should
-        additionally gate on ``tracer.enabled`` so even the call's
-        argument construction is skipped."""
-        if not self.enabled:
+        """Context manager timing one operation. Disabled and no
+        profiler session live: returns the shared NULL_SPAN — no
+        allocation. Hot per-frame sites should additionally gate on
+        ``tracer.enabled`` so even the call's argument construction is
+        skipped."""
+        profiling = _profiling()
+        if not (self.enabled or profiling):
             return NULL_SPAN
-        return _Span(self, name, lane, args)
+        return _Span(self, name, lane, args, annotate=profiling)
 
     def next_span_id(self) -> str:
         """Mint a globally-unique span id (``<trace_id>.<n>``) for a
@@ -319,12 +354,15 @@ def configure_from_env(
     default_dir: str | pathlib.Path | None = None,
     env: dict | None = None,
 ) -> Tracer:
-    """Apply the ``P2PFL_TRACE`` convention: unset/empty/``0`` →
-    disabled; ``1`` → enabled, exporting to ``default_dir`` (the
-    launcher wires it next to the status dir); any other value →
+    """Apply the ``P2PFL_TRACE`` convention: unset/empty → as the
+    process left it (off, unless someone enabled the tracer in code);
+    ``0`` → disabled; ``1`` → enabled, exporting to ``default_dir``
+    (the launcher wires it next to the status dir); any other value →
     enabled, exporting to that path."""
     raw = (env if env is not None else os.environ).get(ENV_VAR, "")
-    if raw in ("", "0"):
+    if raw == "":
+        return _TRACER
+    if raw == "0":
         return _TRACER.configure(enabled=False)
     if raw == "1":
         return _TRACER.configure(enabled=True, export_dir=default_dir)
@@ -343,21 +381,56 @@ _xla_installed = False
 _xla_recompiles = 0
 _xla_compile_s = 0.0
 
+# Seconds of what happens once, kept since the process started and NOT
+# zeroed by reset_xla_counters(): set-up runs before any profiler does,
+# so spans alone would not reach a reader. jax's own tracing/lowering
+# and persistent-cache seconds count only while the program is on the
+# stack (``program_scope``): a benchmark's reference traces and
+# compiles in the same process, and its seconds are not the program's.
+_TRACE_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                       "/jax/core/compile/jaxpr_to_mlir_module_duration")
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_program_depth = 0
+# tracing nests (a jit traced inside a jit's trace reports its own
+# duration too, inner first): the disjoint (start, end) stretches seen so
+# far, so that seconds are counted once
+_trace_lower_spans: list[tuple[float, float]] = []
+_cache_load_s = 0.0
+_stage_s: dict[str, float] = {}
+
+
+def _add_trace_lower(duration: float) -> None:
+    """Note the stretch that ends now and lasted ``duration``. Events
+    arrive in order of their ends, so only the newest stretches can lie
+    inside (or run into) this one: they merge into it."""
+    end = time.perf_counter()
+    start = end - duration
+    spans = _trace_lower_spans
+    while spans and spans[-1][1] > start:
+        start = min(start, spans.pop()[0])
+    spans.append((start, end))
+
 
 def _on_xla_event(event: str, duration: float, **_kw) -> None:
-    # key on backend_compile specifically: jaxpr tracing/lowering
-    # events fire even for programs that then hit the compile cache,
-    # and internal array-building programs compile too — only
-    # backend_compile counts real XLA work
-    if "backend_compile" not in event:
-        return
-    global _xla_recompiles, _xla_compile_s
-    with _xla_lock:
-        _xla_recompiles += 1
-        _xla_compile_s += duration
-    if _TRACER.enabled:
-        _TRACER.count("xla/backend_compiles")
-        _TRACER.count("xla/backend_compile_s", duration)
+    # the compile counters key on backend_compile specifically:
+    # jaxpr tracing/lowering events fire even for programs that then
+    # hit the compile cache, and internal array-building programs
+    # compile too — only backend_compile counts real XLA work
+    global _xla_recompiles, _xla_compile_s, _cache_load_s
+    if "backend_compile" in event:
+        with _xla_lock:
+            _xla_recompiles += 1
+            _xla_compile_s += duration
+        if _TRACER.enabled:
+            _TRACER.count("xla/backend_compiles")
+            _TRACER.count("xla/backend_compile_s", duration)
+    elif _program_depth:
+        if event in _TRACE_LOWER_EVENTS:
+            with _xla_lock:
+                _add_trace_lower(duration)
+        elif event == _CACHE_LOAD_EVENT:
+            with _xla_lock:
+                _cache_load_s += duration
 
 
 def install_xla_listener() -> bool:
@@ -389,8 +462,62 @@ def xla_compile_seconds() -> float:
 
 def reset_xla_counters() -> None:
     """Zero the compile counters (after warm-up, before a measured
-    region — steady-state rounds are expected to stay at 0)."""
+    region — steady-state rounds are expected to stay at 0). The
+    since-process-start seconds below are not touched."""
     global _xla_recompiles, _xla_compile_s
     with _xla_lock:
         _xla_recompiles = 0
         _xla_compile_s = 0.0
+
+
+@contextlib.contextmanager
+def program_scope():
+    """Held (also as a decorator) by the program's entry calls — a
+    Scenario's constructor, ``run()`` and ``evaluate()`` — so that the
+    listener tells the program's tracing and cache loads from anyone
+    else's in the process. Installs the listener."""
+    global _program_depth
+    install_xla_listener()
+    with _xla_lock:
+        _program_depth += 1
+    try:
+        yield
+    finally:
+        with _xla_lock:
+            _program_depth -= 1
+
+
+@contextlib.contextmanager
+def stage(name: str):
+    """A span that also adds its seconds to ``stage_seconds()[name]``,
+    whether or not anything is tracing: for the stages of set-up."""
+    t0 = time.perf_counter()
+    try:
+        with _TRACER.span(name):
+            yield
+    finally:
+        dt = time.perf_counter() - t0
+        with _xla_lock:
+            _stage_s[name] = _stage_s.get(name, 0.0) + dt
+
+
+def stage_seconds() -> dict[str, float]:
+    """Seconds spent under each ``stage()`` name since process start."""
+    with _xla_lock:
+        return dict(_stage_s)
+
+
+def trace_lower_seconds() -> float:
+    """Seconds inside the program's calls during which jax was tracing
+    to a jaxpr or lowering one to MLIR, since process start: nested
+    traces counted once, and whatever runs at trace time (the kernel
+    gate's measurements) with them."""
+    with _xla_lock:
+        return sum(end - start for start, end in _trace_lower_spans)
+
+
+def cache_load_seconds() -> float:
+    """Seconds jax reported for retrieving executables from the
+    persistent compilation cache inside the program's calls, since
+    process start (each is also inside a backend_compile duration)."""
+    return _cache_load_s

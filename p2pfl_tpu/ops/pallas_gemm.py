@@ -576,6 +576,7 @@ def _measure(kind: str, key: str, pallas_fn, xla_fn, specs) -> str:
     # candidates run as ahead-of-time compiled programs (_slope_ms).
     # No except: a kernel Mosaic refuses, or one that faults at launch,
     # is a defect to surface, not a reason to answer "xla".
+    t0 = time.perf_counter()
     width = _measure_width(specs)
     with jax.ensure_compile_time_eval():
         args = tuple(jnp.zeros((width,) + s.shape[1:], s.dtype)
@@ -585,7 +586,10 @@ def _measure(kind: str, key: str, pallas_fn, xla_fn, specs) -> str:
     impl = "pallas" if p_ms < x_ms else "xla"
     _decisions[key] = {"kind": kind, "impl": impl, "forced": False,
                        "pallas_ms": round(p_ms, 4), "xla_ms": round(x_ms, 4),
-                       "nodes_measured": width}
+                       "nodes_measured": width,
+                       # what deciding cost: both candidates compiled
+                       # (or loaded) and timed
+                       "measure_s": time.perf_counter() - t0}
     return impl
 
 

@@ -422,6 +422,7 @@ def build_round_fn(
                 wn_off = wn * (1.0 - eye)
                 wn_diag = jnp.diagonal(wn)
 
+                @jax.named_scope("exchange.mix")
                 def leaf_mix_staged(p, ps):
                     flat_s = ps.reshape(ps.shape[0], -1).astype(mix_dt)
                     flat_f = p.reshape(p.shape[0], -1).astype(mix_dt)
@@ -436,6 +437,7 @@ def build_round_fn(
                 agg = jax.tree.map(leaf_mix_staged, states.params,
                                    stale_params)
             else:
+                @jax.named_scope("exchange.mix")
                 def leaf_mix(p):
                     flat = p.reshape(p.shape[0], -1).astype(mix_dt)
                     out = jax.lax.dot(  # [n,n]@[n,d] — MXU, f32 accum

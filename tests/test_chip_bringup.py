@@ -101,6 +101,7 @@ def test_gate_measures_under_an_enclosing_trace(tpu_gate):
     assert "error" not in rec and not rec["forced"]
     assert rec["pallas_ms"] >= 0.0 and rec["xla_ms"] >= 0.0
     assert rec["nodes_measured"] == 2  # far under the operand budget
+    assert rec["measure_s"] > 0.0  # what deciding cost, beside the verdict
     assert rec["impl"] in ("pallas", "xla")
 
 

@@ -32,17 +32,21 @@ def test_patchconv_matches_nnconv(cin, k, feat):
 
 
 def test_femnist_cnn_param_tree_unchanged_by_patchconv():
-    """conv1 (contraction 25) runs as PatchConv but keeps the Conv_0
-    key (explicit name=), so pre-PatchConv checkpoints still load;
-    conv2 (contraction 800) keeps the conv lowering (patches would
-    800x-inflate activations)."""
+    """conv1 (contraction 25) runs as the lane-dense BandedConvPool
+    (PatchConv before PR 33) but keeps the Conv_0 key (explicit name=)
+    and nn.Conv's leaves, so checkpoints from either earlier form still
+    load, and aggregators and the benchmark's param_map see no
+    difference; conv2 (contraction 800) keeps the conv lowering."""
     model = get_model("femnist-cnn")
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 28, 28, 1)))
-    names = set(params["params"])
-    assert {"Conv_0", "Conv_1"} <= names, names
-    assert not any(n.startswith("PatchConv") for n in names), names
-    assert params["params"]["Conv_0"]["kernel"].shape == (5, 5, 1, 32)
+    shapes = jax.tree.map(lambda a: a.shape, params["params"])
+    assert shapes == {
+        "Conv_0": {"kernel": (5, 5, 1, 32), "bias": (32,)},
+        "Conv_1": {"kernel": (5, 5, 32, 64), "bias": (64,)},
+        "Dense_0": {"kernel": (3136, 2048), "bias": (2048,)},
+        "Dense_1": {"kernel": (2048, 62), "bias": (62,)},
+    }
     assert 1 * 25 <= PATCH_CONV_MAX_CONTRACTION < 32 * 25
 
 

@@ -10,6 +10,17 @@ rows a node feeds: per epoch ``rng, k = split(rng)`` and
 ``permutation(k, rows)[:steps * batch]``, the node's rng being an input
 made from the seed by the harness.
 
+What belongs to a configuration comes from its model module: ``SHAPES``,
+``init(key)`` (the TRAINED leaves, float32), ``forward(params, x, q)`` and,
+where it has one, ``loss(outputs, y, mask) -> scalar``: the mean over the
+kept rows of a batch, by the module's own rule for what a row holds (a
+label a position, positions masked inside ``y``); else the cross-entropy
+of one label a row. A label keeps whatever axes follow its row axis. A
+configuration with a frozen part (``FROZEN_SHAPES``) is handed it once,
+unstacked, in its stored type, and gets it back in every call as
+``forward(params, x, q, frozen=...)``; only ``init``'s leaves are trained,
+aggregated and compared.
+
 ``q`` (see :func:`quantizer`) turns the reference into the control;
 ``fault`` plants one of the faults the contract names.
 """
@@ -98,6 +109,7 @@ class Federation:
 
     def __init__(self, model, spec, q=None, fault=None, chips=1):
         self.model, self.spec = model, spec
+        self.loss = getattr(model, "loss", masked_ce)
         self.q = quantizer(q)
         self.fault, self.chips = fault, chips
         # the nodes are spread over as many chips as the cell has, so that
@@ -105,9 +117,10 @@ class Federation:
         self.mesh = Mesh(np.array(jax.devices()[:chips]), ("d",))
         self.by_node = NamedSharding(self.mesh, PS("d"))
         self.by_column = NamedSharding(self.mesh, PS(None, "d"))
+        self.on_every_chip = NamedSharding(self.mesh, PS())
         self._epoch = jax.jit(jax.shard_map(
             self._all_nodes_epoch, mesh=self.mesh,
-            in_specs=(PS("d"),) * 6, out_specs=(PS("d"),) * 4,
+            in_specs=(PS("d"),) * 6 + (PS(),), out_specs=(PS("d"),) * 4,
             check_vma=False),
             donate_argnums=(0, 1))
         self._agg = jax.jit(self._aggregate, donate_argnums=(0,))
@@ -128,6 +141,11 @@ class Federation:
             o["v"] = jax.tree.map(lambda v: jnp.zeros(v.shape, F32), params)
             o["t"] = jnp.zeros((next(iter(params.values())).shape[0],), F32)
         return o
+
+    def _forward(self, p32, x, frozen):
+        if frozen:
+            return self.model.forward(p32, x, self.q, frozen=frozen)
+        return self.model.forward(p32, x, self.q)
 
     # ---- one node, one epoch
     def _update(self, p, o, g):
@@ -157,20 +175,20 @@ class Federation:
             raise ValueError(f"reference has no optimizer {opt['name']!r}")
         return jax.tree.map(lambda v: stored(v, pdt), p_new), o_new
 
-    def _node_epoch(self, p, o, rng, x, y, m):
+    def _node_epoch(self, p, o, rng, x, y, m, frozen):
         s = x.shape[0]
         bsz = min(self.spec["batch_size"], s)
         steps = s // bsz
         rng, k = jax.random.split(rng)
         perm = jax.random.permutation(k, s)[: steps * bsz]
         bx = x[perm].reshape((steps, bsz) + x.shape[1:])
-        by = y[perm].reshape(steps, bsz)
+        by = y[perm].reshape((steps, bsz) + y.shape[1:])
         bm = m[perm].reshape(steps, bsz)
         if self.fault == "half_batch":
             bm = jnp.logical_and(bm, jnp.arange(bsz)[None, :] < bsz // 2)
 
         def loss_fn(p32, xb, yb, mb):
-            return masked_ce(self.model.forward(p32, xb, self.q), yb, mb)
+            return self.loss(self._forward(p32, xb, frozen), yb, mb)
 
         def step(carry, batch):
             p, o, tot = carry
@@ -182,11 +200,12 @@ class Federation:
         (p, o, tot), _ = jax.lax.scan(step, (p, o, F32(0)), (bx, by, bm))
         return p, o, rng, tot / steps
 
-    def _all_nodes_epoch(self, P, O, rngs, x, y, m):
+    def _all_nodes_epoch(self, P, O, rngs, x, y, m, frozen):
         def one(args):
             p, o, rng, x_, y_, m_ = args
             for _ in range(self.spec.get("epochs", 1)):
-                p, o, rng, loss = self._node_epoch(p, o, rng, x_, y_, m_)
+                p, o, rng, loss = self._node_epoch(
+                    p, o, rng, x_, y_, m_, frozen)
             return p, o, rng, loss
 
         with jax.default_matmul_precision("highest"):
@@ -237,12 +256,12 @@ class Federation:
         raise ValueError(f"reference has no aggregator {agg['name']!r}")
 
     # ---- evaluation
-    def _evaluate(self, P, x, y):
+    def _evaluate(self, P, x, y, frozen):
         bsz = min(self.spec.get("eval_batch", 512), x.shape[0])
         steps = math.ceil(x.shape[0] / bsz)
         pad = steps * bsz - x.shape[0]
         xp = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
-        yp = jnp.concatenate([y, jnp.zeros((pad,), y.dtype)])
+        yp = jnp.concatenate([y, jnp.zeros((pad,) + y.shape[1:], y.dtype)])
         mp = jnp.arange(steps * bsz) < x.shape[0]
         shp = lambda v: v.reshape((steps, bsz) + v.shape[1:])
 
@@ -251,8 +270,8 @@ class Federation:
 
             def batch(b):
                 xb, yb, mb = b
-                logits = self.model.forward(p32, xb, self.q)
-                return masked_ce(logits, yb, mb) * jnp.sum(mb.astype(F32))
+                out = self._forward(p32, xb, frozen)
+                return self.loss(out, yb, mb) * jnp.sum(mb.astype(F32))
 
             return jnp.sum(jax.lax.map(
                 batch, (shp(xp), shp(yp), shp(mp)))) / x.shape[0]
@@ -262,8 +281,9 @@ class Federation:
 
     # ---- the whole comparison run
     def follow(self, *, key, rngs, x, y, mask, n_samples, x_test, y_test,
-               eval_nodes, rounds):
-        """Follow ``rounds`` rounds from the seed's weights. Returns
+               eval_nodes, rounds, frozen=None):
+        """Follow ``rounds`` rounds from the seed's weights; ``frozen``
+        is the configuration's frozen part, device arrays by name. Returns
         numpy arrays: ``loss`` [rounds, n], ``moment`` [leaves, n] (first
         moment after round 1), ``change`` [leaves, n] (parameters' change
         after the last round), ``eval_loss`` [len(eval_nodes)], ``eval0_loss`` [1]
@@ -271,6 +291,8 @@ class Federation:
         ``leaves`` (sorted names)."""
         n = n_samples.shape[0]
         put = lambda a: jax.device_put(np.asarray(a), self.by_node)
+        # no copy where the arrays already lie there: on one chip, always
+        frozen = jax.device_put(frozen or {}, self.on_every_chip)
         p0 = self.init(key)
         spread = jax.jit(lambda t: jax.tree.map(
             lambda v: jnp.broadcast_to(v[None], (n,) + v.shape), t),
@@ -280,10 +302,10 @@ class Federation:
         rngs, x, y, mask, ns = (put(a) for a in (rngs, x, y, mask, n_samples))
         norms_of = jax.jit(leaf_norms)
         eval0 = self._eval(jax.tree.map(lambda v: v[None], p0),
-                           jnp.asarray(x_test), jnp.asarray(y_test))
+                           jnp.asarray(x_test), jnp.asarray(y_test), frozen)
         losses, moment = [], None
         for r in range(rounds):
-            P, O, rngs, loss = self._epoch(P, O, rngs, x, y, mask)
+            P, O, rngs, loss = self._epoch(P, O, rngs, x, y, mask, frozen)
             if r == 0:
                 moment = np.asarray(norms_of(O["m"]))
             P = self._agg(P, ns)
@@ -295,7 +317,7 @@ class Federation:
             lambda u, v: u.astype(F32) - v.astype(F32)[None], a, b)))(P, p0))
         idx = np.asarray(eval_nodes)
         el = self._eval(jax.tree.map(lambda v: v[idx], P),
-                            jnp.asarray(x_test), jnp.asarray(y_test))
+                        jnp.asarray(x_test), jnp.asarray(y_test), frozen)
         return {"loss": np.stack(losses), "moment": moment, "change": change,
                 "eval_loss": np.asarray(el), "eval0_loss": np.asarray(eval0),
                 "leaves": sorted(p0)}

@@ -3,8 +3,11 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 One run = one process = one cell of ``BENCHMARK.json``. The cell's
-configuration file, traffic file and limits file are found by the names
-in ``BENCHMARK.json``; nothing here names a cell or a model.
+configuration file, traffic file, limits file, reference module, FLOP
+count and readers are found by the names in ``BENCHMARK.json`` and in
+the configuration file; nothing here names a cell or a model, so a new
+one comes as new files and entries (``PERF.md``, "Adding a
+configuration").
 
 Set-up builds the cell's ``ScenarioConfig`` from those files, builds
 the program's ``Scenario``, hands it weights and row-order keys made
@@ -27,6 +30,7 @@ import time
 T_START = time.monotonic()
 
 import argparse
+import functools
 import gc
 import importlib.util
 import json
@@ -40,7 +44,6 @@ HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(HERE))
-
 
 
 class BenchFailure(Exception):
@@ -71,10 +74,15 @@ def load_module(path, name):
 
 
 class Cell:
-    """Everything ``BENCHMARK.json`` and the data files say of one cell."""
+    """Everything ``BENCHMARK.json`` and the data files say of one cell.
+    ``home`` holds the files found by name (``traffic/``, ``cells/``,
+    ``reference/``, ``counts/``, ``readers/``); the tests keep a
+    benchmark of fixture files in a home of their own."""
 
-    def __init__(self, workload: str, rehearse: bool):
-        self.bench = load_json(ROOT / "BENCHMARK.json")
+    def __init__(self, workload: str, rehearse: bool,
+                 bench=ROOT / "BENCHMARK.json", home=HERE):
+        self.bench = load_json(bench)
+        self.home = home = pathlib.Path(home)
         cells = {w["name"]: w for w in self.bench["workloads"]}
         if workload not in cells:
             raise BenchFailure(f"no workload {workload!r}; have {sorted(cells)}")
@@ -84,8 +92,8 @@ class Cell:
         cfg_entry = next(c for c in self.bench["configs"]
                          if c["name"] == self.entry["config"])
         self.config = load_json(ROOT / cfg_entry["file"])
-        self.traffic = load_json(HERE / "traffic" / f"{self.entry['traffic']}.json")
-        limits = load_json(HERE / "cells" / f"{workload}.json")
+        self.traffic = load_json(home / "traffic" / f"{self.entry['traffic']}.json")
+        limits = load_json(home / "cells" / f"{workload}.json")
         # the CPU's own bf16 arithmetic reads some norms further off than
         # the chip's does: a rehearsal may carry limits of its own
         self.limits = merged(limits["limits"], limits.get(
@@ -128,7 +136,7 @@ class Cell:
 
     def reference_model(self):
         return load_module(
-            HERE / "reference" / f"{self.config['reference']['module']}.py",
+            self.home / "reference" / f"{self.config['reference']['module']}.py",
             "bench_ref_model")
 
 
@@ -144,6 +152,22 @@ def seed_key(seed: int):
 
 def path_str(path) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "name", k))) for k in path)
+
+
+def named_leaves(tree, param_map, shapes, lead=0):
+    """The program's ``tree`` by the reference's names: the leaves, the
+    tree's structure and each leaf's name in ``param_map``; a leaf whose
+    shape past its ``lead`` axes is not the reference's is refused."""
+    import jax
+
+    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    names = [param_map[path_str(p)] for p, _ in flat]
+    for (p, leaf), name in zip(flat, names):
+        if tuple(leaf.shape[lead:]) != tuple(shapes[name]):
+            raise BenchFailure(
+                f"{path_str(p)} is {leaf.shape[lead:]}, reference {name} "
+                f"is {shapes[name]}")
+    return [leaf for _, leaf in flat], treedef, names
 
 
 class Driven:
@@ -169,17 +193,10 @@ class Driven:
 
         # ---- weights and row-order keys from the seed, in one jitted call
         model = cell.reference_model()
-        pmap = cell.config["param_map"]
-        flat, treedef = jax.tree_util.tree_flatten_with_path(sc.fed.states.params)
-        names = [pmap[path_str(p)] for p, _ in flat]
-        for (p, leaf), name in zip(flat, names):
-            if tuple(leaf.shape[1:]) != tuple(model.SHAPES[name]):
-                raise BenchFailure(
-                    f"{path_str(p)} is {leaf.shape[1:]}, reference {name} "
-                    f"is {model.SHAPES[name]}")
+        leaves, treedef, names = named_leaves(
+            sc.fed.states.params, cell.config["param_map"], model.SHAPES, lead=1)
         self.order = sorted(range(len(names)), key=lambda i: names[i])
         n = cell.n_nodes
-        leaves = [leaf for _, leaf in flat]
 
         def make(key):
             ref = model.init(key)
@@ -203,6 +220,14 @@ class Driven:
                            x=x, y=y, mask=smask, n_samples=nsamp,
                            x_test=np.asarray(sc.dataset.x_test),
                            y_test=np.asarray(sc.dataset.y_test))
+        # ---- the frozen part, where the configuration states one: the
+        # program's own arrays, once, on the device, in their stored type
+        frozen = cell.config.get("frozen")
+        if frozen:
+            held, _, held_names = named_leaves(
+                functools.reduce(getattr, frozen["from"].split("."), sc),
+                frozen["param_map"], model.FROZEN_SHAPES)
+            self.inputs["frozen"] = dict(zip(held_names, held))
 
         # ---- probes
         def norms(tree_leaves):
@@ -308,7 +333,9 @@ class Driven:
     def release(self):
         """Close the program and drop its state and compiled programs, so
         that the reference has the device to itself. Returns what the
-        comparison needs: the program's readings and the inputs."""
+        comparison needs: the program's readings and the inputs (a frozen
+        part among them stays on the device: the reference computes over
+        the same arrays)."""
         import jax
 
         self.sc.close()
@@ -372,11 +399,12 @@ def cache_entries(path):
         return 0
 
 
-def run_cell(args, sabotage=None) -> dict:
-    """Drive one cell; returns the result line as a dict. ``sabotage`` is
-    for the tests under ``benchmark/tests``: called with the built
-    ``Driven`` before its first round, to break the timed path."""
-    cell = Cell(args.workload, args.rehearse_cpu)
+def run_cell(args, sabotage=None, cell=None) -> dict:
+    """Drive one cell; returns the result line as a dict. ``sabotage``
+    and ``cell`` are for the tests under ``benchmark/tests``: the first
+    is called with the built ``Driven`` before its first round, to break
+    the timed path; the second is a ``Cell`` built from fixture files."""
+    cell = cell or Cell(args.workload, args.rehearse_cpu)
 
     import jax
 
@@ -522,7 +550,7 @@ def run_cell(args, sabotage=None) -> dict:
             "rows_per_node": int(inputs["x"].shape[1]),
         }
         for m in cell.metrics("per_layer"):
-            reader = load_module(HERE / "readers" / f"{m['name']}.py",
+            reader = load_module(cell.home / "readers" / f"{m['name']}.py",
                                  "bench_reader")
             value = reader.read(ctx)
             if value is not None:

@@ -1,0 +1,12 @@
+"""The largest load of a held expert over the held experts' mean, the
+worst layer and step since the process started: the program's own
+counter (``obs.trace.counted``), fetched with each round's losses."""
+
+from p2pfl_tpu.obs import trace as obs_trace
+
+
+def read(ctx):
+    # a program from before the counter has nothing to read
+    counted = getattr(obs_trace, "counted", None)
+    got = counted().get("moe.load_max_over_mean") if counted else None
+    return None if got is None else float(got["max"].max())

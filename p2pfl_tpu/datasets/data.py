@@ -61,16 +61,21 @@ class FederatedDataset:
         """Pad each node's train shard to a common size and stack.
 
         Returns ``(x, y, mask, n_samples)`` with shapes
-        ``[n, S, ...], [n, S], [n, S], [n]``. Padding rows are masked
-        out of loss/metrics and, being weight-0, out of FedAvg.
+        ``[n, S, ...], [n, S, ...], [n, S], [n]``. Padding rows are
+        masked out of loss/metrics and, being weight-0, out of FedAvg.
+        ``x`` keeps the shards' own type where it is an integer (token
+        ids) and a label keeps the axes that follow its row axis (one a
+        position for token rows).
         """
         sizes = [nd.n_samples for nd in self.nodes]
         s = pad_to or max(sizes)
         if s < max(sizes):
             raise ValueError(f"pad_to={s} < largest shard {max(sizes)}")
         n = self.n_nodes
-        x = np.zeros((n, s) + self.input_shape, np.float32)
-        y = np.zeros((n, s), np.int32)
+        x0, y0 = self.nodes[0].x, self.nodes[0].y
+        x = np.zeros((n, s) + self.input_shape,
+                     x0.dtype if x0.dtype.kind in "iu" else np.float32)
+        y = np.zeros((n, s) + y0.shape[1:], np.int32)
         mask = np.zeros((n, s), bool)
         for i, nd in enumerate(self.nodes):
             k = nd.n_samples

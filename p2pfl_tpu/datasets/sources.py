@@ -9,6 +9,13 @@ Dataset families mirror the reference (SURVEY.md §2.4):
 | cifar10  | 32×32×3        | 10      | cifar10/cifar10.py                 |
 | syscall  | 17 features    | 9       | syscall/syscall.py                 |
 | wadi     | 123 features   | 2       | wadi/wadi.py                       |
+| tokens-V-T | T token ids  | V       | none: a seeded synthetic corpus    |
+
+``tokens-<vocabulary>-<length>`` (``tokens`` alone: 256 ids, 128
+positions) is the language-model source: rows are whole sequences of
+token ids drawn from a seeded first-order Markov chain, one document a
+row, no padding, with a label a position (the next id), so that a
+next-token loss can fall.
 
 Real data: ``$P2PFL_TPU_DATA_DIR/<name>.npz`` with arrays
 ``x_train, y_train, x_test, y_test`` (images HWC float or uint8), or
@@ -286,6 +293,56 @@ def _synthetic_hard(name: str, n_train: int, n_test: int,
     )
 
 
+def token_spec(name: str) -> tuple[int, int] | None:
+    """``(vocabulary, length)`` of a ``tokens[-V-T]`` data set name,
+    ``None`` for any other name."""
+    head, *sizes = name.lower().split("-")
+    if head != "tokens" or len(sizes) not in (0, 2):
+        return None
+    try:
+        vocab, length = (int(v) for v in sizes) if sizes else (256, 128)
+    except ValueError:
+        return None
+    if vocab < 8 or length < 2:
+        raise ValueError(f"dataset {name!r}: needs >= 8 ids and >= 2 positions")
+    return vocab, length
+
+
+#: likely successors of each id in the token source's Markov chain
+_TOKEN_FANOUT = 4
+
+
+def _synthetic_tokens(name: str, vocab: int, length: int, n_train: int,
+                      n_test: int, seed: int) -> DatasetSplits:
+    """A first-order Markov source over ``vocab`` ids: each id has
+    ``_TOKEN_FANOUT`` likely successors (seeded), taken with probability
+    0.9 and a uniform id otherwise, so the next id is predictable from
+    the last and a next-token loss falls from ``ln vocab`` towards about
+    ``0.1 ln vocab + ln 4``. Rows are ``length`` ids with the next id at
+    each position as the label ([rows, length] both), generated a
+    position at a time over all rows at once."""
+    rng = np.random.default_rng([seed, 0x70C])
+    successors = rng.integers(0, vocab, size=(vocab, _TOKEN_FANOUT),
+                              dtype=np.int32)
+
+    def draw(n):
+        ids = np.empty((n, length + 1), np.int32)
+        ids[:, 0] = rng.integers(0, vocab, size=n)
+        pick = rng.integers(0, _TOKEN_FANOUT, size=(n, length))
+        stray = rng.random((n, length)) >= 0.9
+        anywhere = rng.integers(0, vocab, size=(n, length), dtype=np.int32)
+        for t in range(length):
+            nxt = successors[ids[:, t], pick[:, t]]
+            ids[:, t + 1] = np.where(stray[:, t], anywhere[:, t], nxt)
+        return ids[:, :-1].copy(), ids[:, 1:].copy()
+
+    x_train, y_train = draw(n_train)
+    x_test, y_test = draw(n_test)
+    return DatasetSplits(name=name, x_train=x_train, y_train=y_train,
+                         x_test=x_test, y_test=y_test, num_classes=vocab,
+                         synthetic=True)
+
+
 def _synthetic(name: str, n_train: int, n_test: int, seed: int,
                profile: str = "hard") -> DatasetSplits:
     if profile == "easy":
@@ -309,8 +366,13 @@ def get_dataset(name: str, seed: int = 0,
                 profile: str = "hard") -> DatasetSplits:
     """Load a dataset by name — real if files exist, surrogate otherwise."""
     key = name.lower()
+    tokens = token_spec(key)
+    if tokens is not None:
+        n_train, n_test = synthetic_sizes or (512, 64)
+        return _synthetic_tokens(key, *tokens, n_train, n_test, seed)
     if key not in _SPECS:
-        raise ValueError(f"unknown dataset {name!r}; have {DATASETS}")
+        raise ValueError(
+            f"unknown dataset {name!r}; have {DATASETS} and tokens-V-T")
     real = _try_load_real(key)
     if real is not None:
         return real

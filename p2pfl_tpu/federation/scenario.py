@@ -121,10 +121,12 @@ class Scenario(Observable):
             # share one base bit-exactly
             from p2pfl_tpu.learning.lora import maybe_wrap_lora
 
-            self.model = maybe_wrap_lora(
-                self.model, config,
-                jnp.asarray(self.dataset.nodes[0].x[:1]),
-            )
+            with obs_trace.stage("scenario.init.base"):
+                self.model = maybe_wrap_lora(
+                    self.model, config,
+                    jnp.asarray(self.dataset.nodes[0].x[:1]),
+                )
+                jax.block_until_ready(self.model.base)
         with obs_trace.stage("scenario.init.build"):
             self.fns = make_step_fns(
                 self.model,
@@ -317,8 +319,18 @@ class Scenario(Observable):
                     dp=self.dp_spec,
                     dp_mask=self.dp_mask,
                 )
-            self._round_fn = tr.compile_round(round_fn)
-            self._eval_fn = tr.compile_eval(build_eval_fn(self.fns))
+            # what the model holds once and does not train (an adapter
+            # federation's base) is the programs' LAST argument, on every
+            # device of the mesh, and not a constant inside them
+            from p2pfl_tpu.learning.lora import frozen_args, frozen_argument
+
+            if config.lora.active:  # the one copy, where the mesh is
+                self.model.base = tr.put_replicated(self.model.base)
+            self._frozen = frozen_args(self.model)
+            self._round_fn = tr.compile_round(
+                frozen_argument(self.model, round_fn))
+            self._eval_fn = tr.compile_eval(
+                frozen_argument(self.model, build_eval_fn(self.fns)))
         with obs_trace.stage("scenario.init.federation"):
             fed0 = init_federation(self.fns, jnp.asarray(x[0, :1]), n,
                                    seed=config.seed)
@@ -625,7 +637,8 @@ class Scenario(Observable):
                 # the fetch below blocks anyway: waiting here only puts
                 # the device pass and the host fetch under separate spans
                 metrics = jax.block_until_ready(
-                    self._eval_fn(self.fed, self._x_test, self._y_test))
+                    self._eval_fn(self.fed, self._x_test, self._y_test,
+                                  *self._frozen))
             with tracer.span("scenario.evaluate.fetch"):
                 acc = self._node_host(metrics["accuracy"]).astype(np.float64)
                 loss = self._node_host(metrics["loss"]).astype(np.float64)
@@ -692,6 +705,7 @@ class Scenario(Observable):
                     with tracer.span("scenario.dispatch"):
                         self.fed, metrics = self._round_fn(
                             self.fed, *self._data_args, *plan_args,
+                            *self._frozen,
                         )
                     with tracer.span("scenario.wait"):
                         jax.block_until_ready(self.fed.states.params)
@@ -705,7 +719,7 @@ class Scenario(Observable):
                         if self._devprof_flops is False:
                             self._devprof_flops = round_flops(
                                 self._round_fn, self.fed, *self._data_args,
-                                *plan_args)
+                                *plan_args, *self._frozen)
                         self.devprof_last = devprof.round_gauges(
                             self._devprof_flops, dt,
                             self.transport.n_devices)
@@ -714,6 +728,13 @@ class Scenario(Observable):
                     with tracer.span("scenario.fetch"):
                         train_loss = self._node_host(
                             metrics["train_loss"]).astype(np.float64)
+                        if "counted" in metrics:
+                            # the model's own counters of this round's
+                            # steps (the same on every node where the
+                            # layer saw all nodes' rows together)
+                            obs_trace.note_counted({
+                                k: self._node_host(v)
+                                for k, v in metrics["counted"].items()})
                         if (self.reputation is not None
                                 and "trust_obs" in metrics):
                             # round r ran on trust from round r-1 (one-

@@ -38,6 +38,7 @@ from p2pfl_tpu.learning.objectives import (
     NO_ACCURACY_OBJECTIVES,
     get_objective,
     masked_accuracy,
+    next_token_loss,
     ocsvm_penalty,
 )
 from p2pfl_tpu.obs import devprof
@@ -213,12 +214,17 @@ def make_step_fns(
         )
 
     def batch_loss(params, bx, by, bmask):
+        """``(loss, counters)``: the counters are what the model itself
+        counts in a step (an expert layer's dropped pairs and load), an
+        empty dict for every model that counts nothing."""
+        if objective == "next_token":
+            return next_token_loss(model, params, bx, by, bmask)
         out = model.apply(params, bx)
         if objective == "autoencoder":
-            return loss_fn(out, bx, bmask)
+            return loss_fn(out, bx, bmask), {}
         if objective == "ocsvm":
-            return loss_fn(out, by, bmask) + ocsvm_penalty(params)
-        return loss_fn(out, by, bmask)
+            return loss_fn(out, by, bmask) + ocsvm_penalty(params), {}
+        return loss_fn(out, by, bmask), {}
 
     def _shuffle(x, perm):
         """Per-epoch reshuffle of the shard. TPU row-gathers of small
@@ -297,7 +303,9 @@ def make_step_fns(
         rng, perm_rng = jax.random.split(state.rng)
         perm = jax.random.permutation(perm_rng, s)[:used]
         bx = _shuffle(x, perm).reshape((steps, bsz) + x.shape[1:])
-        by = y[perm].reshape(steps, bsz)
+        # a label keeps the axes that follow its row axis (one a
+        # position for token rows)
+        by = y[perm].reshape((steps, bsz) + y.shape[1:])
         bm = mask[perm].reshape(steps, bsz)
         return rng, (bx, by, bm)
 
@@ -305,7 +313,7 @@ def make_step_fns(
         """devprof forward phase: the primal pass, returning the vjp
         residual closure (a jit-able Partial pytree) so the backward
         phase is measured without recomputing the forward."""
-        return jax.vjp(lambda p: batch_loss(p, bx, by, bm), params)
+        return jax.vjp(lambda p: batch_loss(p, bx, by, bm)[0], params)
 
     def backward(vjp_fn, loss):
         """devprof backward phase: the cotangent pass alone. ``loss``
@@ -324,15 +332,16 @@ def make_step_fns(
             # named scopes are metadata: they put the step's two halves
             # into the device ops' names (flax names the modules itself)
             with jax.named_scope("fit.value_and_grad"):
-                loss, grads = jax.value_and_grad(batch_loss)(
-                    st.params, xb, yb, mb)
+                (loss, counted), grads = jax.value_and_grad(
+                    batch_loss, has_aux=True)(st.params, xb, yb, mb)
             with jax.named_scope("fit.optimizer_update"):
                 st = apply_update(st, grads, gate)
-            return (st, loss_sum + loss), None
+            return (st, loss_sum + loss), counted
 
-        (state, loss_sum), _ = jax.lax.scan(step, (state, 0.0), (bx, by, bm))
+        (state, loss_sum), counted = jax.lax.scan(
+            step, (state, 0.0), (bx, by, bm))
         state = state.replace(rng=rng)
-        return state, loss_sum / steps
+        return state, (loss_sum / steps, counted)
 
     def train_epochs(state: TrainState, x, y, mask, epochs: int, gate=None):
         """``gate`` (optional f32 scalar, 1.0/0.0) scales every SGD
@@ -343,41 +352,46 @@ def make_step_fns(
         (lightninglearner.py:167-193 builds a fresh Trainer per fit)."""
 
         def body(st, _):
-            st, loss = train_one_epoch(st, (x, y, mask), gate)
-            return st, loss
+            return train_one_epoch(st, (x, y, mask), gate)
 
-        state, losses = jax.lax.scan(body, state, None, length=epochs)
-        return state, {"loss": losses[-1], "loss_per_epoch": losses}
+        state, (losses, counted) = jax.lax.scan(
+            body, state, None, length=epochs)
+        metrics = {"loss": losses[-1], "loss_per_epoch": losses}
+        if counted:  # the model's own counters, [epochs, steps, ...]
+            metrics["counted"] = counted
+        return state, metrics
 
     def evaluate(params, x, y, mask):
         """Batched eval via scan (bounds device memory on big test sets)."""
         s = x.shape[0]
-        bsz = min(eval_batch_size, s)
+        # a row of tokens is a whole sequence: an evaluation batch is
+        # what a training batch is, the size the step's memory is for
+        bsz = min(batch_size if objective == "next_token"
+                  else eval_batch_size, s)
         steps = (s + bsz - 1) // bsz
         pad = steps * bsz - s
         xp = jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
-        yp = jnp.concatenate([y, jnp.zeros((pad,), y.dtype)])
+        yp = jnp.concatenate([y, jnp.zeros((pad,) + y.shape[1:], y.dtype)])
         mp = jnp.concatenate([mask, jnp.zeros((pad,), mask.dtype)])
         bx = xp.reshape((steps, bsz) + x.shape[1:])
-        by = yp.reshape(steps, bsz)
+        by = yp.reshape((steps, bsz) + y.shape[1:])
         bm = mp.reshape(steps, bsz)
 
         def step(carry, batch):
             loss_sum, correct_sum, count = carry
             xb, yb, mb = batch
-            with jax.named_scope("eval.forward"):
-                out = model.apply(params, xb)
             w = mb.astype(jnp.float32)
             cnt = jnp.sum(w)
-            if objective == "autoencoder":
-                loss = loss_fn(out, xb, mb)
-            elif objective == "ocsvm":
-                loss = loss_fn(out, yb, mb) + ocsvm_penalty(params)
-            else:
-                loss = loss_fn(out, yb, mb)
             if objective in NO_ACCURACY_OBJECTIVES:
-                acc = jnp.float32(0.0)  # outputs aren't class logits
+                # outputs aren't class logits (or, for token rows, are
+                # never whole): the training loss is the whole of it
+                with jax.named_scope("eval.forward"):
+                    loss, _ = batch_loss(params, xb, yb, mb)
+                acc = jnp.float32(0.0)
             else:
+                with jax.named_scope("eval.forward"):
+                    out = model.apply(params, xb)
+                loss = loss_fn(out, yb, mb)
                 acc = masked_accuracy(out, yb, mb)
             return (loss_sum + loss * cnt, correct_sum + acc * cnt,
                     count + cnt), None

@@ -22,16 +22,29 @@ magnitude *without changing*, because each is generic over "params":
 Mechanically this is ONE seam: :class:`LoraModel` duck-types the two
 methods ``make_step_fns`` uses (``init(rng, x)`` / ``apply(params,
 x)``), returning and consuming an **adapter-only pytree**. The frozen
-base is a captured constant of the compiled programs — it never enters
-``TrainState``, the optimizer state, the donated ``FederatedState``
-buffers, or any wire payload. Per target kernel ``W`` the effective
-weight is
+base never enters ``TrainState``, the optimizer state, the donated
+``FederatedState`` buffers, or any wire payload. It is held ONCE on the
+device and is an ARGUMENT of the compiled round and evaluation programs
+(:func:`frozen_argument`; ``Scenario`` passes ``model.base``): a
+constant of 5.6 GB would go into the HLO, the compile and every
+compile-cache entry. Code that calls ``apply`` outside such a wrapper
+(tests, the socket plane's small models) still gets the base the model
+was built with.
 
-    ``W_eff = W + (alpha / rank) * A @ B``
+The adapter is a SIDE PATH at each target layer,
 
-with ``A ~ N(0, 1/d_in)`` and ``B = 0``, so the merged model equals the
-base **bit-exactly** at adapter init (``W + 0.0 == W`` for finite
-``W``) — the property the cross-plane parity tests anchor on.
+    ``y = x W + (alpha / rank) * (x A) B``
+
+(``nn.intercept_methods`` on the target ``Dense``/``DenseGeneral``; the
+adapters ride in a flax collection ``"lora"`` beside ``"params"``, so a
+model scanned over its layers hands each layer its own pair). The base
+kernel is never rewritten: merging ``W + A B`` under the round's
+``vmap`` over nodes would make n full copies of every target kernel a
+step. :meth:`LoraModel.materialize` still gives the merged weights, for
+export and for the tests. With ``A ~ N(0, 1/d_in)`` and ``B = 0`` the
+side path adds exactly 0 at adapter init, so the wrapped model equals
+the base **bit-exactly** — the property the cross-plane parity tests
+anchor on.
 
 Shape handling: a target kernel is viewed as ``lead axes + [d_in axes]
 + [d_out axes]``. ``lead`` (e.g. the ``nn.scan`` depth axis) broadcasts
@@ -44,10 +57,13 @@ back to the plain 2-D view ``(..., d_in, d_out)``.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import math
 from typing import Any
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
@@ -60,6 +76,8 @@ ADAPTERS_KEY = "adapters"
 # joins a tree path into the flat adapter-tree key; "/" cannot appear
 # in flax module/param names
 _SEP = "/"
+# the flax collection the adapters ride in, beside "params"
+LORA = "lora"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,12 +164,17 @@ def init_adapters(sites: tuple[AdapterSite, ...], rank: int,
     return adapters
 
 
+def lora_scale(rank: int, alpha: float | None) -> float:
+    """``alpha / rank``; ``alpha`` unset means ``rank`` (scale 1)."""
+    return (alpha if alpha is not None else float(rank)) / float(rank)
+
+
 def adapter_deltas(adapters: dict, sites: tuple[AdapterSite, ...],
                    rank: int, alpha: float | None) -> dict:
     """``(alpha/rank) * A @ B`` per site, reshaped to the kernel shape.
     The matmul broadcasts over the lead axes, so scanned layers keep
     per-depth adapters in one contraction."""
-    scale = (alpha if alpha is not None else float(rank)) / float(rank)
+    scale = lora_scale(rank, alpha)
     out = {}
     for site in sites:
         ab = adapters[site.key]
@@ -197,11 +220,12 @@ class LoraModel:
 
     Duck-types the surface ``make_step_fns`` consumes: ``init`` returns
     the adapter-only pytree (so ``TrainState.params`` and the optimizer
-    state are adapter-sized), ``apply`` merges the adapters into the
-    closed-over frozen base and delegates. The base is a compile-time
-    constant of every jitted program — never donated, vmapped, shipped
-    or optimized, and shared by all nodes of a federation (one copy in
-    HBM regardless of the node count).
+    state are adapter-sized), ``apply`` runs the frozen base with each
+    adapter as a side path of its target layer. The base is held once,
+    shared by all nodes of a federation — never donated, vmapped,
+    shipped or optimized — and handed to the compiled programs as an
+    argument (:func:`frozen_argument`, which binds it for the trace
+    through :meth:`bound`).
     """
 
     def __init__(self, model, base: Any, rank: int,
@@ -212,6 +236,7 @@ class LoraModel:
         self.alpha = alpha
         self.targets = tuple(targets)
         self.base = jax.tree.map(jnp.asarray, base)
+        self._bound = None
         self.sites = find_adapter_sites(self.base, self.targets, specs)
         if self.rank < 1:
             raise ValueError(f"lora rank must be >= 1, got {rank}")
@@ -221,13 +246,29 @@ class LoraModel:
         del sample_x  # base already fixes every shape
         return init_adapters(self.sites, self.rank, rng)
 
-    def apply(self, adapters: dict, x):
-        return self.inner.apply(self.materialize(adapters), x)
+    def apply(self, adapters: dict, x, *args, **kwargs):
+        base = self.base if self._bound is None else self._bound
+        side = functools.partial(_side_path,
+                                 lora_scale(self.rank, self.alpha))
+        with nn.intercept_methods(side):
+            return self.inner.apply({**base, LORA: _nested(adapters)},
+                                    x, *args, **kwargs)
+
+    @contextlib.contextmanager
+    def bound(self, base):
+        """While a program is traced: ``apply`` reads ``base`` (the
+        program's argument) in place of the arrays the model holds."""
+        self._bound, was = base, self._bound
+        try:
+            yield
+        finally:
+            self._bound = was
 
     # -- merge math ----------------------------------------------------
     def materialize(self, adapters: dict) -> Any:
         """Effective full weights: ``base + (alpha/rank) * A @ B`` at
-        every site, untouched leaves passed through by reference."""
+        every site, untouched leaves passed through by reference. For
+        export and for tests; ``apply`` never merges."""
         deltas = adapter_deltas(adapters, self.sites, self.rank,
                                 self.alpha)
 
@@ -244,6 +285,67 @@ class LoraModel:
         )
 
 
+def _nested(adapters: dict) -> dict:
+    """``{"params/a/b/kernel": {A, B}} -> {"a": {"b": {A, B}}}``: the
+    adapters as a flax collection, each pair at its layer's own path."""
+    out: dict = {}
+    for key, pair in adapters.items():
+        *path, leaf = key.split(_SEP)[1:-1]
+        at = out
+        for k in path:
+            at = at.setdefault(k, {})
+        at[leaf] = pair
+    return out
+
+
+def _side_path(scale, next_fun, args, kwargs, context):
+    """``y + scale * (x A) B`` at a layer that holds an adapter pair.
+    ``x``'s trailing axes that the kernel contracts are laid flat
+    (``d_in``), the low-rank product is computed in the layer's own
+    compute type and shaped like ``y``."""
+    mod = context.module
+    y = next_fun(*args, **kwargs)
+    if context.method_name != "__call__" or not (
+            isinstance(mod, (nn.Dense, nn.DenseGeneral))
+            and mod.has_variable(LORA, "A")):
+        return y
+    a, b = mod.get_variable(LORA, "A"), mod.get_variable(LORA, "B")
+    x = args[0]
+    lead = x.ndim
+    while math.prod(x.shape[lead:]) < a.shape[0]:
+        lead -= 1
+    dt = getattr(mod, "dtype", None) or y.dtype
+    with jax.named_scope("lora.side"):
+        xa = jnp.dot(x.reshape(x.shape[:lead] + (-1,)).astype(dt),
+                     a.astype(dt))
+        return y + (jnp.dot(xa, b.astype(dt)) * jnp.asarray(scale, dt)
+                    ).reshape(y.shape)
+
+
+def frozen_args(model) -> tuple:
+    """What a program compiled through :func:`frozen_argument` takes
+    after its own arguments: ``(model.base,)`` for a :class:`LoraModel`,
+    nothing for any other model."""
+    return (model.base,) if hasattr(model, "bound") else ()
+
+
+def frozen_argument(model, fn):
+    """``fn(*args)`` as ``call(*args, frozen)``: the model's frozen tree
+    is the LAST argument of the program that ``jax.jit`` compiles from
+    ``call``, not a constant in it. A model with no frozen part gets
+    ``fn`` back as it is."""
+    if not hasattr(model, "bound"):
+        return fn
+
+    @functools.wraps(fn)
+    def call(*args):
+        *args, frozen = args
+        with model.bound(frozen):
+            return fn(*args)
+
+    return call
+
+
 def base_params_for(model, seed: int, sample_x) -> Any:
     """The frozen base every plane derives identically from config:
     ``model.init(PRNGKey(seed), sample)`` — the SAME key the full-weight
@@ -251,8 +353,12 @@ def base_params_for(model, seed: int, sample_x) -> Any:
     ``JaxLearner.init``), so a lora federation's merged round-0 model
     equals the full-weight federation's round-0 model bit-exactly.
     Depends only on the sample's shape/dtype, never its values, so
-    every node of a socket federation converges on one base."""
-    return model.init(jax.random.PRNGKey(seed), jnp.asarray(sample_x))
+    every node of a socket federation converges on one base. One
+    compiled call: every leaf is made on the device in the model's own
+    parameter type, and the initializer's forward pass is never run (a
+    2.8B-parameter bfloat16 base has no float32 copy)."""
+    return jax.jit(model.init)(jax.random.PRNGKey(seed),
+                               jnp.asarray(sample_x))
 
 
 def wrap_model(model, model_name: str, rank: int, *,

@@ -58,12 +58,24 @@ def masked_accuracy(logits, y, mask=None):
     return _mean(correct, mask)
 
 
-NO_ACCURACY_OBJECTIVES = ("autoencoder", "ocsvm")  # scores aren't class logits
+def next_token_loss(model, params, x, y, mask=None):
+    """Next-token objective of a language model: rows are whole
+    sequences of token ids, ``y`` holds a label a position. The MODEL
+    computes it (``loss(tokens, labels, mask) -> (loss, counters)``:
+    the mean over the kept rows of each row's mean cross-entropy over its
+    positions), because the head and the loss go a chunk of positions at
+    a time: ``[tokens, vocabulary]`` logits are never whole."""
+    return model.apply(params, x, y, mask, method="loss")
+
+
+# scores aren't class logits / a label a position has no one "class"
+NO_ACCURACY_OBJECTIVES = ("autoencoder", "ocsvm", "next_token")
 
 _OBJECTIVES: dict[str, Callable] = {
     "classification": cross_entropy_loss,
     "autoencoder": mse_loss,
     "ocsvm": ocsvm_loss,
+    "next_token": next_token_loss,
 }
 
 

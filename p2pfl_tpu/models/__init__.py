@@ -3,7 +3,9 @@
 Reference inventory (SURVEY.md §2.4, fedstellar/learning/pytorch/*):
 MNIST MLP/CNN, FEMNIST CNN, CIFAR10 ResNet9/18/34/50 + two MobileNets,
 SYSCALL MLP/Autoencoder/One-class-SVM, WADI MLP — plus ViT-Tiny for the
-stretch config in BASELINE.json.
+stretch config in BASELINE.json and Ling-3.0-flash, a hybrid
+linear/latent-attention language model with sparse experts, as a frozen
+base under adapters.
 
 TPU-first design notes:
 - Normalization is **GroupNorm**, not BatchNorm: batch statistics are
@@ -22,6 +24,7 @@ from p2pfl_tpu.models.resnet import CIFAR10ModelResNet, ResNet
 from p2pfl_tpu.models.mobilenet import FasterMobileNet, SimpleMobileNet
 from p2pfl_tpu.models.syscall import SyscallModelAutoencoder, SyscallModelOneClassSVM
 from p2pfl_tpu.models.vit import ViT
+from p2pfl_tpu.models.ling import LingLM
 
 __all__ = [
     "get_model",
@@ -40,4 +43,5 @@ __all__ = [
     "SyscallModelAutoencoder",
     "SyscallModelOneClassSVM",
     "ViT",
+    "LingLM",
 ]

@@ -1,0 +1,703 @@
+"""Ling-3.0-flash (``bailing_hybrid``): a hybrid language model of
+linear-attention (KDA) and latent-attention (MLA) mixers over dense and
+group-limited sparse-expert feed-forward layers.
+
+Pre-norm residual blocks, ``x += Mixer(RMSNorm(x)); x += FFN(RMSNorm(x))``,
+RMSNorm eps 1e-6, no biases, a final RMSNorm and an untied head. Layer
+``i`` of the published stack has an MLA mixer where ``(i + 1) %
+layer_group_size == 0`` and a KDA mixer otherwise; a dense SwiGLU FFN
+where ``i < first_k_dense_replace`` and the expert FFN otherwise. Every
+size is a keyword argument: the published widths come from the
+scenario's ``model.kwargs`` (``benchmark/configs/ling-3.0-flash.json``),
+the defaults are a toy for the CPU tests.
+
+Meant to be trained as a FROZEN base under per-node adapters
+(``learning/lora.py``): the dense projections are ``nn.Dense`` modules,
+which is where an adapter rides; the expert layer's grouped products see
+the tokens of all nodes of a federation's step together
+(``ops/fold.py``). The equations, each departure from the published
+description and what the public config leaves open are written down in
+``benchmark/reference/ling_flash.py``, the plain reference this module
+is compared with.
+
+One chip holds its share of a deployment: ``experts_held`` of the
+``n_experts`` the router scores (one routing group: experts
+``expert_offset ..``), and a slice of the vocabulary. The layer routes
+over all experts and computes its own experts' part of the result; what
+the absent experts would add is left out. No chosen (token, held
+expert) pair is dropped, whatever the imbalance.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from p2pfl_tpu.models.base import register_lora_targets, register_model
+from p2pfl_tpu.ops.fold import fold_rows
+
+F32 = jnp.float32
+HI = jax.lax.Precision.HIGHEST
+
+
+def _dense(features, name, mod):
+    return nn.Dense(features, use_bias=False, dtype=mod.dtype,
+                    param_dtype=mod.param_dtype, name=name,
+                    kernel_init=nn.initializers.lecun_normal())
+
+
+def rms_norm(x, scale, eps):
+    x32 = x.astype(F32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return y * scale.astype(F32)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
+        return rms_norm(x, scale, self.eps).astype(self.dtype)
+
+
+def swiglu(gate_up):
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+# --------------------------------------------------------------------------
+# Kimi Delta Attention, chunk-wise
+
+
+def head_blocks(core, head_block, *arrays):
+    """``core(*arrays)`` over ``head_block`` heads at a time (axis 2 of
+    every array), one block after the other and each recomputed on the
+    way back: heads do not meet in the delta rule, and its float32
+    intermediates for all 32 heads of 8 nodes' sequences are more than a
+    chip holds."""
+    H = arrays[0].shape[2]
+    if H <= head_block or H % head_block:
+        return core(*arrays)
+    blocks = lambda a: jnp.moveaxis(a.reshape(
+        a.shape[:2] + (H // head_block, head_block) + a.shape[3:]), 2, 0)
+    o = jax.lax.map(lambda args: jax.checkpoint(core)(*args),
+                    tuple(map(blocks, arrays)))
+    o = jnp.moveaxis(o, 0, 2)  # [B, T, blocks, head_block, V]
+    return o.reshape(o.shape[:2] + (H, o.shape[-1]))
+
+
+def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16,
+                head_block=8):
+    """:func:`kda_heads`, a block of heads at a time."""
+    return head_blocks(
+        functools.partial(kda_heads, chunk=chunk, sub=sub, dtype=dtype),
+        head_block, q, k, v, g, beta)
+
+
+def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
+    """The delta rule with a decay per channel, a chunk at a time.
+
+    ``q, k`` [B, T, H, K] (normalised, q scaled), ``v`` [B, T, H, V],
+    ``g`` [B, T, H, K] the log-decay (in ``(-80 / sub, 0]``), ``beta``
+    [B, T, H]. Per head: ``S_t = (I - b_t k_t k_t^T) Diag(exp g_t)
+    S_{t-1} + b_t k_t v_t^T``, ``o_t = S_t^T q_t``. Within a chunk, with
+    ``G`` the running sum of ``g`` and ``S_0`` the state the chunk
+    starts from, the updates ``u_i = b_i (v_i - k_i^T Diag(a_i)
+    S_{i-1})`` solve the unit lower-triangular system ``(I + A) U =
+    b (V - (K e^G) S_0)``, ``A_ij = b_i sum_c k_ic k_jc e^(G_ic - G_jc)``
+    for ``j < i``; then ``O = (Q e^G) S_0 + B U`` with ``B_ij = sum_c
+    q_ic k_jc e^(G_ic - G_jc)`` for ``j <= i``, and ``S_C = e^(G_C) S_0
+    + (K e^(G_C - G))^T U``. ``e^(G_i - G_j)`` is never formed from
+    ``e^(-G_j)`` alone, which overflows within a chunk: rows are taken
+    a sub-chunk of ``sub`` at a time against the running sum at that
+    sub-chunk's start, so that each factor's exponent lies within
+    ``sub`` steps of decay. The state, the running sums, the
+    exponentials and the triangular solve are float32; the products take
+    ``dtype`` operands and accumulate in float32."""
+    B, T, H, K = q.shape
+    V = v.shape[-1]
+    C = chunk
+    pad = -T % C
+    if pad:  # zero keys, no decay, beta 0: the state passes unchanged
+        longer = lambda a: jnp.pad(
+            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        q, k, v, g, beta = map(longer, (q, k, v, g, beta))
+    NC = (T + pad) // C
+    chunks = lambda a: a.astype(F32).reshape(
+        (B, NC, C) + a.shape[2:]).swapaxes(2, 3)  # [B, NC, H, C, ..]
+    q, k, v, g = map(chunks, (q, k, v, g))
+    beta = beta.astype(F32).reshape(B, NC, C, H).swapaxes(2, 3)  # [B,NC,H,C]
+    G = jnp.cumsum(g, axis=3)
+    mm = functools.partial(jnp.einsum, preferred_element_type=F32)
+    lo = lambda a: a.astype(dtype)
+
+    # ---- the two intra-chunk matrices, a sub-chunk of rows at a time
+    rows_a, rows_b = [], []
+    for a in range(C // sub):
+        s0, s1 = a * sub, (a + 1) * sub
+        ref = G[..., s0 - 1:s0, :] if a else jnp.zeros_like(G[..., :1, :])
+        left = jnp.exp(G[..., s0:s1, :] - ref)  # exponents <= 0
+        right = lo(k[..., :s1, :] * jnp.exp(jnp.minimum(
+            ref - G[..., :s1, :], 80.0)))  # <= 0 before s0, <= 80 inside
+        widen = lambda m: jnp.pad(m, ((0, 0),) * 4 + ((0, C - s1),))
+        rows_a.append(widen(mm("bnhik,bnhjk->bnhij",
+                               lo(k[..., s0:s1, :] * left), right)))
+        rows_b.append(widen(mm("bnhik,bnhjk->bnhij",
+                               lo(q[..., s0:s1, :] * left), right)))
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    A = jnp.where(j < i, jnp.concatenate(rows_a, axis=3), 0.0) * beta[..., None]
+    Bm = jnp.where(j <= i, jnp.concatenate(rows_b, axis=3), 0.0)
+
+    # ---- (I + A) [Wv | Wk] = beta [V | K e^G]
+    rhs = jnp.concatenate([v, k * jnp.exp(G)], axis=-1) * beta[..., None]
+    solved = jax.lax.linalg.triangular_solve(
+        A + jnp.eye(C, dtype=F32), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    Wv, Wk = solved[..., :V], solved[..., V:]
+    Qp = q * jnp.exp(G)
+    G_end = G[..., -1:, :]
+    Kd = k * jnp.exp(G_end - G)
+    decay = jnp.exp(G_end[..., 0, :])  # [B, NC, H, K]
+
+    # ---- the recurrence over chunks
+    def step(S, xs):
+        Wv_c, Wk_c, Qp_c, B_c, Kd_c, decay_c = xs
+        U = Wv_c - mm("bhck,bhkv->bhcv", lo(Wk_c), lo(S))
+        O = mm("bhck,bhkv->bhcv", lo(Qp_c), lo(S)) \
+            + mm("bhij,bhjv->bhiv", lo(B_c), lo(U))
+        S = decay_c[..., None] * S + mm("bhck,bhcv->bhkv", lo(Kd_c), lo(U))
+        return S, O
+
+    first = lambda a: jnp.moveaxis(a, 1, 0)
+    _, O = jax.lax.scan(step, jnp.zeros((B, H, K, V), F32),
+                        tuple(map(first, (Wv, Wk, Qp, Bm, Kd, decay))))
+    # [NC, B, H, C, V] -> [B, T, H, V]
+    O = jnp.moveaxis(O, 0, 1).swapaxes(2, 3).reshape(B, NC * C, H, V)
+    return O[:, :T]
+
+
+def causal_conv_silu(x, taps):
+    """Depthwise causal convolution over positions, then SiLU. ``x``
+    [B, T, D], ``taps`` [k, D]: ``y_t = sum_i taps[i] x_(t - k + 1 + i)``."""
+    k = taps.shape[0]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    T = x.shape[1]
+    y = sum(xp[:, i:i + T] * taps[i] for i in range(k))
+    return jax.nn.silu(y)
+
+
+class KDAMixer(nn.Module):
+    heads: int
+    head_dim: int
+    conv: int = 4
+    lower_bound: float = -5.0
+    chunk: int = 64
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, _ = x.shape
+        H, K = self.heads, self.head_dim
+        taps_init = nn.initializers.normal(1.0 / math.sqrt(self.conv))
+        heads_of = lambda y: y.reshape(B, T, H, K)
+        qkv = [heads_of(_dense(H * K, f"kda_{n}", self)(x)) for n in "qkv"]
+        taps = [self.param(f"{n}_conv", taps_init, (self.conv, H * K),
+                           self.param_dtype).astype(self.dtype)
+                .reshape(1, self.conv, H, K) for n in "qkv"]
+        a = heads_of(_dense(H * K, "kda_a", self)(x))
+        beta = jax.nn.sigmoid(_dense(H, "kda_b", self)(x).astype(F32))
+        gate = jax.nn.sigmoid(_dense(H, "kda_g", self)(x).astype(F32))
+        scale = self.param("o_norm", nn.initializers.ones, (K,),
+                           self.param_dtype)
+
+        def heads(q, k, v, tq, tk, tv, a, beta, gate):
+            # everything between the projections and the output
+            # projection, a block of heads at a time: what is float32 (the
+            # unit q and k, the log-decay, the state, the output before its
+            # norm and gate) is made and used up here
+            with jax.named_scope("kda.conv"):
+                conv = lambda y, t: causal_conv_silu(
+                    y.reshape(B, T, -1), t.reshape(self.conv, -1)
+                ).reshape(y.shape)
+                unit = lambda t: t.astype(F32) * jax.lax.rsqrt(jnp.sum(
+                    jnp.square(t.astype(F32)), -1, keepdims=True) + 1e-6)
+                q, k, v = conv(q, tq), conv(k, tk), conv(v, tv)
+                q, k = unit(q) * K ** -0.5, unit(k)
+            g = self.lower_bound * jax.nn.sigmoid(a.astype(F32))
+            with jax.named_scope("kda.scan"):
+                o = kda_heads(q, k, v, g, beta, chunk=self.chunk,
+                              dtype=self.dtype)
+            return (rms_norm(o, scale, self.eps)
+                    * gate[..., None]).astype(self.dtype)
+
+        o = head_blocks(heads, 4, *qkv, *taps, a, beta, gate)
+        return _dense(x.shape[-1], "kda_o", self)(o.reshape(B, T, H * K))
+
+
+# --------------------------------------------------------------------------
+# latent attention
+
+
+def rope_interleaved(x, theta):
+    """Rotary embedding over the last axis, pairs ``(x_0, x_1), (x_2,
+    x_3), ..``; ``x`` [B, T, H, R], positions ``0 .. T - 1``."""
+    R = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, R, 2, dtype=F32) / R)
+    ang = jnp.arange(x.shape[1], dtype=F32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x0, x1 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
+                     axis=-1).reshape(x.shape)
+
+
+def causal_attention(q, k, v, scale, block=256):
+    """Causal softmax attention, a block of queries at a time (a scan,
+    its body recomputed on the way back), so that scores are never
+    [T, T] whole. Each block meets every key under the mask: slicing the
+    keys to a block's own past makes a copy of them a block.
+    ``q, k`` [B, T, H, D], ``v`` [B, T, H, Dv]; scores and softmax
+    float32."""
+    B, T, H, D = q.shape
+    block = min(block, T)
+    pad = -T % block
+    qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+        B, -1, block, H, D).swapaxes(0, 1)  # [blocks, B, block, H, D]
+    kpos = jnp.arange(T)[None, :]
+
+    @jax.checkpoint
+    def rows(first, qi):
+        s = jnp.einsum("bqhd,bkhd->bhqk", qi, k,
+                       preferred_element_type=F32) * scale
+        qpos = first + jnp.arange(block)[:, None]
+        p = jax.nn.softmax(jnp.where(kpos <= qpos, s, -jnp.inf), axis=-1)
+        return first + block, jnp.einsum(
+            "bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+            preferred_element_type=F32)
+
+    _, out = jax.lax.scan(rows, jnp.int32(0), qb)
+    return out.swapaxes(0, 1).reshape(B, -1, H, v.shape[-1])[:, :T]
+
+
+class MLAMixer(nn.Module):
+    heads: int
+    nope: int = 128
+    rope: int = 64
+    v_dim: int = 128
+    kv_rank: int = 512
+    theta: float = 6e6
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, _ = x.shape
+        H, N, R, Dv = self.heads, self.nope, self.rope, self.v_dim
+        norm = lambda name, width: self.param(
+            name, nn.initializers.ones, (width,), self.param_dtype)
+        q = _dense(H * (N + R), "mla_q", self)(x).reshape(B, T, H, N + R)
+        ckr = _dense(self.kv_rank + R, "mla_dkv", self)(x)
+        c = rms_norm(ckr[..., :self.kv_rank], norm("c_norm", self.kv_rank),
+                     self.eps).astype(self.dtype)
+        kv = _dense(H * (N + Dv), "mla_ukv", self)(c).reshape(B, T, H, N + Dv)
+        k = jnp.concatenate([kv[..., :N], jnp.broadcast_to(
+            ckr[:, :, None, self.kv_rank:], (B, T, H, R))], axis=-1)
+        q = rms_norm(q, norm("q_norm", N + R), self.eps)
+        k = rms_norm(k, norm("k_norm", N + R), self.eps)
+        turn = lambda a: jnp.concatenate(
+            [a[..., :N], rope_interleaved(a[..., N:], self.theta)],
+            axis=-1).astype(self.dtype)
+        gate = jax.nn.sigmoid(_dense(H, "mla_g", self)(x).astype(F32))
+        with jax.named_scope("mla.attn"):
+            o = causal_attention(turn(q), turn(k), kv[..., N:],
+                                 (N + R) ** -0.5)
+        o = (o * gate[..., None]).astype(self.dtype).reshape(B, T, H * Dv)
+        return _dense(x.shape[-1], "mla_o", self)(o)
+
+
+# --------------------------------------------------------------------------
+# the expert layer
+
+
+def route(x, router, bias, n_group, topk_group, top_k, scale):
+    """DeepSeek-V3's router: sigmoid scores over ALL experts, a
+    selection bias added for the choice only, groups scored by the sum
+    of their two largest, ``topk_group`` groups kept, the ``top_k``
+    largest among them chosen, weights ``scale * s_i / sum_chosen s_j``.
+    float32 throughout. Returns the chosen ids and weights, [N, top_k]."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(F32), router.astype(F32),
+                               precision=HI))
+    sel = s + bias.astype(F32)
+    n, e = sel.shape
+    grouped = sel.reshape(n, n_group, e // n_group)
+    top2 = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(top2, topk_group)
+    keep = jnp.zeros((n, n_group), bool).at[
+        jnp.arange(n)[:, None], best].set(True)
+    sel = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(n, e)
+    _, idx = jax.lax.top_k(sel, top_k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    return idx, scale * w / jnp.sum(w, axis=1, keepdims=True)
+
+
+def _dispatch(idx, n_experts, held, offset, top_k):
+    """The chosen pairs sorted by held expert: ``order`` (pair ids, the
+    pairs of absent experts last, padded to whole blocks), the held
+    experts' ``counts`` and running ``ends``, and the static block shape:
+    a block holds twice the pairs expected under even routing (a
+    multiple of 128), and the blocks cover every pair that can be
+    chosen."""
+    N = idx.shape[0]
+    local = idx - offset
+    here = jnp.logical_and(local >= 0, local < held)
+    pair_e = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(pair_e, stable=True)
+    counts = jnp.bincount(pair_e, length=held + 1)[:held]
+    most = N * min(top_k, held)
+    rows = min(most, -(-2 * N * top_k * held // n_experts // 128) * 128)
+    n_blocks = -(-most // rows)
+    order = jnp.pad(order, (0, max(n_blocks * rows - order.shape[0], 0)))
+    return order, counts, jnp.cumsum(counts), rows, n_blocks
+
+
+def _block(x, pair_w, first, order, counts, ends, w_gu, w_d, *, rows, top_k,
+           dtype):
+    """Rows ``first .. first + rows`` of the sorted pairs: gather their
+    tokens, one grouped product a projection, weigh, scatter back.
+    ``pair_w`` [rows]: the pairs' weights. Returns [N, d] float32."""
+    with jax.named_scope("moe.dispatch"):
+        # rows past the last chosen pair compute nothing and give and
+        # take nothing (a grouped product leaves them unspecified)
+        live = (first + jnp.arange(rows) < ends[-1])[:, None]
+        tok = jax.lax.dynamic_slice(order, (first,), (rows,)) // top_k
+        xs = jnp.where(live, x[tok], 0).astype(dtype)
+        sizes = (jnp.clip(ends - first, 0, rows)
+                 - jnp.clip(ends - counts - first, 0, rows)).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        h = swiglu(jax.lax.ragged_dot(
+            xs, w_gu.astype(dtype), sizes,
+            preferred_element_type=F32)).astype(dtype)
+        ys = jax.lax.ragged_dot(h, w_d.astype(dtype), sizes,
+                                preferred_element_type=F32)
+    with jax.named_scope("moe.combine"):
+        ys = jnp.where(live, ys * pair_w[:, None], 0.0)
+        return jnp.zeros(x.shape, F32).at[tok].add(ys)
+
+
+def held_experts(x, frozen, *, offset, n_group, topk_group, top_k, scale,
+                 dtype):
+    """The held experts' part of the layer's output for ``x`` [N, d]
+    and what the dispatch counted: ``y`` [N, d] and ``stats`` [2]
+    (chosen pairs not computed, which has to be 0; the largest load of
+    a held expert over their mean).
+
+    The chosen (token, held expert) pairs are sorted by expert and run
+    through one grouped product a projection, a block of rows at a time
+    (``_dispatch``); a block past the last pair is skipped. Nothing is
+    dropped, whatever the imbalance."""
+    w_gu, w_d = frozen["gate_up"], frozen["down"]
+    held, n_experts = w_gu.shape[0], frozen["router"].shape[1]
+    with jax.named_scope("moe.route"):
+        idx, w = route(x, frozen["router"], frozen["bias"], n_group,
+                       topk_group, top_k, scale)
+    with jax.named_scope("moe.dispatch"):
+        order, counts, ends, rows, n_blocks = _dispatch(
+            idx, n_experts, held, offset, top_k)
+        pair_w = jnp.pad(w.reshape(-1), (0, order.shape[0] - w.size))[order]
+    block = functools.partial(_block, rows=rows, top_k=top_k, dtype=dtype)
+
+    def one(y, first):
+        return y + jax.lax.cond(
+            first < ends[-1],
+            lambda: block(x, jax.lax.dynamic_slice(pair_w, (first,), (rows,)),
+                          first, order, counts, ends, w_gu, w_d),
+            lambda: jnp.zeros(x.shape, F32)), None
+
+    y, _ = jax.lax.scan(one, jnp.zeros(x.shape, F32),
+                        jnp.arange(n_blocks) * rows)
+    total = ends[-1]
+    computed = jnp.minimum(total, n_blocks * rows)
+    load = jnp.max(counts) / jnp.maximum(jnp.mean(counts.astype(F32)), 1e-9)
+    stats = jnp.stack([(total - computed).astype(F32), load.astype(F32)])
+    return y.astype(dtype), stats
+
+
+def held_experts_back(x, g, frozen, *, offset, n_group, topk_group, top_k,
+                      scale, dtype):
+    """The gradient of ``held_experts``'s ``y`` to its rows, by
+    recomputation: the forward pass kept nothing, the base has no
+    gradient. Each block is differentiated where it is recomputed (to
+    its rows and its pairs' weights), so that nothing is kept from block
+    to block either; the weights' gradients then go back through the
+    router's scores."""
+    w_gu, w_d = frozen["gate_up"], frozen["down"]
+    held, n_experts = w_gu.shape[0], frozen["router"].shape[1]
+    g = g.astype(F32)
+    with jax.named_scope("moe.route"):
+        w, route_back, idx = jax.vjp(
+            lambda x_: route(x_, frozen["router"], frozen["bias"], n_group,
+                             topk_group, top_k, scale)[::-1], x, has_aux=True)
+    with jax.named_scope("moe.dispatch"):
+        order, counts, ends, rows, n_blocks = _dispatch(
+            idx, n_experts, held, offset, top_k)
+        pair_w = jnp.pad(w.reshape(-1), (0, order.shape[0] - w.size))[order]
+    block = functools.partial(_block, rows=rows, top_k=top_k, dtype=dtype)
+
+    def one(carry, first):
+        dx, dpw = carry
+
+        def run():
+            _, back = jax.vjp(
+                lambda x_, pw_: block(x_, pw_, first, order, counts, ends,
+                                      w_gu, w_d),
+                x, jax.lax.dynamic_slice(pair_w, (first,), (rows,)))
+            dx_b, dpw_b = back(g)
+            return (dx + dx_b.astype(F32),
+                    jax.lax.dynamic_update_slice(dpw, dpw_b, (first,)))
+
+        return jax.lax.cond(first < ends[-1], run, lambda: (dx, dpw)), None
+
+    (dx, dpw), _ = jax.lax.scan(
+        one, (jnp.zeros(x.shape, F32), jnp.zeros(pair_w.shape, F32)),
+        jnp.arange(n_blocks) * rows)
+    with jax.named_scope("moe.route"):
+        dw = jnp.zeros(pair_w.shape, F32).at[order].set(dpw)[:w.size]
+        dx = dx + route_back(dw.reshape(w.shape))[0].astype(F32)
+    return dx.astype(x.dtype)
+
+
+def _frozen_experts(**static):
+    """``held_experts`` as a frozen layer: rows of all nodes in one
+    dispatch under ``vmap`` (``fold_rows``), the gradient to the rows
+    only and by recomputation (the base has none and keeps nothing)."""
+    fwd = fold_rows(lambda x, fr: held_experts(x, fr, **static),
+                    lambda out: (True, False))
+    bwd = fold_rows(
+        lambda rows, fr: held_experts_back(rows[0], rows[1], fr, **static),
+        lambda out: True)
+
+    @jax.custom_vjp
+    def layer(x, fr):
+        return fwd(x, fr)
+
+    layer.defvjp(lambda x, fr: (fwd(x, fr), (x, fr)),
+                 lambda res, g: (bwd((res[0], g[0]), res[1]), None))
+    return layer
+
+
+class ExpertFFN(nn.Module):
+    n_experts: int
+    experts_held: int
+    expert_offset: int
+    width: int
+    shared_width: int
+    top_k: int
+    n_group: int
+    topk_group: int
+    scale: float
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, d = x.shape
+        he = nn.initializers.variance_scaling(1.0, "fan_in", "normal",
+                                              in_axis=-2, out_axis=-1,
+                                              batch_axis=(0,))
+        frozen = {
+            "router": self.param("router", nn.initializers.lecun_normal(),
+                                 (d, self.n_experts), self.param_dtype),
+            # the selection bias: frozen and seeded (trained models balance
+            # their load with it; it takes no gradient)
+            "bias": self.param("router_bias", nn.initializers.normal(0.01),
+                               (self.n_experts,), self.param_dtype),
+            "gate_up": self.param("experts_gate_up", he,
+                                  (self.experts_held, d, 2 * self.width),
+                                  self.param_dtype),
+            "down": self.param("experts_down", he,
+                               (self.experts_held, self.width, d),
+                               self.param_dtype),
+        }
+        layer = _frozen_experts(
+            offset=self.expert_offset, n_group=self.n_group,
+            topk_group=self.topk_group, top_k=self.top_k, scale=self.scale,
+            dtype=self.dtype)
+        y, stats = layer(x.reshape(B * T, d), frozen)
+        with jax.named_scope("moe.shared"):
+            shared = _dense(d, "shared_down", self)(swiglu(
+                _dense(2 * self.shared_width, "shared_gate_up", self)(x)))
+        return y.reshape(B, T, d) + shared, stats
+
+
+class DenseFFN(nn.Module):
+    width: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        return _dense(x.shape[-1], "down", self)(swiglu(
+            _dense(2 * self.width, "gate_up", self)(x)))
+
+
+# --------------------------------------------------------------------------
+# the model
+
+
+class LingBlock(nn.Module):
+    mixer: str  # "kda" | "mla"
+    experts: bool
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.cfg
+        kw = dict(dtype=c["dtype"], param_dtype=c["param_dtype"])
+        h = RMSNorm(c["eps"], name="mixer_norm", **kw)(x)
+        if self.mixer == "mla":
+            x = x + MLAMixer(
+                c["heads"], c["nope"], c["rope"], c["v_dim"], c["kv_rank"],
+                c["theta"], c["eps"], name="mla", **kw)(h)
+        else:
+            x = x + KDAMixer(
+                c["heads"], c["head_dim"], c["conv"], c["kda_lower_bound"],
+                c["kda_chunk"], c["eps"], name="kda", **kw)(h)
+        h = RMSNorm(c["eps"], name="ffn_norm", **kw)(x)
+        if self.experts:
+            y, stats = ExpertFFN(
+                c["n_experts"], c["experts_held"], c["expert_offset"],
+                c["expert_width"], c["shared_width"], c["top_k"],
+                c["n_group"], c["topk_group"], c["route_scale"],
+                name="moe", **kw)(h)
+            return x + y, stats
+        return x + DenseFFN(c["dense_width"], name="ffn", **kw)(h), None
+
+
+class LingLM(nn.Module):
+    """Token ids [B, T] -> logits [B, T, vocab] (``__call__``: small
+    sizes only), or with labels the mean next-token loss, head and loss a
+    chunk of positions at a time (``loss``)."""
+
+    vocab: int = 64
+    hidden: int = 32
+    layers: int = 4  # layers kept, taken from ``first_layer`` on
+    first_layer: int = 0  # index in the published stack of the first
+    layer_group: int = 3  # every ``layer_group``-th layer is MLA
+    first_dense: int = 1  # published layers below this have a dense FFN
+    heads: int = 2
+    head_dim: int = 16  # KDA's key and value width
+    nope: int = 16
+    rope: int = 8
+    v_dim: int = 16
+    kv_rank: int = 12
+    theta: float = 6e6
+    conv: int = 4
+    kda_lower_bound: float = -5.0
+    kda_chunk: int = 64
+    dense_width: int = 48
+    n_experts: int = 16
+    experts_held: int = 4
+    expert_offset: int = 0
+    expert_width: int = 8
+    shared_width: int = 8
+    top_k: int = 4
+    n_group: int = 4
+    topk_group: int = 2
+    route_scale: float = 2.5
+    eps: float = 1e-6
+    loss_chunk: int = 1024
+    remat: bool = True
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        cfg = {f: getattr(self, f) for f in (
+            "heads", "head_dim", "nope", "rope", "v_dim", "kv_rank", "theta",
+            "conv", "kda_lower_bound", "kda_chunk", "dense_width", "n_experts",
+            "experts_held", "expert_offset", "expert_width", "shared_width",
+            "top_k", "n_group", "topk_group", "route_scale", "eps", "dtype",
+            "param_dtype")}
+        block = nn.remat(LingBlock) if self.remat else LingBlock
+        self.embed = nn.Embed(self.vocab, self.hidden, dtype=self.dtype,
+                              param_dtype=self.param_dtype,
+                              embedding_init=nn.initializers.normal(1.0))
+        self.blocks = [
+            block("mla" if (i + 1) % self.layer_group == 0 else "kda",
+                  i >= self.first_dense, cfg, name=f"layer_{i}")
+            for i in range(self.first_layer, self.first_layer + self.layers)]
+        self.final_norm = RMSNorm(self.eps, dtype=self.dtype,
+                                  param_dtype=self.param_dtype)
+        self.head = self.param(
+            "head", nn.initializers.lecun_normal(), (self.hidden, self.vocab),
+            self.param_dtype)
+
+    def hidden_states(self, tokens):
+        x = self.embed(tokens.astype(jnp.int32))
+        stats = []
+        for blk in self.blocks:
+            x, s = blk(x)
+            if s is not None:
+                stats.append(s)
+        return self.final_norm(x), stats
+
+    def _logits(self, h):
+        return jnp.dot(h, self.head.astype(self.dtype),
+                       preferred_element_type=F32)
+
+    def __call__(self, tokens):
+        return self._logits(self.hidden_states(tokens)[0])
+
+    def loss(self, tokens, labels, mask=None):
+        """Mean over the kept rows of each row's mean cross-entropy over
+        its positions, and the expert layers' counters
+        ``{"moe.dropped_pairs", "moe.load_max_over_mean"}`` [expert
+        layers]. ``[tokens, vocab]`` logits are never whole."""
+        h, stats = self.hidden_states(tokens)
+        T = h.shape[1]
+
+        @jax.checkpoint
+        def chunk_loss(hc, yc):
+            logits = self._logits(hc)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            ll = jnp.take_along_axis(logits, yc[..., None], axis=-1)[..., 0]
+            return jnp.sum(lse - ll, axis=1)
+
+        with jax.named_scope("lm.head_loss"):
+            per_row = sum(
+                chunk_loss(h[:, t:t + self.loss_chunk],
+                           labels[:, t:t + self.loss_chunk].astype(jnp.int32))
+                for t in range(0, T, self.loss_chunk)) / T
+        m = jnp.ones(per_row.shape, F32) if mask is None else mask.astype(F32)
+        loss = jnp.sum(per_row * m) / jnp.maximum(jnp.sum(m), 1.0)
+        aux = {}
+        if stats:
+            stats = jnp.stack(stats)
+            aux = {"moe.dropped_pairs": stats[:, 0],
+                   "moe.load_max_over_mean": stats[:, 1]}
+        return loss, aux
+
+
+@register_model("ling-3.0-flash", "ling")
+def _ling(num_classes: int | None = None, **kw) -> LingLM:
+    del num_classes  # the vocabulary is the model's own
+    return LingLM(**kw)
+
+
+# adapters ride on the mixers' projections: KDA's q, k, v, o and MLA's
+# q, kv-down, kv-up, o; every kernel is a plain [d_in, d_out]
+register_lora_targets(
+    "ling-3.0-flash", "ling",
+    default=("kda_q", "kda_k", "kda_v", "kda_o",
+             "mla_q", "mla_dkv", "mla_ukv", "mla_o"),
+)

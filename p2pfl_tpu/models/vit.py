@@ -110,7 +110,9 @@ class ViT(nn.Module):
         if self.scan_layers:
             scanned = nn.scan(
                 _BlockStep,
-                variable_axes={"params": 0},
+                # "lora": the adapters' collection (learning.lora), one
+                # pair a layer like the kernels they ride on
+                variable_axes={"params": 0, "lora": 0},
                 split_rngs={"params": True},
                 length=self.depth,
             )

@@ -397,6 +397,7 @@ _program_depth = 0
 _trace_lower_spans: list[tuple[float, float]] = []
 _cache_load_s = 0.0
 _stage_s: dict[str, float] = {}
+_counted: dict[str, dict] = {}
 
 
 def _add_trace_lower(duration: float) -> None:
@@ -505,6 +506,40 @@ def stage_seconds() -> dict[str, float]:
     """Seconds spent under each ``stage()`` name since process start."""
     with _xla_lock:
         return dict(_stage_s)
+
+
+def note_counted(values: dict) -> None:
+    """Fold one round's model counters into ``counted()``. ``values``:
+    ``{name: [nodes, epochs, steps, ...]}`` host arrays that rode with
+    the round's metrics fetch (what a model counts in a step: an expert
+    layer's dropped pairs, its largest load over the mean, a layer). A
+    layer that sees all nodes' rows together counts the same on every
+    node, so the node axis is reduced by its largest; per name the
+    running ``sum`` and ``max`` over steps (trailing axes kept: a
+    layer) and the ``steps`` seen. Kept since the process started,
+    whether or not anything is tracing; also counters/gauges of the
+    tracer when it is enabled."""
+    import numpy as np
+
+    for name, v in values.items():
+        v = np.asarray(v, np.float64)
+        v = v.max(axis=0).reshape((-1,) + v.shape[3:])  # [steps, ...]
+        with _xla_lock:
+            at = _counted.setdefault(name, {
+                "sum": np.zeros(v.shape[1:]), "steps": 0,
+                "max": np.full(v.shape[1:], -np.inf)})
+            at["sum"] = at["sum"] + v.sum(axis=0)
+            at["max"] = np.maximum(at["max"], v.max(axis=0))
+            at["steps"] += v.shape[0]
+        _TRACER.count(name, float(v.sum()))
+        _TRACER.high_water(name + ".max", float(v.max()))
+
+
+def counted() -> dict[str, dict]:
+    """``{name: {"sum", "max", "steps"}}`` of every model counter seen
+    since process start (``note_counted``)."""
+    with _xla_lock:
+        return {k: dict(v) for k, v in _counted.items()}
 
 
 def trace_lower_seconds() -> float:

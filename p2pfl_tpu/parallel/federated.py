@@ -506,6 +506,11 @@ def build_round_fn(
             "train_loss": train_metrics["loss"],  # [n]
             "alive": alive,
         }
+        if "counted" in train_metrics:
+            # what the model itself counts in a step (an expert layer's
+            # dropped pairs and load): [n, epochs, steps, ...], fetched
+            # with the losses
+            metrics["counted"] = train_metrics["counted"]
         if update_stats:
             from p2pfl_tpu.adversary.reputation import spmd_trust_obs
 

@@ -82,3 +82,40 @@ def test_femnist_cnn_step_has_no_conv1_sized_relayout(one_chip,
             relaid.append(m.group(0))
     assert "convolution" in hlo  # the text is the optimized module
     assert not relaid, relaid
+
+
+def test_ling_expert_layer_is_one_grouped_product_over_all_nodes(
+        one_chip, no_persistent_cache):
+    """The Ling cell's expert layer at its own shapes (8 nodes x 4096
+    tokens, hidden 2560, 64 held experts of width 768, 512 routed over,
+    8 a token), vmapped over the nodes as the round does: XLA:TPU's own
+    grouped matmul (a ``ragged-dot`` custom call, not a dense expansion
+    over the experts), as many of them as ONE node's call has, each over
+    the rows of all nodes; forward and the way back."""
+    from p2pfl_tpu.models import ling
+
+    n, T, d, held, width = 8, 4096, 2560, 64, 768
+    layer = ling._frozen_experts(offset=0, n_group=8, topk_group=4, top_k=8,
+                                 scale=2.5, dtype=jnp.bfloat16)
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                               sharding=one_chip)
+    frozen = {"router": bf16(d, 512), "bias": bf16(512),
+              "gate_up": bf16(held, d, 2 * width), "down": bf16(held, width, d)}
+
+    def step(x, g, frozen):
+        y, back = jax.vjp(lambda x_: jax.vmap(
+            lambda xb: layer(xb, frozen)[0])(x_), x)
+        return y, back(g)[0]
+
+    compiled = jax.jit(step).lower(bf16(n, T, d), bf16(n, T, d),
+                                   frozen).compile()
+    hlo = compiled.as_text()
+    rows = 2 * n * T  # a block: twice the even share of the 8 x 4096 x 8 pairs
+    grouped = re.findall(r"ragged-dot\S* = \w+\[(\d+),(\d+)\]\S* custom-call",
+                         hlo)
+    # gate-up and down on the way forward; on the way back the forward
+    # again and the two input gradients
+    assert sorted(grouped) == sorted(
+        [(str(rows), str(2 * width)), (str(rows), str(d))] * 2
+        + [(str(rows), str(width)), (str(rows), str(d))]), grouped
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9

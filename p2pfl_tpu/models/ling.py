@@ -95,6 +95,74 @@ def head_blocks(core, head_block, *arrays):
     return o.reshape(o.shape[:2] + (H, o.shape[-1]))
 
 
+def unit_lower_inverse(A):
+    """``(I + A)^-1`` for strictly lower-triangular ``A`` [..., C, C],
+    ``C`` a power of two, by block forward substitution: ``[[L11, 0],
+    [L21, L22]]^-1 = [[T11, 0], [-T22 L21 T11, T22]]``, all diagonal
+    blocks of one size at a time from size 1 (the diagonal is 1) up.
+
+    The systems lie along the last axis meanwhile (``[C, C, systems]``)
+    and a product of blocks is a float32 multiply and sum over whole
+    vectors of systems, not a batched matmul: at ``HIGHEST`` the v5e's
+    matrix unit takes longer over a system's 16- or 32-row blocks than
+    over its 64 rows at once, and the merges ran 2 to 8 times slower
+    there (``PERF.md`` Findings PR 36)."""
+    C = A.shape[-1]
+    if C & (C - 1):
+        raise ValueError(f"a chunk of {C} rows cannot be halved down to 1: "
+                         "the chunk length has to be a power of two")
+    L = jnp.moveaxis(A.reshape((-1, C, C)), 0, -1)
+    n = L.shape[-1]
+    product = lambda X, Y: jnp.sum(X[:, :, :, None] * Y[:, None], axis=2)
+    T = jnp.ones((C, 1, 1, n), F32)  # [blocks, s, s, systems]
+    s = 1
+    while s < C:
+        # lax's own slice and concatenate: 63 blocks are cut out of L a
+        # call, and a jnp index costs several times as much to trace
+        L21 = jax.lax.concatenate(
+            [jax.lax.slice(L, (b + s, b, 0), (b + 2 * s, b + s, n))
+             for b in range(0, C, 2 * s)], 0).reshape(-1, s, s, n)
+        T = T.reshape(-1, 2, s, s, n)
+        T11, T22 = T[:, 0], T[:, 1]
+        T21 = -product(T22, product(L21, T11))
+        T = jax.lax.concatenate([
+            jax.lax.concatenate([T11, jnp.zeros_like(T11)], 2),
+            jax.lax.concatenate([T21, T22], 2)], 1)
+        s *= 2
+    return jnp.moveaxis(T[0], -1, 0).reshape(A.shape)
+
+
+@jax.custom_vjp
+def unit_lower_solve(A, rhs):
+    """``X`` of ``(I + A) X = rhs`` for strictly lower-triangular ``A``
+    [..., C, C] and ``rhs`` [..., C, W], float32: the inverse
+    (:func:`unit_lower_inverse`), then one product at ``HIGHEST``. The
+    way back keeps the inverse and ``X`` and is two more such products:
+    ``d_rhs = T^T g``, ``d_A = -d_rhs X^T`` below the diagonal."""
+    return _solve(A, rhs)[0]
+
+
+def _solve(A, rhs):
+    with jax.named_scope("kda.solve"):
+        T = unit_lower_inverse(A)
+        X = jnp.matmul(T, rhs, precision=HI)
+    return X, (T, X)
+
+
+def _solve_back(kept, g):
+    T, X = kept
+    # a hand-written way back does not inherit the name stack of the way
+    # forward: the scopes the device time is read by are set here
+    with jax.named_scope("kda.scan"), jax.named_scope("kda.solve"):
+        d_rhs = jnp.einsum("...ji,...jw->...iw", T, g, precision=HI)
+        d_A = -jnp.tril(jnp.einsum("...iw,...jw->...ij", d_rhs, X,
+                                   precision=HI), -1)
+    return d_A, d_rhs
+
+
+unit_lower_solve.defvjp(_solve, _solve_back)
+
+
 def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16,
                 head_block=8):
     """:func:`kda_heads`, a block of heads at a time."""
@@ -120,9 +188,14 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
     ``e^(-G_j)`` alone, which overflows within a chunk: rows are taken
     a sub-chunk of ``sub`` at a time against the running sum at that
     sub-chunk's start, so that each factor's exponent lies within
-    ``sub`` steps of decay. The state, the running sums, the
-    exponentials and the triangular solve are float32; the products take
-    ``dtype`` operands and accumulate in float32."""
+    ``sub`` steps of decay. The system is solved by forming ``(I +
+    A)^-1`` block by block and one product with the right-hand side
+    (:func:`unit_lower_solve`; ``chunk`` a power of two), not by the
+    powers of ``A``: with ``|A_ij|`` up to ``b_i`` < 1 they reach
+    binomial size over 64 rows and cancel in float32. The state, the
+    running sums, the exponentials and the solve are float32 whatever
+    ``dtype`` is; the other products take ``dtype`` operands and
+    accumulate in float32."""
     B, T, H, K = q.shape
     V = v.shape[-1]
     C = chunk
@@ -159,9 +232,7 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
 
     # ---- (I + A) [Wv | Wk] = beta [V | K e^G]
     rhs = jnp.concatenate([v, k * jnp.exp(G)], axis=-1) * beta[..., None]
-    solved = jax.lax.linalg.triangular_solve(
-        A + jnp.eye(C, dtype=F32), rhs, left_side=True, lower=True,
-        unit_diagonal=True)
+    solved = unit_lower_solve(A, rhs)
     Wv, Wk = solved[..., :V], solved[..., V:]
     Qp = q * jnp.exp(G)
     G_end = G[..., -1:, :]

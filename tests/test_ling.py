@@ -45,13 +45,18 @@ def ref_name(p):
     return ".".join(keys)
 
 
-@pytest.fixture(scope="module")
-def ref():
+def benchmark_module(file):
     spec = importlib.util.spec_from_file_location(
-        "ling_flash_ref", ROOT / "benchmark" / "reference" / "ling_flash.py")
+        "bench_" + pathlib.Path(file).stem.replace(".", "_"),
+        ROOT / "benchmark" / file)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return benchmark_module("reference/ling_flash.py")
 
 
 @pytest.fixture(autouse=True)
@@ -126,6 +131,85 @@ def test_chunked_kda_is_the_recurrence_forward_and_backward(ref, T):
     np.testing.assert_allclose(
         ling.kda_chunked(*args, dtype=F32, head_block=1),
         ling.kda_chunked(*args, dtype=F32), rtol=1e-5, atol=1e-6)
+
+
+def chunk_systems(case, n=6, C=64, K=16, seed=0):
+    """``n`` chunks' systems as ``kda_heads`` builds them: ``A`` [n, C, C]
+    strictly lower, ``A_ij = b_i sum_c k_ic k_jc e^(G_ic - G_jc)``, and
+    the right-hand side ``b [V | K e^G]`` [n, C, 2 K]."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    k = jax.random.normal(ks[0], (n, 1 if case == "equal keys" else C, K))
+    k = jnp.broadcast_to(k / jnp.linalg.norm(k, axis=-1, keepdims=True),
+                         (n, C, K))
+    v = jax.random.normal(ks[1], (n, C, K))
+    if case == "equal keys":  # beta within 1e-3 of 1, no decay
+        beta = 1.0 - 1e-3 * jax.random.uniform(ks[2], (n, C))
+        g = jnp.zeros((n, C, K))
+    else:
+        beta = jax.nn.sigmoid(jax.random.normal(ks[2], (n, C)))
+        shift = 12.0 if case == "fastest decay" else 0.0  # g within 1e-4 of -5
+        g = -5.0 * jax.nn.sigmoid(
+            jax.random.normal(ks[3], (n, C, K)) + shift)
+    G = jnp.cumsum(g, axis=1)
+    pair = jnp.exp(jnp.minimum(G[:, :, None] - G[:, None, :], 0.0))
+    A = jnp.tril(jnp.einsum("nik,njk,nijk->nij", k, k, pair), -1)
+    rhs = jnp.concatenate([v, k * jnp.exp(G)], axis=-1)
+    return A * beta[..., None], rhs * beta[..., None]
+
+
+@pytest.mark.parametrize("case", ["random", "equal keys", "fastest decay"])
+def test_unit_lower_solve_is_the_triangular_solve(case):
+    """The product form against ``jax.lax.linalg.triangular_solve`` in
+    float32, forward and both gradients, on chunks like ``kda_inputs``',
+    on the near-worst case for cancellation (all keys of a chunk equal,
+    ``beta`` at 1, no decay: every entry of ``A`` is near 1 and the
+    inverse is all but bidiagonal) and with the decay at its bound."""
+    A, rhs = chunk_systems(case)
+    weigh = jax.random.normal(jax.random.PRNGKey(7), rhs.shape)
+    plain = lambda A, rhs: jax.lax.linalg.triangular_solve(
+        A + jnp.eye(A.shape[-1]), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    close = lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=0, atol=1e-5 * float(jnp.max(jnp.abs(b))))
+    close(ling.unit_lower_solve(A, rhs), plain(A, rhs))
+    got, want = (jax.grad(lambda A, rhs: jnp.sum(f(A, rhs) * weigh),
+                          argnums=(0, 1))(A, rhs)
+                 for f in (ling.unit_lower_solve, plain))
+    assert not np.triu(got[0]).any()  # the gradient to A: below the diagonal
+    close(got[0], jnp.tril(want[0], -1))
+    close(got[1], want[1])
+
+
+def test_chunk_length_has_to_be_a_power_of_two():
+    with pytest.raises(ValueError, match="power of two"):
+        ling.kda_heads(*kda_inputs(48), chunk=48)
+
+
+def test_the_solves_device_time_is_read_by_its_scope(monkeypatch):
+    """``kda.solve_device_s_per_round`` finds the scope nested in
+    ``kda.scan`` on the way forward and as the hand-written way back
+    names it, in the round program only; ``kda.device_s_per_round`` still
+    reads all of the delta rule; a program without the scope reads
+    nothing."""
+    read = lambda metric, ctx: benchmark_module(
+        f"readers/{metric}.py").read(ctx)
+    fit = "jit(round_fn)/vmap()/while/body/fit.value_and_grad/"
+    scope_s = {
+        fit + "jvp(LingLM)/layer_2/kda/kda.scan/kda.solve/dot_general": 1.0,
+        fit + "jvp(LingLM)/layer_2/kda/kda.scan/kda.solve/reduce_sum": 2.0,
+        fit + "transpose(jvp(LingLM))/layer_2/kda/"
+              "transpose(vmap(jvp(kda.scan)))/kda.solve/dot_general": 4.0,
+        fit + "jvp(LingLM)/layer_2/kda/kda.scan/cumsum": 8.0,
+        "jit(eval_fn)/vmap()/eval.forward/LingLM/layer_2/kda/kda.scan/"
+        "kda.solve/dot_general": 16.0}
+    monkeypatch.syspath_prepend(str(ROOT / "benchmark"))  # ``scopework``
+    ctx = {"trace": {"scope_s": scope_s}, "rounds": 2}
+    assert read("kda.solve_device_s_per_round", ctx) == 3.5
+    assert read("kda.device_s_per_round", ctx) == 7.5
+    without = {k: v for k, v in scope_s.items() if "kda.solve" not in k}
+    for trace in (None, {"scope_s": without}):
+        assert read("kda.solve_device_s_per_round",
+                    {"trace": trace, "rounds": 2}) is None
 
 
 # --------------------------------------------------------------------------

@@ -119,3 +119,54 @@ def test_ling_expert_layer_is_one_grouped_product_over_all_nodes(
         [(str(rows), str(2 * width)), (str(rows), str(d))] * 2
         + [(str(rows), str(width)), (str(rows), str(d))]), grouped
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_ling_delta_rule_holds_no_triangular_solve(one_chip,
+                                                   no_persistent_cache):
+    """The Ling cell's delta rule at its own shapes as the round runs it
+    (8 nodes under ``vmap``, a sequence of 4096, a block of 4 heads of
+    128, float32 in, bfloat16 products), value and gradient. XLA:TPU's
+    ``triangular_solve`` (a custom call that inverts the diagonal blocks,
+    3.5 ms an execution on the v5e at these shapes and a seventh of the
+    cell's round, PERF.md Findings PR 36) is not in the program; the
+    solve's three products on the matrix unit, one forward and two on
+    the way back, and the inverse's block products carry ``kda.solve``,
+    the scope its device time is read by; and the step needs no more
+    temporary memory than it did with the custom call."""
+    from p2pfl_tpu.models import ling
+
+    n, T, H, K = 8, 4096, 4, 128
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+
+    def loss(q, k, v, g, beta, weigh):
+        return jnp.sum(weigh * ling.kda_chunked(q, k, v, g, beta,
+                                                dtype=jnp.bfloat16))
+
+    compiled = jax.jit(jax.vmap(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4)))).lower(
+        *[f32(n, 1, T, H, K)] * 4, f32(n, 1, T, H), f32(n, 1, T, H, K)
+    ).compile()
+    hlo = compiled.as_text()
+    assert "convolution" in hlo  # the text is the optimized module
+    assert not [target for target in re.findall(
+        r'custom_call_target="([^"]+)"', hlo) if "riangular" in target]
+    scoped = re.findall(
+        r"= f32\[([\d,]+)\]\S* (\w+)\(.*op_name=\"([^\"]*kda\.solve[^\"]*)\"",
+        hlo)
+    back = lambda name: "transpose(" in name
+    products = sorted((dims, back(name)) for dims, op, name in scoped
+                      if op == "convolution")
+    chunks = f"{n},{T // 64},{H},64"
+    assert products == [(f"{chunks},256", False), (f"{chunks},256", True),
+                        (f"{chunks},64", True)], products
+    # the inverse's block products: multiply and sum, on the way forward
+    assert any(op == "reduce" and not back(name) for _, op, name in scoped)
+    # they are the program's products of float32 operands in six passes
+    # (the others take bfloat16 operands)
+    highest = re.findall(r"operand_precision=\{highest,highest\}.*"
+                         r"op_name=\"([^\"]*)\"", hlo)
+    assert len(highest) == 3 and all(
+        "kda.solve" in name for name in highest), highest
+    # 1,662,749,184 with the custom call (the same program at 24068e8)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1_662_749_184

@@ -4,7 +4,8 @@
     python chip_smoke.py --rehearse-cpu  # same stages, toy sizes, any backend
 
 One process, the entry points a user calls, the north-star federation
-at full width (``bench.py``'s headline, written as a ``ScenarioConfig``):
+at full width (the configuration ``BASELINE.md`` states, written as a
+``ScenarioConfig``):
 
 A. ``ScenarioConfig`` -> ``Scenario`` -> ``Scenario.run()`` (what
    ``python -m p2pfl_tpu.run scenario.json`` calls): 64 nodes, DFL,
@@ -46,8 +47,8 @@ def check(ok: bool, what: str) -> None:
 
 
 def north_star_config(n_nodes: int, rounds: int):
-    """``bench.py``'s headline (``_phase_headline``/``_build``) as the
-    scenario a user would write."""
+    """The north-star federation as the scenario a user would write
+    (``benchmark/configs/femnist-cnn.json`` holds the same values)."""
     from p2pfl_tpu.config.schema import (
         DataConfig,
         ModelConfig,
@@ -66,7 +67,7 @@ def north_star_config(n_nodes: int, rounds: int):
         data=DataConfig(
             dataset="femnist", samples_per_node=spn, batch_size=336,
             # sized so samples_per_node is actually delivered after the
-            # 10% validation split (bench._build)
+            # 10% validation split
             synthetic_train=int(n_nodes * spn / 0.9) + n_nodes,
             surrogate_profile="hard",
         ),
@@ -75,7 +76,7 @@ def north_star_config(n_nodes: int, rounds: int):
             rounds=rounds, epochs_per_round=1, learning_rate=0.05,
             momentum_dtype="bf16", eval_every=0,
         ),
-        # every node trains every round, as in the bench
+        # every node trains every round
         protocol=ProtocolConfig(train_set_size=0),
         aggregator="fedavg",
         wire_dtype="bf16",

@@ -29,7 +29,7 @@ Entry points::
 
     python -m p2pfl_tpu.analysis.fedlint <paths>   # lint only
     python -m p2pfl_tpu.analysis [<paths>]         # all passes
-                                                   # (fedlint + bench-keys sync)
+                                                   # (fedlint + status-keys sync)
 
 Exit codes are healthcheck-style: 0 = clean, 1 = findings,
 2 = operational error (unparseable file, bad arguments). Suppress a

@@ -1,34 +1,30 @@
 """``python -m p2pfl_tpu.analysis`` — run every static pass.
 
-Currently three passes, run in order with the combined exit code being
-the max (healthcheck-style: 0 clean, 1 findings, 2 operational error):
+Two passes, run in order with the combined exit code being the max
+(healthcheck-style: 0 clean, 1 findings, 2 operational error):
 
 1. **fedlint** over the given paths (default ``p2pfl_tpu/``);
-2. **bench-keys** three-way sync (registry vs docs/perf.md vs the
-   regression gate's HEADLINE keys);
-3. **status-keys** three-way sync (monitor.STATUS_KEYS vs the
+2. **status-keys** three-way sync (monitor.STATUS_KEYS vs the
    publishers' emitted keys vs the renderer/health-rule reads).
 
 Extra CLI flags are forwarded to fedlint (``--json`` etc. apply to the
-lint pass only; the key passes keep their one-line text contracts).
+lint pass only; the key pass keeps its one-line text contract).
 """
 
 from __future__ import annotations
 
 import sys
 
-from p2pfl_tpu.analysis import benchkeys, fedlint, statuskeys
+from p2pfl_tpu.analysis import fedlint, statuskeys
 
 
 def main(argv: list[str] | None = None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     print("== fedlint ==")
     lint_rc = fedlint.main(argv)
-    print("== bench-keys ==")
-    bench_rc = benchkeys.main()
     print("== status-keys ==")
     status_rc = statuskeys.main()
-    return max(lint_rc, bench_rc, status_rc)
+    return max(lint_rc, status_rc)
 
 
 if __name__ == "__main__":

@@ -6,9 +6,9 @@ Usage::
         [--baseline PATH | --no-baseline] [--write-baseline]
         [--rules rule1,rule2] [--root DIR]
 
-Exit codes (healthcheck-style, for CI alongside ``healthcheck`` and
-``check_bench_regress.py``): 0 = no unsuppressed findings, 1 =
-findings, 2 = operational error (unparseable file, unknown rule).
+Exit codes (healthcheck-style, for CI alongside ``healthcheck``):
+0 = no unsuppressed findings, 1 = findings, 2 = operational error
+(unparseable file, unknown rule).
 """
 
 from __future__ import annotations

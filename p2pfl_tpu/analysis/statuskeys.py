@@ -5,8 +5,7 @@ of every key a status publisher may emit and a renderer or health rule
 may read. The failure mode it exists for is silent: rename a gauge on
 the publisher side and the monitor column renders "-" forever, the
 health rule never fires, and nothing crashes. This pass fails (exit 1)
-when any side drifts — the benchkeys discipline applied to the status
-plane:
+when any side drifts:
 
 1. a **consumed** key (best-effort AST scan of the status readers —
    utils/monitor.py, webapp.py, obs/health.py — for ``rec.get("k")`` /

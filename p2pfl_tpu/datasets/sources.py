@@ -198,8 +198,7 @@ def _synthetic_easy(name: str, n_train: int, n_test: int,
 
 
 #: hard-surrogate difficulty knobs (calibrated on the bench chip so the
-#: 64-node north-star federation plateaus ~0.85-0.92 — VERDICT r4 #5;
-#: calibration sweep: scripts/exp_surrogate_calibration.py)
+#: 64-node north-star federation plateaus ~0.85-0.92 — VERDICT r4 #5)
 _HARD = {
     "n_writers": 240,       # 80% train / 20% held out for the test set
     "style_gamma": 0.7,     # writer-specific class-rendering strength
@@ -207,9 +206,9 @@ _HARD = {
     "label_noise": 0.04,    # train-label flip rate (test labels clean)
     "sample_noise": 0.8,    # per-sample gaussian sigma
 }
-# calibration (bench chip, 64-node north star, 30-round trajectory —
-# scripts/exp_surrogate_calibration.py): gamma 0.4 -> plateau 0.948,
-# 0.55 -> 0.937, 0.7 -> 0.917 with rounds-to-80 = 13. gamma 0.7 puts
+# calibration (bench chip, 64-node north star, 30-round trajectory):
+# gamma 0.4 -> plateau 0.948, 0.55 -> 0.937, 0.7 -> 0.917 with
+# rounds-to-80 = 13. gamma 0.7 puts
 # the plateau in the 0.85-0.92 target band: 80% is now a threshold the
 # federation fights for, not a point on a saturating curve.
 

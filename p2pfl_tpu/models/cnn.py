@@ -44,7 +44,7 @@ from p2pfl_tpu.ops import pallas_gemm
 #: factor that grows with the contraction (im2col: k * k times the
 #: input; the band: k times the input and w_in / k times the MACs), so
 #: only small contractions qualify: conv2's 800-wide patches sank
-#: whole-model im2col (scripts/exp_im2col.py) and, as a Pallas stream,
+#: whole-model im2col (docs/perf.md §4) and, as a Pallas stream,
 #: asked a 16 GB v5e for a 34.5 GB patches array at the 64-node north
 #: star (PERF.md, PR 21); banded, conv2 would need 72 MFLOP a sample
 #: for 20.

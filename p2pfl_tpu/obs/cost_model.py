@@ -1,9 +1,10 @@
-"""Honest-FLOP accounting shared by the bench and the live gauges.
+"""Honest-FLOP accounting behind the live gauges.
 
-One cost model, two consumers: ``bench.py`` (the headline MFU keys)
-and ``obs.devprof`` (the live per-node MFU gauge) must agree on what a
-FLOP is, or the dashboard number silently diverges from the audited
-one. Two corrections make the raw ``cost_analysis()`` read honest:
+One cost model for ``obs.devprof`` (the live per-node MFU gauge),
+``parallel.federated.round_flops`` and ``chip_smoke.py``: they must
+agree on what a FLOP is, or the dashboard number silently diverges
+from the audited one. Two corrections make the raw ``cost_analysis()``
+read honest:
 
 1. **Count only what XLA counts correctly** (docs/perf.md §4): the
    grouped-conv lowering used before round 4 made ``cost_analysis``
@@ -23,8 +24,8 @@ one. Two corrections make the raw ``cost_analysis()`` read honest:
 
 The peak table and the watermark reader live here too so every MFU /
 HBM number in the repo shares one denominator. Module-level imports
-stay jax-free: the bench parent process imports this without touching
-the accelerator.
+stay jax-free: a parent process can import this without touching the
+accelerator.
 """
 
 from __future__ import annotations
@@ -32,8 +33,7 @@ from __future__ import annotations
 import os
 from typing import Any
 
-# bf16 peak FLOP/s per chip, by device_kind substring (the table
-# bench.py's headline MFU has used since round 1; moved here round 22)
+# bf16 peak FLOP/s per chip, by device_kind substring
 PEAKS = {
     "v5 lite": 197e12,  # v5e
     "v5litepod": 197e12,

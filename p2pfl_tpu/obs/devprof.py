@@ -11,8 +11,8 @@ every fit the learner computes a live MFU / achieved-TFLOPs gauge
 peak-HBM / RSS watermarks, and stows them in ``devprof_last`` for the
 status publisher. Nothing touches the training program; the only
 added work is a once-per-shape FLOP probe (cached) and two gauge
-reads per fit. This is the arm the bench's ``devprof_overhead_pct``
-A/B gates at <= 2%.
+reads per fit (measured at <= 2% of the fit on the dev box,
+docs/perf.md §10).
 
 **step** (``P2PFL_DEVPROF=step``) — explicit opt-in step profiling.
 The fit runs a *phase-split* pipeline instead of the fused scan:
@@ -54,7 +54,7 @@ from p2pfl_tpu.obs.trace import get_tracer
 
 ENV_VAR = "P2PFL_DEVPROF"
 
-# span names the step level records (perf_report / bench join on them)
+# span names the step level records (perf_report joins on them)
 PHASE_SPANS = ("devprof.data", "devprof.forward", "devprof.backward",
                "devprof.update", "devprof.accum")
 

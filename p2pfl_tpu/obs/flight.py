@@ -16,8 +16,8 @@ Design discipline (mirrors obs.trace, priority order):
    and appends to a bounded ``collections.deque`` — atomic under
    CPython, so asyncio callbacks and executor threads share the ring
    without a lock. No per-event I/O, no serialization until dump time.
-2. **Disabled is one attribute read.** ``P2PFL_FLIGHT=0`` (the bench
-   A/B's off-arm) short-circuits before any allocation.
+2. **Disabled is one attribute read.** ``P2PFL_FLIGHT=0``
+   short-circuits before any allocation.
 3. **Dump is atomic and re-entrant.** ``dump()`` rewrites the same
    ``flight_<pid>.json`` via tmp+rename; repeated dumps (crash then
    eviction) keep the latest, fullest picture with every trigger

@@ -9,8 +9,8 @@ tail (``node_<i>.status.json`` records and the ``metrics.jsonl``
 event stream), with **firing/clear semantics** — an alert is a stateful
 object that fires once when its condition appears, updates while it
 holds, and clears when it goes away, so a watcher (the monitor's
-alerts pane, the healthcheck CLI's exit code, the bench's detection-
-latency probe) sees transitions, not a re-printed condition.
+alerts pane, the healthcheck CLI's exit code) sees transitions, not a
+re-printed condition.
 
 Built-in rules (severity in parentheses; all thresholds live on
 ``HealthConfig``):

@@ -8,8 +8,7 @@ gate on federation health the same way they gate on a test run:
 
 ``--watch`` keeps a persistent engine polling the directory, printing
 fire/clear *transitions* as they happen (and alert lines on ``--json``
-as JSONL); the exit code then reflects the worst severity seen, which
-is what the bench's detection-latency probe consumes.
+as JSONL); the exit code then reflects the worst severity seen.
 """
 
 from __future__ import annotations

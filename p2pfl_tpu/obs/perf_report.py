@@ -1,9 +1,8 @@
 """Automated perf attribution: *where did the round go*, as a report.
 
 docs/perf.md §6 reaches its verdicts by hand: merge the traces, stare
-at the critpath table, divide FLOPs by walls, cross-reference the BENCH
-trajectory. This module mechanizes that loop over the artifacts the
-stack already writes —
+at the critpath table, divide FLOPs by walls. This module mechanizes
+that loop over the artifacts the stack already writes —
 
 1. **critical-path components** (obs.critpath): per-round
    fit/wire/wait/agg/other over every ``node.round`` span, averaged
@@ -16,17 +15,11 @@ stack already writes —
 3. **recompile counters**: the per-process ``xla/backend_compiles``
    totals the tracer exports — a fat ``other``/``fit`` bucket with a
    nonzero steady-state compile count is a recompile storm, not a
-   compute floor;
-4. **the BENCH trajectory** (``--bench BENCH_*.json ...``): each
-   HEADLINE key of the LAST file given (the candidate) is compared
-   against the best-ever value across all given files with matching
-   provenance (scripts/check_bench_regress's baseline discipline), and
-   the component furthest over its floor is named.
+   compute floor.
 
 Usage::
 
-    python -m p2pfl_tpu.obs.perf_report <trace-dir> [--round N]
-        [--bench BENCH_a.json BENCH_b.json ...] [--json]
+    python -m p2pfl_tpu.obs.perf_report <trace-dir> [--round N] [--json]
 
 Exit code 1 when there is nothing to attribute (no readable trace
 files, or no ``node.round`` spans — tracing was off).
@@ -36,7 +29,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import pathlib
 import sys
 from typing import Any
 
@@ -129,65 +121,10 @@ def attribute(doc: dict, round_no: int | None = None) -> dict[str, Any]:
 
 
 # ---------------------------------------------------------------------
-# BENCH trajectory join
-# ---------------------------------------------------------------------
-
-def _regress_module():
-    """scripts/check_bench_regress, imported the way benchkeys does —
-    one baseline discipline, not a reimplementation."""
-    repo = pathlib.Path(__file__).resolve().parents[2]
-    scripts = repo / "scripts"
-    if str(scripts) not in sys.path:
-        sys.path.insert(0, str(scripts))
-    import check_bench_regress
-
-    return check_bench_regress
-
-
-def bench_attribution(bench_paths: list[str]) -> dict[str, Any]:
-    """HEADLINE keys of the last envelope given vs the best-ever
-    provenance-matched values over all of them; the top over-floor key
-    is the component the next perf PR should attack. over_floor_pct is
-    always worse-is-positive regardless of the key's direction."""
-    cbr = _regress_module()
-    history: list[tuple[str, dict]] = []
-    for p in bench_paths:
-        parsed = cbr.load_parsed(pathlib.Path(p))
-        if parsed is not None:
-            history.append((pathlib.Path(p).name, parsed))
-    if not history:
-        return {"rows": [], "top": None, "error": "no parseable envelopes"}
-    cand_name, cand = history[-1]
-    prov = cbr._provenance(cand)
-    rows = []
-    for key, direction in sorted(cbr.HEADLINE.items()):
-        v = cand.get(key)
-        if not isinstance(v, (int, float)):
-            continue
-        best = cbr.baseline_over(history, key, direction,
-                                 cand.get("metric"), provenance=prov)
-        if best is None or best[0] == 0:
-            continue
-        v = float(v)
-        over = ((v - best[0]) if direction == "lower" else (best[0] - v))
-        rows.append({
-            "key": key, "value": v, "best": best[0], "best_from": best[1],
-            "over_floor_pct": round(100.0 * over / abs(best[0]), 2),
-        })
-    rows.sort(key=lambda r: -r["over_floor_pct"])
-    over_floor = [r for r in rows if r["over_floor_pct"] > 0]
-    return {
-        "candidate": cand_name,
-        "rows": rows,
-        "top": over_floor[0]["key"] if over_floor else None,
-    }
-
-
-# ---------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------
 
-def _fmt_report(attr: dict, bench: dict | None) -> str:
+def _fmt_report(attr: dict) -> str:
     lines = []
     rounds = attr["rounds"]
     span = (f"round {rounds[0]}" if len(rounds) == 1
@@ -214,27 +151,6 @@ def _fmt_report(attr: dict, bench: dict | None) -> str:
     lines.append(f"recompiles: {attr['recompiles']} post-warm-up backend "
                  "compiles across traced processes")
     lines.append(f"top component: {attr['top']}")
-    if bench is not None:
-        lines.append("")
-        if bench.get("error"):
-            lines.append(f"bench trajectory: {bench['error']}")
-        else:
-            lines.append(f"bench trajectory (candidate {bench['candidate']} "
-                         "vs best-ever, provenance-matched)")
-            lines.append(f"  {'KEY':<32}{'VALUE':>12}{'BEST':>12}"
-                         f"{'OVER-FLOOR':>12}")
-            for r in bench["rows"]:
-                lines.append(
-                    f"  {r['key']:<32}{r['value']:>12.4g}"
-                    f"{r['best']:>12.4g}{r['over_floor_pct']:>+11.1f}%")
-            if bench["top"]:
-                top = bench["rows"][0]
-                lines.append(
-                    f"top over-floor: {top['key']} "
-                    f"{top['over_floor_pct']:+.1f}% vs {top['best_from']}")
-            else:
-                lines.append("top over-floor: none — every headline key "
-                             "is at its historical floor")
     return "\n".join(lines)
 
 
@@ -245,10 +161,6 @@ def main(argv: list[str] | None = None) -> int:
                          "*.trace.json) or individual trace files")
     ap.add_argument("--round", type=int, default=None,
                     help="restrict attribution to one round")
-    ap.add_argument("--bench", nargs="+", default=None, metavar="BENCH",
-                    help="BENCH_*.json envelopes, oldest first; the "
-                         "last is the candidate judged against the "
-                         "best-ever of the rest")
     ap.add_argument("--json", action="store_true",
                     help="machine-readable JSON instead of the report")
     args = ap.parse_args(argv)
@@ -262,14 +174,10 @@ def main(argv: list[str] | None = None) -> int:
         print("no node.round spans found (was tracing enabled?)",
               file=sys.stderr)
         return 1
-    bench = bench_attribution(args.bench) if args.bench else None
     if args.json:
-        out = dict(attr)
-        if bench is not None:
-            out["bench"] = bench
-        print(json.dumps(out, sort_keys=True))
+        print(json.dumps(attr, sort_keys=True))
     else:
-        print(_fmt_report(attr, bench))
+        print(_fmt_report(attr))
     return 0
 
 

@@ -44,7 +44,7 @@ The XLA recompile counter hooks ``jax.monitoring``'s duration events:
 every real backend compile fires ``.../backend_compile_duration``
 (jit-cache hits do not), so a repeat of the round-7 recompile storm
 (~450 mid-round compiles, ≈32% of wall — perf.md §7b) is loudly
-visible in every bench record instead of needing a hand profile.
+visible in every benchmark line instead of needing a hand profile.
 """
 
 from __future__ import annotations
@@ -234,8 +234,8 @@ class Tracer:
 
     def summarize(self) -> dict[str, Any]:
         """Per-span-name totals + counters + gauges, in the shared
-        record shape (obs.records.make_record) — what bench.py turns
-        into attribution keys."""
+        record shape (obs.records.make_record) — what ``p2p.launch``
+        returns under ``obs``."""
         agg: dict[str, list[float]] = {}
         for name, _lane, _t0, dur, _args in list(self._events):
             agg.setdefault(name, [0, 0.0, 0.0])
@@ -373,8 +373,8 @@ def configure_from_env(
 # XLA recompile counter (jax.monitoring)
 # ---------------------------------------------------------------------
 # Plain module ints, counted whether or not span tracing is on: the
-# recompile signal must reach bench records and assertions even in an
-# untraced run (tracking two ints per compile is free at compile
+# recompile signal must reach benchmark records and assertions even in
+# an untraced run (tracking two ints per compile is free at compile
 # granularity). The tracer mirrors them as counters when enabled.
 _xla_lock = threading.Lock()
 _xla_installed = False

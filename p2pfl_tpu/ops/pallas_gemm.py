@@ -43,17 +43,17 @@ kernels are written 2-D):
 Selection: every call site asks :func:`choose`, which measures the
 Pallas and XLA variants at the actual per-node shape, vmapped as wide
 as the federation or as a 1 GiB operand budget allows
-(:func:`_measure_width`), on the real backend — scan-slope timing,
-same methodology as
-``scripts/exp_ceiling.py`` — caches the verdict per shape, and takes
+(:func:`_measure_width`), on the real backend — scan-slope timing
+(:func:`_slope_ms`: a longer scan less a shorter one, net of dispatch
+and sync) — caches the verdict per shape, and takes
 XLA whenever Pallas does not win. "XLA measured faster" is a decision;
 "the kernel broke" is not: on a TPU a kernel that fails to lower,
 compile or launch raises out of the gate. ``P2PFL_PALLAS_GEMM``
 (auto|on|off) forces either path; non-TPU backends always take XLA
 (interpret-mode Pallas is a correctness tool, not a fast path). The
-decision table is exported into the bench output
-(``pallas_gemm_decisions``) so every headline run records the
-before/after per-op numbers that justified its path.
+decision table (:func:`decisions`) is what ``benchmark/run.py`` and
+its readers count as ``kernels.pallas_picked`` and
+``kernels.gate_measure_s``.
 
 Block shapes are what Mosaic accepts on a v5e (compiled at the
 north-star shapes, PERF.md "Bring-up"): a block's last dimension is the
@@ -503,7 +503,7 @@ def set_nodes_hint(n: int) -> None:
 
 def decisions() -> dict[str, dict]:
     """JSON-able copy of every gate decision this process made
-    (impl, measured ms per variant, forcing). Exported by bench.py."""
+    (impl, ms per variant, forcing), for ``benchmark/`` and chip_smoke."""
     return {k: dict(v) for k, v in _decisions.items()}
 
 
@@ -531,8 +531,8 @@ def _repeat_program(fn, reps: int):
 
 def _slope_ms(fn, args, r1: int = 2, r2: int = 6) -> float:
     """Per-call ms net of dispatch/sync overhead: time a scan of r2
-    repeats minus a scan of r1 repeats over (r2 - r1) — the
-    scripts/exp_ceiling.py scan-slope methodology."""
+    repeats minus a scan of r1 repeats over (r2 - r1), so that what
+    both scans pay once cancels."""
 
     def repeat(reps):
         # lowered and compiled ahead of time: a plain call of the jitted
@@ -604,7 +604,7 @@ def choose(kind: str, shapes: tuple, dtype) -> str:
     fused backward), or "sgd_accum" (fused optimizer stream).
     ``shapes``: the per-node operand shapes as seen at the call site.
     Measured decisions are cached per (kind, shapes, dtype, nodes,
-    backend); env/backend forcings are recorded too so the bench
+    backend); env/backend forcings are recorded too so the decision
     table shows WHY a path ran.
     """
     backend = _backend()

@@ -912,6 +912,11 @@ class P2PNode:
                 if not dead:
                     peer.draining = True
                     try:
+                        if peer.writer.is_closing():
+                            # as in _write: on Python 3.12 a write to a
+                            # closed transport is a TypeError out of
+                            # asyncio, which would end this task
+                            raise ConnectionResetError("peer writer closed")
                         await write_message(peer.writer, msg)
                         self._count_tx(peer, msg)
                     except (ConnectionError, RuntimeError, OSError):

@@ -1,9 +1,8 @@
 """Where XLA's persistent compile cache lives — decided in one place.
 
 Every entry point (``run``, ``p2p.launch`` parent and child,
-``parallel.dcn``, ``chip_smoke.py``, ``bench.py``'s children,
-``__graft_entry__`` and the experiment scripts) calls :func:`enable`
-first thing. A cold 64-node round program compiles for most of a
+``parallel.dcn``, ``chip_smoke.py``, ``benchmark/run.py`` and
+``__graft_entry__``) calls :func:`enable` first thing. A cold 64-node round program compiles for most of a
 minute on a v5e; the machine a run lands on may keep nothing between
 calls but one directory, and the directory's path is part of the cache
 key — so the path is either the one the operator names or a fixed one,

@@ -1,9 +1,9 @@
 """Socket-path round-time attribution (VERDICT r4 #6).
 
 The 24-node socket federation records ~3.8 s/round with no story of
-where the time goes. This profiles the EXACT bench scenario
-(bench._socket24's config) under cProfile and buckets cumulative time
-into the candidate sinks the verdict names:
+where the time goes. This profiles that scenario (``_cfg`` below)
+under cProfile and buckets cumulative time into the candidate sinks
+the verdict names:
 
   serialization (core.serialize msgpack+CRC), signing (p2p.tls),
   learner compute (fit/evaluate), socket IO, and event-loop idle
@@ -20,9 +20,8 @@ raw payload segment, docs/architecture.md):
   zero-copy data plane was A/B'd on, docs/perf.md §7);
 - ``--multiproc K`` runs the scenario through ``p2p.launch`` with K
   nodes per child process (K=1 -> 24 processes, K=4 -> 6) instead of
-  the in-process simulation, reporting the per-layout round time the
-  bench's ``socket_round_s_24node_multiproc`` key records. cProfile
-  cannot cross process boundaries, so this mode reports timing only —
+  the in-process simulation, reporting the per-layout round time.
+  cProfile cannot cross process boundaries, so this mode reports timing only —
   profile a single child by running it under ``python -m cProfile``.
 
 Usage: python scripts/exp_socket_profile.py [--rounds 3] [--sweep]
@@ -44,9 +43,8 @@ from pathlib import Path
 _REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_REPO))
 
-# CPU backend: 24 asyncio nodes must not fight for the bench chip, and
-# the socket path's cost is control-plane, not compute (bench._socket24
-# runs the same way)
+# CPU backend: 24 asyncio nodes must not fight for the chip, and the
+# socket path's cost is control-plane, not compute
 flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                os.environ.get("XLA_FLAGS", "")).strip()
 os.environ["XLA_FLAGS"] = flags
@@ -88,8 +86,8 @@ def run_once(**kw):
 
 def run_multiproc(nodes_per_proc: int, **kw) -> None:
     """The scenario through real OS processes (p2p.launch), timing only
-    — matches bench._socket_mp's method: round time = the slowest
-    node's post-warm-up round-loop wall (learn_wall_s) / rounds."""
+    — round time = the slowest node's post-warm-up round-loop wall
+    (learn_wall_s) / rounds."""
     import tempfile
 
     from p2pfl_tpu.p2p.launch import launch

@@ -19,7 +19,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 # Persistent XLA compile cache: the suite is compile-dominated (the
 # vmapped round programs recompile identically every run), so warm
-# runs skip most of the wall-clock. Separate dir from the TPU bench
+# runs skip most of the wall-clock. Separate dir from the TPU
 # cache (.jax_cache) to keep either side prunable on its own.
 # Min-compile-time 0: the suite's wall-clock is the SUM of hundreds
 # of sub-second compiles, so the default 1s floor would persist

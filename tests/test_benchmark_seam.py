@@ -1,0 +1,189 @@
+"""The program's side of the seam with ``benchmark/``.
+
+``BENCHMARK.json`` + ``benchmark/run.py`` is the repo's one yardstick,
+and it reaches into the program by name: a reader file per metric, the
+harness's own imports, a ``ScenarioConfig`` built from each cell's data
+files. The benchmark's own tests (``benchmark/tests/``) are subprocess
+rehearsals that tier-1 never collects, so a program change that renames
+one of those names would pass every test here and stop every cell at
+the driver's check. These cases hold the names and call shapes in
+process, on no device. They are parametrised from ``BENCHMARK.json``:
+a later cell or reader is covered by being listed there.
+
+``benchmark/run.py`` is imported by path, as ``benchmark/tests/`` do;
+nothing of it is copied here.
+"""
+
+import ast
+import dataclasses
+import importlib
+import importlib.util
+import inspect
+import json
+import pathlib
+import sys
+
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+HOME = ROOT / "benchmark"
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+@pytest.fixture(scope="module")
+def harness():
+    """``benchmark/run.py`` as a module, with ``benchmark/`` importable
+    while this file's cases run (readers import ``spans``, ``scopework``
+    and ``tracereduce`` from there) and gone again afterwards."""
+    before = set(sys.modules)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.syspath_prepend(str(HOME))  # undo restores all of sys.path
+        spec = importlib.util.spec_from_file_location(
+            "benchmark_run", HOME / "run.py")
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        yield module
+    for name in set(sys.modules) - before:
+        file = getattr(sys.modules[name], "__file__", None)
+        if file and pathlib.Path(file).is_relative_to(HOME):
+            del sys.modules[name]
+
+
+def program_names(path):
+    """What the source at ``path`` takes from ``p2pfl_tpu``, as dotted
+    names: every ``from p2pfl_tpu.a import b``, every ``import
+    p2pfl_tpu.a``, and every ``b.c`` or ``getattr(b, "c", ...)`` on a
+    name so bound (a reader may tolerate a missing attribute and fill
+    nothing; the seam may not)."""
+    tree = ast.parse(pathlib.Path(path).read_text())
+    bound, names = {}, set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and (node.module or "").split(
+                ".")[0] == "p2pfl_tpu":
+            for a in node.names:
+                bound[a.asname or a.name] = f"{node.module}.{a.name}"
+        elif isinstance(node, ast.Import):
+            for a in node.names:
+                if a.name.split(".")[0] == "p2pfl_tpu":
+                    names.add(a.name)
+                    if a.asname:
+                        bound[a.asname] = a.name
+    names.update(bound.values())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Attribute) and isinstance(
+                node.value, ast.Name) and node.value.id in bound:
+            names.add(f"{bound[node.value.id]}.{node.attr}")
+        elif (isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+                and node.func.id == "getattr" and len(node.args) >= 2
+                and isinstance(node.args[0], ast.Name)
+                and node.args[0].id in bound
+                and isinstance(node.args[1], ast.Constant)):
+            names.add(f"{bound[node.args[0].id]}.{node.args[1].value}")
+    return sorted(names)
+
+
+def resolves(dotted):
+    """Whether ``dotted`` names a module, or an attribute chain under
+    the longest module prefix that imports."""
+    parts = dotted.split(".")
+    for cut in range(len(parts), 0, -1):
+        try:
+            obj = importlib.import_module(".".join(parts[:cut]))
+        except ImportError:
+            continue
+        try:
+            for attr in parts[cut:]:
+                obj = getattr(obj, attr)
+        except AttributeError:
+            return False
+        return True
+    return False
+
+
+def missing(path):
+    return [n for n in program_names(path) if not resolves(n)]
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
+def test_reader_finds_what_it_reads(harness, metric):
+    path = HOME / "readers" / f"{metric}.py"
+    assert path.is_file(), f"per_layer metric {metric} has no reader file"
+    gone = missing(path)
+    assert not gone, f"reader {metric}: the program no longer has {gone}"
+    reader = harness.load_module(path, "bench_reader")
+    inspect.signature(reader.read).bind({})  # read(ctx)
+
+
+def test_a_name_made_to_disappear_fails_its_reader(monkeypatch):
+    from p2pfl_tpu.ops import pallas_gemm
+
+    path = HOME / "readers" / "kernels.gate_measure_s.py"
+    assert missing(path) == []
+    monkeypatch.delattr(pallas_gemm, "decisions")
+    assert missing(path) == ["p2pfl_tpu.ops.pallas_gemm.decisions"]
+
+
+@pytest.mark.parametrize("rehearse", [False, True], ids=["own", "rehearsal"])
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+def test_cell_builds_its_scenario_config(harness, workload, rehearse):
+    """The cell's ``configs/`` and ``traffic/`` files make a
+    ``ScenarioConfig`` the program accepts (an unknown key or a value
+    ``__post_init__`` refuses raises here), and name a class the
+    program's ``federation`` has. Nothing is placed on a device."""
+    from p2pfl_tpu import federation
+    from p2pfl_tpu.config.schema import ScenarioConfig
+
+    cell = harness.Cell(workload, rehearse)
+    cfg = cell.scenario_config(7)
+    assert isinstance(cfg, ScenarioConfig)
+    assert cfg.n_nodes == cell.n_nodes and cfg.seed == cfg.data.seed == 7
+    assert cfg.training.rounds == cell.traffic["followed_rounds"]
+    scenario = getattr(federation, cell.traffic["scenario_class"])
+    inspect.signature(scenario).bind(cfg)
+
+
+def test_harness_finds_the_program_it_drives(harness):
+    """Every name ``benchmark/``'s own sources take from the program
+    resolves, and what ``run.py`` and ``control.py`` call has the shape
+    they call it with."""
+    for path in sorted(HOME.glob("*.py")):
+        gone = missing(path)
+        assert not gone, f"benchmark/{path.name}: the program no longer has {gone}"
+
+    from p2pfl_tpu import federation
+    from p2pfl_tpu.federation.events import Events
+    from p2pfl_tpu.models import cnn
+    from p2pfl_tpu.obs import trace as obs_trace
+    from p2pfl_tpu.ops import pallas_gemm
+    from p2pfl_tpu.utils import compile_cache
+
+    def takes(fn, *args, **kwargs):
+        inspect.signature(fn).bind(*args, **kwargs)
+
+    for name in sorted({json.loads(f.read_text())["scenario_class"]
+                        for f in (HOME / "traffic").glob("*.json")}):
+        scenario = getattr(federation, name)
+        takes(scenario.run, None, rounds=3)  # -> ScenarioResult
+        takes(scenario.evaluate, None)
+        takes(scenario.add_observer, None, lambda event, payload: None)
+        takes(scenario.close, None)
+    assert {"history", "round_times_s"} <= {
+        f.name for f in dataclasses.fields(federation.ScenarioResult)}
+    # ``Driven._on_event`` closes its spans on these three
+    assert {"ROUND_STARTED", "AGGREGATION_FINISHED",
+            "ROUND_FINISHED"} <= set(Events.__members__)
+
+    takes(compile_cache.enable)
+    for fn in (obs_trace.install_xla_listener, obs_trace.reset_xla_counters):
+        takes(fn)
+    assert float(obs_trace.xla_compile_seconds()) >= 0
+    assert int(obs_trace.xla_recompiles()) >= 0
+    # the readers' side: seconds and counters kept since process start,
+    # the span ring as raw tuples, the trace-time records
+    assert isinstance(obs_trace.stage_seconds(), dict)
+    assert float(obs_trace.trace_lower_seconds()) >= 0
+    assert float(obs_trace.cache_load_seconds()) >= 0
+    assert isinstance(obs_trace.counted(), dict)
+    assert isinstance(obs_trace.get_tracer().spans(), list)
+    assert isinstance(pallas_gemm.decisions(), dict)
+    assert isinstance(cnn.lowerings(), dict)

@@ -163,7 +163,7 @@ def test_fit_gauges_live_mfu_and_flops_cache(monkeypatch):
     f1 = devprof.fit_flops(ln)
     assert f1 and ln._devprof_flops == f1
     assert devprof.fit_flops(ln) == f1
-    # live MFU agrees with the bench-side arithmetic over the same
+    # live MFU agrees with cost_model's arithmetic over the same
     # wall (the gauge is rounded to 4 decimals, hence the abs band)
     expect = cost_model.mfu(f1 * 1, g["devprof_fit_s"], n_devices=1)
     assert g["devprof_mfu"] == pytest.approx(expect, abs=5.1e-5)
@@ -307,50 +307,3 @@ def test_cli_json_mode(tmp_path, capsys):
     assert doc["top"] == "wait"
     assert set(doc["components"]) == {"fit", "wire", "wait", "agg",
                                       "other"}
-
-
-def test_cli_bench_join_names_top_over_floor(tmp_path, capsys):
-    """--bench: the candidate (last file) is judged against the best-
-    ever provenance-matched value per HEADLINE key; the top over-floor
-    key is the named verdict. Bare-dict envelopes (no rc/parsed
-    wrapper) ride check_bench_regress.load_parsed's compat path."""
-    _write_trace(tmp_path, 1, _meta(1) + [
-        _x("node.round", 1, 0, 4, {"round": 0}),
-    ])
-    hist = tmp_path / "BENCH_r90.json"
-    cand = tmp_path / "BENCH_r91.json"
-    hist.write_text(json.dumps({"socket_round_s_24node": 1.0,
-                                "round_s_8node": 2.0}))
-    cand.write_text(json.dumps({"socket_round_s_24node": 1.8,
-                                "round_s_8node": 2.0}))
-    rc = perf_report.main([str(tmp_path),
-                           "--bench", str(hist), str(cand)])
-    assert rc == 0
-    out = capsys.readouterr().out
-    assert "bench trajectory" in out
-    assert "top over-floor: socket_round_s_24node" in out
-
-    # candidate AT the floor everywhere: the report says so
-    cand.write_text(json.dumps({"socket_round_s_24node": 1.0,
-                                "round_s_8node": 2.0}))
-    rc = perf_report.main([str(tmp_path),
-                           "--bench", str(hist), str(cand)])
-    assert rc == 0
-    assert "top over-floor: none" in capsys.readouterr().out
-
-
-def test_bench_attribution_over_floor_sign_convention():
-    """over_floor_pct is worse-is-positive for BOTH directions: a
-    lower-is-better key above its floor and a higher-is-better key
-    below its floor must both rank as over-floor."""
-    import tempfile
-    with tempfile.TemporaryDirectory() as td:
-        td = __import__("pathlib").Path(td)
-        a, b = td / "BENCH_a.json", td / "BENCH_b.json"
-        a.write_text(json.dumps({"mfu": 0.5, "round_s_8node": 1.0}))
-        b.write_text(json.dumps({"mfu": 0.25, "round_s_8node": 1.0}))
-        res = perf_report.bench_attribution([str(a), str(b)])
-    rows = {r["key"]: r for r in res["rows"]}
-    assert rows["mfu"]["over_floor_pct"] == pytest.approx(50.0)
-    assert rows["round_s_8node"]["over_floor_pct"] == pytest.approx(0.0)
-    assert res["top"] == "mfu"

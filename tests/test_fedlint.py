@@ -276,13 +276,12 @@ def test_fedlint_cli_over_repo_subprocess():
 
 
 def test_analysis_single_entry_point_runs_all_passes():
-    """``python -m p2pfl_tpu.analysis``: fedlint + bench-keys +
-    status-keys under one command, combined exit code."""
+    """``python -m p2pfl_tpu.analysis``: fedlint + status-keys under
+    one command, combined exit code."""
     res = subprocess.run(
         [sys.executable, "-m", "p2pfl_tpu.analysis", "p2pfl_tpu/"],
         capture_output=True, text=True, timeout=180, cwd=REPO)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "== fedlint ==" in res.stdout
-    assert "== bench-keys ==" in res.stdout
     assert "== status-keys ==" in res.stdout
-    assert "ok:" in res.stdout  # bench-keys kept its text contract
+    assert "ok:" in res.stdout  # status-keys keeps its text contract

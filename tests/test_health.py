@@ -1,6 +1,6 @@
 """Health plane (round 12): the rule engine's firing/clear semantics,
-the healthcheck CLI's exit-code contract, the flight recorder's
-dump-on-crash postmortem, and the bench regression gate.
+the healthcheck CLI's exit-code contract and the flight recorder's
+dump-on-crash postmortem.
 
 Engine tests drive ``HealthEngine.evaluate`` with synthetic status
 records and explicit clocks — the engine is read-only over published
@@ -11,9 +11,6 @@ same recompile-amortising reason as test_elastic)."""
 import asyncio
 import json
 import os
-import pathlib
-import subprocess
-import sys
 import time
 
 import pytest
@@ -31,8 +28,6 @@ from p2pfl_tpu.obs.healthcheck import main as healthcheck_main
 from p2pfl_tpu.utils.monitor import publish_status
 
 from test_p2p import _make_learners
-
-REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _status(node, ts, **fields):
@@ -465,150 +460,3 @@ class TestFlightRecorder:
             tr.configure(enabled=old_traced)
             rec.dump_dir, rec.enabled = old_dir, old_enabled
             rec.clear()
-
-
-# ---------------------------------------------------------------------------
-# bench regression gate
-# ---------------------------------------------------------------------------
-
-
-def _socket_best():
-    vals = []
-    for p in sorted(REPO.glob("BENCH_r*.json")):
-        doc = json.loads(p.read_text())
-        if doc.get("rc") not in (0, None):
-            continue
-        v = (doc.get("parsed") or {}).get("socket_round_s_24node")
-        if isinstance(v, (int, float)):
-            vals.append(float(v))
-    return min(vals)
-
-
-def test_check_bench_regress_clean_over_trajectory():
-    """The gate must pass over the checked-in history itself."""
-    res = subprocess.run(
-        [sys.executable, str(REPO / "scripts" / "check_bench_regress.py")],
-        capture_output=True, text=True, timeout=60, cwd=REPO)
-    assert res.returncode == 0, res.stdout + res.stderr[-500:]
-    assert "clean" in res.stdout
-
-
-def test_check_bench_regress_skips_failed_rounds(tmp_path):
-    """A round that did not run to an end (``rc != 0``, nothing
-    parsed — a driver timeout) is skipped by name, never anchored on."""
-    hist = {
-        "BENCH_r90.json": {"rc": 0, "parsed": {
-            "metric": "m", "socket_round_s_24node": 2.0}},
-        "BENCH_r91.json": {"rc": 124, "parsed": None, "tail": "killed"},
-        "BENCH_r92.json": {"rc": 0, "parsed": {
-            "metric": "m", "socket_round_s_24node": 2.05}},
-    }
-    for name, doc in hist.items():
-        (tmp_path / name).write_text(json.dumps(doc))
-    res = subprocess.run(
-        [sys.executable, str(REPO / "scripts" / "check_bench_regress.py"),
-         "--history", str(tmp_path / "BENCH_r*.json")],
-        capture_output=True, text=True, timeout=60, cwd=REPO)
-    assert res.returncode == 0, res.stdout + res.stderr[-500:]
-    assert "skipping BENCH_r91" in res.stdout
-    assert "BENCH_r90" in res.stdout and "clean" in res.stdout
-
-
-def test_check_bench_regress_fails_synthetic_regression(tmp_path):
-    cand = {"metric": "synthetic", "unit": "s/round",
-            "socket_round_s_24node": _socket_best() * 1.30,
-            "meta": {"git_sha": "deadbee", "host": "test"}}
-    p = tmp_path / "BENCH_cand.json"
-    p.write_text(json.dumps(cand))
-    res = subprocess.run(
-        [sys.executable, str(REPO / "scripts" / "check_bench_regress.py"),
-         "--candidate", str(p)],
-        capture_output=True, text=True, timeout=60, cwd=REPO)
-    assert res.returncode == 1, res.stdout + res.stderr[-500:]
-    assert "REGRESSION" in res.stdout
-    assert "FAIL" in res.stderr
-    # the provenance stamp must be surfaced next to the verdict
-    assert "git_sha=deadbee" in res.stdout
-
-
-def test_check_bench_regress_within_tolerance_passes(tmp_path):
-    cand = {"metric": "synthetic",
-            "socket_round_s_24node": _socket_best() * 1.05}
-    p = tmp_path / "BENCH_cand.json"
-    p.write_text(json.dumps(cand))
-    res = subprocess.run(
-        [sys.executable, str(REPO / "scripts" / "check_bench_regress.py"),
-         "--candidate", str(p)],
-        capture_output=True, text=True, timeout=60, cwd=REPO)
-    # one key 5% off best + six missing keys (reported, not failed)
-    assert res.returncode == 0, res.stdout + res.stderr[-500:]
-    assert res.stdout.count("missing") >= 5
-
-
-def test_check_bench_regress_provenance_filtered_baselines(tmp_path):
-    """Round 20: baselines only form over history rows measured on the
-    same ``(backend, device_count)`` as the candidate. An 8-device TPU
-    row must not gate a 1-device CPU run (its round times are in a
-    different regime entirely), and legacy rows that predate the
-    ``meta`` stamps count as ``("cpu", 1)`` — the hardware every
-    pre-stamp trajectory row actually ran on."""
-    hist = {
-        "BENCH_r90.json": {  # fast 8-device TPU row: must be filtered
-            "crossdev_sharded_round_s": 0.5,
-            "meta": {"backend": "tpu", "device_count": 8}},
-        "BENCH_r91.json": {  # stamped cpu/1 row
-            "crossdev_sharded_round_s": 2.0,
-            "meta": {"backend": "cpu", "device_count": 1}},
-        "BENCH_r92.json": {  # legacy unstamped row -> defaults cpu/1
-            "crossdev_sharded_round_s": 1.9},
-    }
-    for name, doc in hist.items():
-        (tmp_path / name).write_text(json.dumps(doc))
-
-    def judge(cand):
-        p = tmp_path / "BENCH_cand.json"
-        p.write_text(json.dumps(cand))
-        return subprocess.run(
-            [sys.executable,
-             str(REPO / "scripts" / "check_bench_regress.py"),
-             "--candidate", str(p),
-             "--history", str(tmp_path / "BENCH_r*.json")],
-            capture_output=True, text=True, timeout=60, cwd=REPO)
-
-    # cpu/1 candidate at 2.1: vs the tpu/8 best (0.5) this would be a
-    # 4.2x "regression"; vs the cpu/1 best (the legacy 1.9) it is
-    # within the 15% band -> the provenance filter must pass it
-    res = judge({"crossdev_sharded_round_s": 2.1,
-                 "meta": {"backend": "cpu", "device_count": 1}})
-    assert res.returncode == 0, res.stdout + res.stderr[-500:]
-    assert "provenance filter: backend=cpu devices=1" in res.stdout
-    assert "BENCH_r92.json" in res.stdout  # legacy row anchors baseline
-
-    # same-hardware regressions still fail: cpu/1 at 2.5 vs best 1.9
-    res = judge({"crossdev_sharded_round_s": 2.5,
-                 "meta": {"backend": "cpu", "device_count": 1}})
-    assert res.returncode == 1, res.stdout + res.stderr[-500:]
-    assert "REGRESSION" in res.stdout
-
-    # a tpu/8 candidate is judged against the tpu/8 row only
-    res = judge({"crossdev_sharded_round_s": 0.7,
-                 "meta": {"backend": "tpu", "device_count": 8}})
-    assert res.returncode == 1, res.stdout + res.stderr[-500:]
-    assert "BENCH_r90.json" in res.stdout
-
-
-def test_bench_run_meta_stamps_backend_and_devices():
-    """Round 20: ``bench._run_meta()`` stamps the accelerator identity
-    (``backend``, ``device_count``) alongside the git provenance — the
-    stamps the regression gate's provenance filter keys on."""
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-        meta = bench._run_meta()
-    finally:
-        sys.path.remove(str(REPO))
-    import jax
-    assert meta["backend"] == jax.default_backend()
-    assert meta["device_count"] == jax.device_count()
-    assert isinstance(meta["device_count"], int)
-    assert meta["device_count"] >= 1

@@ -162,8 +162,7 @@ def test_24node_federation_with_fanout_cap():
     """VERDICT r2 #6: the socket path past 8 nodes. 24 nodes, fully
     connected, control-flood relays capped at 6 random peers
     (gossip_fanout) and a binding train-set cap — every node must
-    finish 2 rounds within the timeout. Records nothing; bench.py
-    carries the timed variant (socket_round_s_24node). Slow tier
+    finish 2 rounds within the timeout. Records nothing. Slow tier
     (~94 s): tests/test_simulation_scale.py guards the >8-node
     fan-out-capped behavior every run at 16 nodes in ~11 s."""
 

@@ -3,7 +3,7 @@
 scenario / devprof / cost_model), and the readers (monitor / webapp /
 health) agreeing on the status-record vocabulary. The drift it gates
 is silent by nature — a renamed gauge renders "-" forever and fails
-nothing — so the repo gate runs from tier-1 like benchkeys does."""
+nothing — so the repo gate runs from tier-1."""
 
 import ast
 

@@ -332,32 +332,166 @@ def rope_interleaved(x, theta):
                      axis=-1).reshape(x.shape)
 
 
-def causal_attention(q, k, v, scale, block=256):
-    """Causal softmax attention, a block of queries at a time (a scan,
-    its body recomputed on the way back), so that scores are never
-    [T, T] whole. Each block meets every key under the mask: slicing the
-    keys to a block's own past makes a copy of them a block.
-    ``q, k`` [B, T, H, D], ``v`` [B, T, H, Dv]; scores and softmax
-    float32."""
-    B, T, H, D = q.shape
+_score_tiles: dict[str, int] = {}
+
+
+def score_tiles() -> dict[str, int]:
+    """The last :func:`causal_attention` traced: ``{"computed": score
+    tiles a pass over a sequence forms, "square": tiles of the full
+    ``[T, T]`` square, "block", "tile": a tile's rows and columns}``.
+    Recorded at trace time, as ``cnn.lowerings()`` is."""
+    return dict(_score_tiles)
+
+
+def causal_attention(q, k, v, scale, block=256, tile=256):
+    """Causal softmax attention a ``block`` of queries against a ``tile``
+    of keys at a time, and only the tiles at or under the diagonal: a
+    block's loop over key tiles ENDS at its own last row (a bound from
+    the outer loop's counter), so a tile wholly above the diagonal is
+    never formed, forward, recomputed or on the way back. A block and a
+    tile are taken by ``dynamic_slice`` from the one copy of the keys
+    and values: static slices of the keys to a band of blocks' past
+    make a copy of them, and of their cotangents, a band (4.9 to 9.5 GB
+    of temporaries at 4 to 16 bands of the cell's shapes, ``PERF.md``
+    Findings PR 38). The row maximum, row sum and unnormalised output
+    are carried from tile to tile in float32; the way back is written
+    by hand (:func:`_attend_back`).
+    ``T`` at or under one block is one tile; otherwise ``T`` is padded
+    to whole blocks and tiles (a padded key lies after every real
+    query). ``q, k`` [B, T, H, D], ``v`` [B, T, H, Dv]; scores, mask,
+    maximum, exponentials and sums float32, the probabilities in
+    ``v``'s type for their product with the values, which accumulates
+    in float32."""
+    T = q.shape[1]
     block = min(block, T)
-    pad = -T % block
-    qb = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
-        B, -1, block, H, D).swapaxes(0, 1)  # [blocks, B, block, H, D]
-    kpos = jnp.arange(T)[None, :]
+    tile = min(tile, T + -T % block)
+    pad = -T % math.lcm(block, tile)
+    blocks, tiles = (T + pad) // block, (T + pad) // tile
+    _score_tiles.update(
+        computed=sum(_tiles_met(i, block, tile) for i in range(blocks)),
+        square=blocks * tiles, block=block, tile=tile)
+    heads = lambda a: jnp.pad(
+        a, ((0, 0), (0, pad), (0, 0), (0, 0))).swapaxes(1, 2)
+    o = _attend(heads(q), heads(k), heads(v), scale, block, tile)
+    return o.swapaxes(1, 2)[:, :T]
 
-    @jax.checkpoint
-    def rows(first, qi):
-        s = jnp.einsum("bqhd,bkhd->bhqk", qi, k,
-                       preferred_element_type=F32) * scale
-        qpos = first + jnp.arange(block)[:, None]
-        p = jax.nn.softmax(jnp.where(kpos <= qpos, s, -jnp.inf), axis=-1)
-        return first + block, jnp.einsum(
-            "bhqk,bkhd->bqhd", p.astype(v.dtype), v,
-            preferred_element_type=F32)
 
-    _, out = jax.lax.scan(rows, jnp.int32(0), qb)
-    return out.swapaxes(0, 1).reshape(B, -1, H, v.shape[-1])[:, :T]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attend(q, k, v, scale, block, tile):
+    """:func:`causal_attention` on ``[B, H, T, .]`` operands, ``T`` whole
+    blocks and tiles."""
+    return _attend_kept(q, k, v, scale, block, tile)[0]
+
+
+_cut = functools.partial(jax.lax.dynamic_slice_in_dim, axis=2)
+
+
+def _tiles_met(i, block, tile):
+    """How many key tiles query block ``i`` meets: those that start at or
+    before its last row. ``i`` a Python or a traced integer."""
+    return -(-(i + 1) * block // tile)
+
+
+def _at_or_under(i, j, block, tile):
+    """The mask of block ``i`` against tile ``j``, [block, tile]."""
+    return (j * tile + jnp.arange(tile)[None, :]
+            <= i * block + jnp.arange(block)[:, None])
+
+
+def _attend_kept(q, k, v, scale, block, tile):
+    B, H, T, _ = q.shape
+    Dv = v.shape[-1]
+
+    def rows(_, i):
+        qi = _cut(q, i * block, block)
+
+        def meet(j, carried):
+            m, l, o = carried
+            s = jnp.einsum("bhqd,bhkd->bhqk", qi, _cut(k, j * tile, tile),
+                           preferred_element_type=F32) * scale
+            s = jnp.where(_at_or_under(i, j, block, tile), s, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))  # finite: tile 0
+            p = jnp.exp(s - m_new[..., None])
+            shrink = jnp.exp(m - m_new)
+            return (m_new, shrink * l + jnp.sum(p, axis=-1),
+                    shrink[..., None] * o + jnp.einsum(
+                        "bhqk,bhkd->bhqd", p.astype(v.dtype),
+                        _cut(v, j * tile, tile), preferred_element_type=F32))
+
+        m, l, o = jax.lax.fori_loop(
+            0, _tiles_met(i, block, tile), meet,
+            (jnp.full((B, H, block), -jnp.inf, F32),
+             jnp.zeros((B, H, block), F32), jnp.zeros((B, H, block, Dv), F32)))
+        return None, (o / l[..., None], m + jnp.log(l))
+
+    _, (o, logsum) = jax.lax.scan(rows, None, jnp.arange(T // block))
+    whole = lambda a: jnp.moveaxis(a, 0, 2).reshape(
+        (B, H, T) + a.shape[4:])  # [blocks, B, H, block, ..] -> [B, H, T, ..]
+    o, logsum = whole(o), whole(logsum)
+    return o, (q, k, v, o, logsum)
+
+
+def _attend_back(scale, block, tile, kept, g):
+    """From each row's log-sum alone: a tile's ``P = exp(S - logsum)``
+    and ``dS = P (g V^T - rowsum(g o))`` are formed again, once for
+    ``dQ += dS K`` a block of queries at a time and once for ``dK += dS^T
+    Q``, ``dV += P^T g`` a tile of keys at a time, each loop ending at
+    the diagonal. Two passes, so that every sum is carried by its own
+    loop: one pass that adds ``dQ`` (or ``dK`` and ``dV``) into a whole
+    float32 array in place is a scatter under the nodes' ``vmap`` and
+    took 1.6 times as long on the v5e (``PERF.md`` Findings PR 38).
+    Products of ``v``'s and ``q``'s types into float32, as reverse mode
+    through the forward products has them."""
+    q, k, v, o, logsum = kept
+    blocks = q.shape[2] // block
+    mm = functools.partial(jnp.einsum, preferred_element_type=F32)
+    # a hand-written way back does not inherit the name stack of the way
+    # forward: the scope the device time is read by is set here
+    with jax.named_scope("mla.attn"):
+        drop = jnp.sum(g * o, axis=-1)
+        g = g.astype(v.dtype)
+
+        def again(i, j):
+            at = i * block
+            qi, gi = _cut(q, at, block), _cut(g, at, block)
+            kj, vj = _cut(k, j * tile, tile), _cut(v, j * tile, tile)
+            s = mm("bhqd,bhkd->bhqk", qi, kj) * scale
+            p = jnp.where(_at_or_under(i, j, block, tile),
+                          jnp.exp(s - _cut(logsum, at, block)[..., None]), 0.0)
+            ds = p * (mm("bhqd,bhkd->bhqk", gi, vj)
+                      - _cut(drop, at, block)[..., None]) * scale
+            return p.astype(v.dtype), ds.astype(q.dtype), qi, gi, kj
+
+        def rows(_, i):
+            def meet(j, dq):
+                _, ds, _, _, kj = again(i, j)
+                return dq + mm("bhqk,bhkd->bhqd", ds, kj)
+
+            dq = jax.lax.fori_loop(
+                0, _tiles_met(i, block, tile), meet,
+                jnp.zeros(q.shape[:2] + (block, q.shape[3]), F32))
+            return None, dq.astype(q.dtype)
+
+        def keys(_, j):
+            def meet(i, carried):
+                p, ds, qi, gi, _ = again(i, j)
+                return (carried[0] + mm("bhqk,bhqd->bhkd", ds, qi),
+                        carried[1] + mm("bhqk,bhqd->bhkd", p, gi))
+
+            dk, dv = jax.lax.fori_loop(
+                j * tile // block, blocks, meet,
+                (jnp.zeros(k.shape[:2] + (tile, k.shape[3]), F32),
+                 jnp.zeros(v.shape[:2] + (tile, v.shape[3]), F32)))
+            return None, (dk.astype(k.dtype), dv.astype(v.dtype))
+
+        _, dq = jax.lax.scan(rows, None, jnp.arange(blocks))
+        _, (dk, dv) = jax.lax.scan(keys, None,
+                                   jnp.arange(k.shape[2] // tile))
+        whole = lambda a, like: jnp.moveaxis(a, 0, 2).reshape(like.shape)
+        return whole(dq, q), whole(dk, k), whole(dv, v)
+
+
+_attend.defvjp(_attend_kept, _attend_back)
 
 
 class MLAMixer(nn.Module):

@@ -152,7 +152,7 @@ def test_harness_finds_the_program_it_drives(harness):
 
     from p2pfl_tpu import federation
     from p2pfl_tpu.federation.events import Events
-    from p2pfl_tpu.models import cnn
+    from p2pfl_tpu.models import cnn, ling
     from p2pfl_tpu.obs import trace as obs_trace
     from p2pfl_tpu.ops import pallas_gemm
     from p2pfl_tpu.utils import compile_cache
@@ -187,3 +187,27 @@ def test_harness_finds_the_program_it_drives(harness):
     assert isinstance(obs_trace.get_tracer().spans(), list)
     assert isinstance(pallas_gemm.decisions(), dict)
     assert isinstance(cnn.lowerings(), dict)
+    assert isinstance(ling.score_tiles(), dict)
+
+
+def test_score_tiles_share_reads_the_programs_record(harness, monkeypatch):
+    """``mla.score_tiles_share``: nothing on a program without the
+    record (the parent's ``models/ling.py``), and after a trace of the
+    cell's 4096 positions at tiles of 256 x 256 the 136 tiles at or
+    under the diagonal over the square's 256."""
+    import jax
+    import jax.numpy as jnp
+
+    from p2pfl_tpu.models import ling
+
+    reader = harness.load_module(
+        HOME / "readers" / "mla.score_tiles_share.py", "bench_reader")
+    one = lambda width: jax.ShapeDtypeStruct((1, 4096, 2, width),
+                                             jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: ling.causal_attention(
+        q, k, v, 1.0, block=256, tile=256), one(24), one(24), one(16))
+    assert reader.read({}) == 53.125
+    monkeypatch.setattr(ling, "_score_tiles", {})  # no such layer traced
+    assert reader.read({}) is None
+    monkeypatch.delattr(ling, "score_tiles")
+    assert reader.read({}) is None

@@ -213,6 +213,66 @@ def test_the_solves_device_time_is_read_by_its_scope(monkeypatch):
 
 
 # --------------------------------------------------------------------------
+# latent attention's tiles
+
+
+def dense_attention(q, k, v, scale):
+    """Masked softmax attention over the whole ``[T, T]`` score square."""
+    T = q.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    s = jnp.where(jnp.arange(T)[None, :] <= jnp.arange(T)[:, None], s,
+                  -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize("nodes", [None, 2], ids=["alone", "vmap"])
+@pytest.mark.parametrize("T", [100, 512, 768, 1000])
+def test_causal_attention_is_the_dense_masked_softmax(T, nodes):
+    """Value and the gradients to q, k and v, float32, with a block of
+    256: under one block, two blocks, three (an odd count), and a length
+    that is padded; alone and under the ``vmap`` over nodes a round puts
+    around it (the loops' bounds come from their counters and stay
+    unbatched)."""
+    shape = lambda *tail: (nodes, 2, T) + tail if nodes else (2, T) + tail
+    ks = jax.random.split(jax.random.PRNGKey(T), 4)
+    q, k = (jax.random.normal(key, shape(2, 24)) for key in ks[:2])
+    v, weigh = (jax.random.normal(key, shape(2, 16)) for key in ks[2:])
+
+    def both(attention):
+        f = jax.value_and_grad(
+            lambda q, k, v, weigh: jnp.sum(
+                weigh * attention(q, k, v, 24 ** -0.5)), argnums=(0, 1, 2))
+        return jax.jit(jax.vmap(f) if nodes else f)(q, k, v, weigh)
+
+    (got, d_got), (want, d_want) = both(ling.causal_attention), both(
+        dense_attention)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    for a, b in zip(d_got, d_want):
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(b))))
+
+
+@pytest.mark.parametrize("T, computed, square", [(4096, 136, 256),
+                                                 (768, 6, 9), (100, 1, 1)])
+def test_the_record_of_the_score_tiles(T, computed, square):
+    """``score_tiles()`` is what the last trace of ``causal_attention``
+    forms and what the full square holds (``mla.score_tiles_share``
+    reads it): 136 of 256 at the cell's 4096 positions with tiles of
+    256 x 256, one tile of one under a block."""
+    one = lambda width: jax.ShapeDtypeStruct((1, T, 2, width), jnp.bfloat16)
+    out = jax.eval_shape(
+        lambda q, k, v: ling.causal_attention(q, k, v, 1.0, block=256,
+                                              tile=256),
+        one(24), one(24), one(16))
+    assert out.shape == (1, T, 2, 16) and out.dtype == F32
+    side = min(T, 256)
+    assert ling.score_tiles() == {"computed": computed, "square": square,
+                                  "block": side, "tile": side}
+
+
+# --------------------------------------------------------------------------
 # each mixer and the expert layer, module against reference function
 
 

@@ -170,3 +170,49 @@ def test_ling_delta_rule_holds_no_triangular_solve(one_chip,
         "kda.solve" in name for name in highest), highest
     # 1,662,749,184 with the custom call (the same program at 24068e8)
     assert compiled.memory_analysis().temp_size_in_bytes <= 1_662_749_184
+
+
+def test_ling_attention_forms_no_tile_above_the_diagonal(one_chip,
+                                                         no_persistent_cache):
+    """The Ling cell's latent attention at its own shapes as the round
+    runs it (8 nodes under ``vmap``, a sequence of 4096, 32 heads of 192
+    and 128, bfloat16), value and gradient under the scope its device
+    time is read by. Until PR 38 a block of 256 queries met all 4096
+    keys under the mask (92 mentions of ``f32[8,32,256,4096]`` in the
+    optimized module, a sixth of the cell's round on the v5e); now the
+    score tiles are 256 x 256, the loops that form them are six (a block
+    or a tile and what it meets up to the diagonal: forward, and on the
+    way back once for the queries' gradient and once for the keys' and
+    values'), every device op bears ``mla.attn``, the hand-written way
+    back's too, and the step needs no more temporary memory than the
+    square did."""
+    from p2pfl_tpu.models import ling
+
+    n, T, H, D, Dv = 8, 4096, 32, 192, 128
+    shaped = lambda dtype, width: jax.ShapeDtypeStruct(
+        (n, 1, T, H, width), dtype, sharding=one_chip)
+
+    def loss(q, k, v, weigh):
+        with jax.named_scope("mla.attn"):
+            return jnp.sum(weigh * ling.causal_attention(q, k, v, D ** -0.5))
+
+    compiled = jax.jit(jax.vmap(jax.value_and_grad(
+        loss, argnums=(0, 1, 2)))).lower(
+        shaped(jnp.bfloat16, D), shaped(jnp.bfloat16, D),
+        shaped(jnp.bfloat16, Dv), shaped(jnp.float32, Dv)).compile()
+    hlo = compiled.as_text()
+    assert ling.score_tiles()["computed"] == 136
+    assert "convolution" in hlo  # the text is the optimized module
+    assert f"f32[{n},{H},256,{T}]" not in hlo
+    assert f"f32[{n},{H},256,256]" in hlo
+    assert len(re.findall(r" while\(", hlo)) == 6
+    # an instruction with an array for a result; a copy of one of this
+    # test's own arguments bears the argument's bare name
+    named = [name for name in re.findall(
+        r"= \w+\[\d[\d,]*\]\S* [\w-]+\(.*op_name=\"([^\"]*)\"", hlo)
+        if "/" in name]
+    assert len(named) > 100 and all("mla.attn" in name for name in named), [
+        name for name in named if "mla.attn" not in name]
+    assert any("transpose(" in name for name in named)  # the way back
+    # 3,399,016,448 with the square (the same program at b4d8916)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3_399_016_448

@@ -3,9 +3,9 @@
 Reference inventory (SURVEY.md §2.4, fedstellar/learning/pytorch/*):
 MNIST MLP/CNN, FEMNIST CNN, CIFAR10 ResNet9/18/34/50 + two MobileNets,
 SYSCALL MLP/Autoencoder/One-class-SVM, WADI MLP — plus ViT-Tiny for the
-stretch config in BASELINE.json and Ling-3.0-flash, a hybrid
-linear/latent-attention language model with sparse experts, as a frozen
-base under adapters.
+stretch config in BASELINE.json and two sparse-expert language models as
+frozen bases under adapters: Ling-3.0-flash (linear and latent attention)
+and Laguna-S-2.1 (window and full grouped-query attention).
 
 TPU-first design notes:
 - Normalization is **GroupNorm**, not BatchNorm: batch statistics are
@@ -25,6 +25,7 @@ from p2pfl_tpu.models.mobilenet import FasterMobileNet, SimpleMobileNet
 from p2pfl_tpu.models.syscall import SyscallModelAutoencoder, SyscallModelOneClassSVM
 from p2pfl_tpu.models.vit import ViT
 from p2pfl_tpu.models.ling import LingLM
+from p2pfl_tpu.models.laguna import LagunaLM
 
 __all__ = [
     "get_model",
@@ -44,4 +45,5 @@ __all__ = [
     "SyscallModelOneClassSVM",
     "ViT",
     "LingLM",
+    "LagunaLM",
 ]

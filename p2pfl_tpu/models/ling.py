@@ -26,6 +26,13 @@ One chip holds its share of a deployment: ``experts_held`` of the
 over all experts and computes its own experts' part of the result; what
 the absent experts would add is left out. No chosen (token, held
 expert) pair is dropped, whatever the imbalance.
+
+``models/laguna.py`` builds its model from the parts here that are not
+Ling's alone: ``causal_attention`` (its window, its grouped query heads
+and its scope are arguments), ``ExpertFFN`` over ``held_experts`` (the
+router is an argument), ``RMSNorm``, ``DenseFFN`` and ``CausalLM`` (the
+embedding, the head and the chunked loss). A change to one of them is
+measured in both models' cells.
 """
 
 from __future__ import annotations
@@ -332,160 +339,242 @@ def rope_interleaved(x, theta):
                      axis=-1).reshape(x.shape)
 
 
-_score_tiles: dict[str, int] = {}
+_score_tiles: dict[str, dict[str, int]] = {}
 
 
-def score_tiles() -> dict[str, int]:
-    """The last :func:`causal_attention` traced: ``{"computed": score
-    tiles a pass over a sequence forms, "square": tiles of the full
-    ``[T, T]`` square, "block", "tile": a tile's rows and columns}``.
-    Recorded at trace time, as ``cnn.lowerings()`` is."""
-    return dict(_score_tiles)
+def score_tiles(scope: str = "mla.attn") -> dict[str, int]:
+    """The last :func:`causal_attention` traced under ``scope``:
+    ``{"computed": score tiles a pass over a sequence forms, "square":
+    tiles of the full ``[T, T]`` square, "block", "tile": a tile's rows
+    and columns}``; empty where none was. Recorded at trace time, as
+    ``cnn.lowerings()`` is."""
+    return dict(_score_tiles.get(scope, {}))
 
 
-def causal_attention(q, k, v, scale, block=256, tile=256):
+def causal_attention(q, k, v, scale, *, window=None, scope="mla.attn",
+                     out_dtype=F32, block=256, tile=256):
     """Causal softmax attention a ``block`` of queries against a ``tile``
-    of keys at a time, and only the tiles at or under the diagonal: a
-    block's loop over key tiles ENDS at its own last row (a bound from
-    the outer loop's counter), so a tile wholly above the diagonal is
-    never formed, forward, recomputed or on the way back. A block and a
-    tile are taken by ``dynamic_slice`` from the one copy of the keys
-    and values: static slices of the keys to a band of blocks' past
+    of keys at a time, and only the tiles a block can see: its loop over
+    key tiles ENDS at its own last row (a bound from the outer loop's
+    counter), so a tile wholly above the diagonal is never formed,
+    forward, recomputed or on the way back; with a ``window`` (query
+    ``i`` sees keys ``i - window < j <= i``) the loop also STARTS at the
+    first tile the block's window touches, and on the way back the pass
+    over key tiles ends at the last block that can see the tile. A block
+    and a tile are taken by ``dynamic_slice`` from the one copy of the
+    keys and values: static slices of the keys to a band of blocks' past
     make a copy of them, and of their cotangents, a band (4.9 to 9.5 GB
-    of temporaries at 4 to 16 bands of the cell's shapes, ``PERF.md``
-    Findings PR 38). The row maximum, row sum and unnormalised output
-    are carried from tile to tile in float32; the way back is written
-    by hand (:func:`_attend_back`).
+    of temporaries at 4 to 16 bands of the Ling cell's shapes,
+    ``PERF.md`` Findings PR 38). The row maximum, row sum and
+    unnormalised output are carried from tile to tile in float32; the
+    way back is written by hand (:func:`_attend_back`).
+
+    ``k`` and ``v`` may have fewer heads than ``q`` (a divisor: query
+    head ``h`` reads key head ``h // group``). The ``group`` query heads
+    of a key head are laid side by side as the ROWS of a block (``group
+    x block`` rows against one tile of that head's keys): keys and
+    values are never repeated in memory, and the keys' and values'
+    gradients sum over the group inside the products.
+
     ``T`` at or under one block is one tile; otherwise ``T`` is padded
     to whole blocks and tiles (a padded key lies after every real
-    query). ``q, k`` [B, T, H, D], ``v`` [B, T, H, Dv]; scores, mask,
-    maximum, exponentials and sums float32, the probabilities in
-    ``v``'s type for their product with the values, which accumulates
-    in float32."""
-    T = q.shape[1]
+    query). ``q`` [B, T, H, D], ``k`` [B, T, H / group, D], ``v`` [B, T,
+    H / group, Dv]; scores, mask, maximum, exponentials and sums
+    float32, the probabilities in ``v``'s type for their product with
+    the values, which accumulates in float32; the normalised output
+    leaves in ``out_dtype`` (and is kept for the way back in it: at 72
+    heads of 128 over 4 x 8192 positions a float32 output is 1.2 GB, in
+    each of its two layouts). ``scope`` is the ``jax.named_scope`` the
+    CALLER opens around this call: the hand-written way back, which does
+    not inherit it, sets it by hand, and the trace-time record
+    (:func:`score_tiles`) is kept by it."""
+    B, T, H, _ = q.shape
+    if H % k.shape[2]:
+        raise ValueError(f"{H} query heads over {k.shape[2]} key heads")
+    group = H // k.shape[2]
     block = min(block, T)
     tile = min(tile, T + -T % block)
     pad = -T % math.lcm(block, tile)
     blocks, tiles = (T + pad) // block, (T + pad) // tile
-    _score_tiles.update(
-        computed=sum(_tiles_met(i, block, tile) for i in range(blocks)),
+    _score_tiles[scope] = dict(
+        computed=sum(_tiles_met(i, block, tile)
+                     - _first_tile(i, block, tile, window)
+                     for i in range(blocks)),
         square=blocks * tiles, block=block, tile=tile)
-    heads = lambda a: jnp.pad(
-        a, ((0, 0), (0, pad), (0, 0), (0, 0))).swapaxes(1, 2)
-    o = _attend(heads(q), heads(k), heads(v), scale, block, tile)
-    return o.swapaxes(1, 2)[:, :T]
+    rows = lambda a, group: _group_rows(
+        jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))), group, block)
+    o = _attend(rows(q, group), rows(k, 1), rows(v, 1), scale, block, tile,
+                window, group, scope, jnp.dtype(out_dtype))
+    return _ungroup_rows(o, group, block)[:, :T]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attend(q, k, v, scale, block, tile):
-    """:func:`causal_attention` on ``[B, H, T, .]`` operands, ``T`` whole
-    blocks and tiles."""
-    return _attend_kept(q, k, v, scale, block, tile)[0]
+def _group_rows(a, group, block):
+    """[B, T, H * group, D] -> [B, H, T * group, D]: a block of
+    positions holds its ``group`` heads' rows one head after the other."""
+    B, T, H, D = a.shape
+    if group == 1:  # the same layout, and the transpose XLA has always had
+        return a.swapaxes(1, 2)
+    a = a.reshape(B, T // block, block, H // group, group, D)
+    return a.transpose(0, 3, 1, 4, 2, 5).reshape(B, H // group, T * group, D)
+
+
+def _ungroup_rows(a, group, block):
+    B, H, rows, D = a.shape
+    if group == 1:
+        return a.swapaxes(1, 2)
+    a = a.reshape(B, H, rows // (group * block), group, block, D)
+    return a.transpose(0, 2, 4, 1, 3, 5).reshape(B, rows // group, H * group, D)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _attend(q, k, v, scale, block, tile, window, group, scope, out_dtype):
+    """:func:`causal_attention` on ``[B, H, T, .]`` keys and values, ``T``
+    whole blocks and tiles, and queries with their group as rows
+    (:func:`_group_rows`)."""
+    return _attend_kept(q, k, v, scale, block, tile, window, group, scope,
+                        out_dtype)[0]
 
 
 _cut = functools.partial(jax.lax.dynamic_slice_in_dim, axis=2)
 
 
 def _tiles_met(i, block, tile):
-    """How many key tiles query block ``i`` meets: those that start at or
-    before its last row. ``i`` a Python or a traced integer."""
+    """One past the last key tile query block ``i`` meets: those that
+    start at or before its last row. ``i`` a Python or a traced integer."""
     return -(-(i + 1) * block // tile)
 
 
-def _at_or_under(i, j, block, tile):
-    """The mask of block ``i`` against tile ``j``, [block, tile]."""
-    return (j * tile + jnp.arange(tile)[None, :]
-            <= i * block + jnp.arange(block)[:, None])
+def _first_tile(i, block, tile, window):
+    """The first key tile the window of query block ``i`` touches."""
+    if window is None:
+        return 0
+    first_key = i * block - window + 1
+    most = max if isinstance(first_key, int) else jnp.maximum
+    return most(first_key, 0) // tile
 
 
-def _attend_kept(q, k, v, scale, block, tile):
-    B, H, T, _ = q.shape
+def _blocks_seeing(j, block, tile, window, blocks):
+    """The query blocks that can see key tile ``j``: from the one that
+    holds its first key to one past the one that holds the last query
+    whose window reaches its last key."""
+    first = j * tile // block
+    if window is None:
+        return first, blocks
+    return first, jnp.minimum(((j + 1) * tile + window - 2) // block + 1,
+                              blocks)
+
+
+def _seen(i, j, block, tile, window, group):
+    """The mask of block ``i``'s rows against tile ``j``, [group x block,
+    tile]."""
+    key = j * tile + jnp.arange(tile)[None, :]
+    query = i * block + jnp.tile(jnp.arange(block), group)[:, None]
+    if window is None:
+        return key <= query
+    return jnp.logical_and(key <= query, key > query - window)
+
+
+def _attend_kept(q, k, v, scale, block, tile, window, group, scope,
+                 out_dtype):
+    B, H, T, _ = k.shape
     Dv = v.shape[-1]
+    rows = group * block
 
-    def rows(_, i):
-        qi = _cut(q, i * block, block)
+    def over_keys(_, i):
+        qi = _cut(q, i * rows, rows)
 
         def meet(j, carried):
             m, l, o = carried
             s = jnp.einsum("bhqd,bhkd->bhqk", qi, _cut(k, j * tile, tile),
                            preferred_element_type=F32) * scale
-            s = jnp.where(_at_or_under(i, j, block, tile), s, -jnp.inf)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))  # finite: tile 0
-            p = jnp.exp(s - m_new[..., None])
-            shrink = jnp.exp(m - m_new)
+            s = jnp.where(_seen(i, j, block, tile, window, group), s,
+                          -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            # finite without a window (tile 0 holds key 0); with one, a
+            # row whose own window starts past this tile has met no key
+            at = m_new if window is None else jnp.where(
+                m_new == -jnp.inf, 0.0, m_new)
+            p = jnp.exp(s - at[..., None])
+            shrink = jnp.exp(m - at)
             return (m_new, shrink * l + jnp.sum(p, axis=-1),
                     shrink[..., None] * o + jnp.einsum(
                         "bhqk,bhkd->bhqd", p.astype(v.dtype),
                         _cut(v, j * tile, tile), preferred_element_type=F32))
 
         m, l, o = jax.lax.fori_loop(
-            0, _tiles_met(i, block, tile), meet,
-            (jnp.full((B, H, block), -jnp.inf, F32),
-             jnp.zeros((B, H, block), F32), jnp.zeros((B, H, block, Dv), F32)))
-        return None, (o / l[..., None], m + jnp.log(l))
+            _first_tile(i, block, tile, window), _tiles_met(i, block, tile),
+            meet,
+            (jnp.full((B, H, rows), -jnp.inf, F32),
+             jnp.zeros((B, H, rows), F32), jnp.zeros((B, H, rows, Dv), F32)))
+        return None, ((o / l[..., None]).astype(out_dtype), m + jnp.log(l))
 
-    _, (o, logsum) = jax.lax.scan(rows, None, jnp.arange(T // block))
+    _, (o, logsum) = jax.lax.scan(over_keys, None, jnp.arange(T // block))
+    # [blocks, B, H, rows, ..] -> [B, H, T x group, ..]
     whole = lambda a: jnp.moveaxis(a, 0, 2).reshape(
-        (B, H, T) + a.shape[4:])  # [blocks, B, H, block, ..] -> [B, H, T, ..]
+        (B, H, T * group) + a.shape[4:])
     o, logsum = whole(o), whole(logsum)
     return o, (q, k, v, o, logsum)
 
 
-def _attend_back(scale, block, tile, kept, g):
+def _attend_back(scale, block, tile, window, group, scope, out_dtype, kept,
+                 g):
     """From each row's log-sum alone: a tile's ``P = exp(S - logsum)``
     and ``dS = P (g V^T - rowsum(g o))`` are formed again, once for
     ``dQ += dS K`` a block of queries at a time and once for ``dK += dS^T
-    Q``, ``dV += P^T g`` a tile of keys at a time, each loop ending at
-    the diagonal. Two passes, so that every sum is carried by its own
-    loop: one pass that adds ``dQ`` (or ``dK`` and ``dV``) into a whole
-    float32 array in place is a scatter under the nodes' ``vmap`` and
-    took 1.6 times as long on the v5e (``PERF.md`` Findings PR 38).
-    Products of ``v``'s and ``q``'s types into float32, as reverse mode
-    through the forward products has them."""
+    Q``, ``dV += P^T g`` a tile of keys at a time, each loop over the
+    tiles or blocks that see each other only. Two passes, so that every
+    sum is carried by its own loop: one pass that adds ``dQ`` (or ``dK``
+    and ``dV``) into a whole float32 array in place is a scatter under
+    the nodes' ``vmap`` and took 1.6 times as long on the v5e
+    (``PERF.md`` Findings PR 38). Products of ``v``'s and ``q``'s types
+    into float32, as reverse mode through the forward products has
+    them."""
     q, k, v, o, logsum = kept
-    blocks = q.shape[2] // block
+    blocks = k.shape[2] // block
+    rows = group * block
     mm = functools.partial(jnp.einsum, preferred_element_type=F32)
     # a hand-written way back does not inherit the name stack of the way
     # forward: the scope the device time is read by is set here
-    with jax.named_scope("mla.attn"):
-        drop = jnp.sum(g * o, axis=-1)
+    with jax.named_scope(scope):
+        drop = jnp.sum(g.astype(F32) * o, axis=-1)
         g = g.astype(v.dtype)
 
         def again(i, j):
-            at = i * block
-            qi, gi = _cut(q, at, block), _cut(g, at, block)
+            at = i * rows
+            qi, gi = _cut(q, at, rows), _cut(g, at, rows)
             kj, vj = _cut(k, j * tile, tile), _cut(v, j * tile, tile)
             s = mm("bhqd,bhkd->bhqk", qi, kj) * scale
-            p = jnp.where(_at_or_under(i, j, block, tile),
-                          jnp.exp(s - _cut(logsum, at, block)[..., None]), 0.0)
+            p = jnp.where(_seen(i, j, block, tile, window, group),
+                          jnp.exp(s - _cut(logsum, at, rows)[..., None]), 0.0)
             ds = p * (mm("bhqd,bhkd->bhqk", gi, vj)
-                      - _cut(drop, at, block)[..., None]) * scale
+                      - _cut(drop, at, rows)[..., None]) * scale
             return p.astype(v.dtype), ds.astype(q.dtype), qi, gi, kj
 
-        def rows(_, i):
+        def over_keys(_, i):
             def meet(j, dq):
                 _, ds, _, _, kj = again(i, j)
                 return dq + mm("bhqk,bhkd->bhqd", ds, kj)
 
             dq = jax.lax.fori_loop(
-                0, _tiles_met(i, block, tile), meet,
-                jnp.zeros(q.shape[:2] + (block, q.shape[3]), F32))
+                _first_tile(i, block, tile, window),
+                _tiles_met(i, block, tile), meet,
+                jnp.zeros(q.shape[:2] + (rows, q.shape[3]), F32))
             return None, dq.astype(q.dtype)
 
-        def keys(_, j):
+        def over_queries(_, j):
             def meet(i, carried):
                 p, ds, qi, gi, _ = again(i, j)
                 return (carried[0] + mm("bhqk,bhqd->bhkd", ds, qi),
                         carried[1] + mm("bhqk,bhqd->bhkd", p, gi))
 
             dk, dv = jax.lax.fori_loop(
-                j * tile // block, blocks, meet,
+                *_blocks_seeing(j, block, tile, window, blocks), meet,
                 (jnp.zeros(k.shape[:2] + (tile, k.shape[3]), F32),
                  jnp.zeros(v.shape[:2] + (tile, v.shape[3]), F32)))
             return None, (dk.astype(k.dtype), dv.astype(v.dtype))
 
-        _, dq = jax.lax.scan(rows, None, jnp.arange(blocks))
-        _, (dk, dv) = jax.lax.scan(keys, None,
+        _, dq = jax.lax.scan(over_keys, None, jnp.arange(blocks))
+        _, (dk, dv) = jax.lax.scan(over_queries, None,
                                    jnp.arange(k.shape[2] // tile))
         whole = lambda a, like: jnp.moveaxis(a, 0, 2).reshape(like.shape)
         return whole(dq, q), whole(dk, k), whole(dv, v)
@@ -535,15 +624,16 @@ class MLAMixer(nn.Module):
 # the expert layer
 
 
-def route(x, router, bias, n_group, topk_group, top_k, scale):
-    """DeepSeek-V3's router: sigmoid scores over ALL experts, a
-    selection bias added for the choice only, groups scored by the sum
-    of their two largest, ``topk_group`` groups kept, the ``top_k``
-    largest among them chosen, weights ``scale * s_i / sum_chosen s_j``.
-    float32 throughout. Returns the chosen ids and weights, [N, top_k]."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(F32), router.astype(F32),
+def route(x, frozen, *, n_group, topk_group, top_k, scale):
+    """DeepSeek-V3's router: sigmoid scores over ALL experts
+    (``frozen["router"]``), a selection bias (``frozen["bias"]``) added
+    for the choice only, groups scored by the sum of their two largest,
+    ``topk_group`` groups kept, the ``top_k`` largest among them chosen,
+    weights ``scale * s_i / sum_chosen s_j``. float32 throughout.
+    Returns the chosen ids and weights, [N, top_k]."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(F32), frozen["router"].astype(F32),
                                precision=HI))
-    sel = s + bias.astype(F32)
+    sel = s + frozen["bias"].astype(F32)
     n, e = sel.shape
     grouped = sel.reshape(n, n_group, e // n_group)
     top2 = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
@@ -556,21 +646,28 @@ def route(x, router, bias, n_group, topk_group, top_k, scale):
     return idx, scale * w / jnp.sum(w, axis=1, keepdims=True)
 
 
-def _dispatch(idx, n_experts, held, offset, top_k):
+#: most rows of a block of sorted pairs: its float32 output is ``rows x
+#: d`` (0.8 GB at 3072 wide), and twice the even share of 10 chosen of
+#: 256 with 64 held would be 2.5 times as many
+BLOCK_ROWS = 65536
+
+
+def _dispatch(idx, n_experts, held, offset):
     """The chosen pairs sorted by held expert: ``order`` (pair ids, the
     pairs of absent experts last, padded to whole blocks), the held
     experts' ``counts`` and running ``ends``, and the static block shape:
     a block holds twice the pairs expected under even routing (a
-    multiple of 128), and the blocks cover every pair that can be
-    chosen."""
-    N = idx.shape[0]
+    multiple of 128) and at most ``BLOCK_ROWS``, and the blocks cover
+    every pair that can be chosen."""
+    N, top_k = idx.shape
     local = idx - offset
     here = jnp.logical_and(local >= 0, local < held)
     pair_e = jnp.where(here, local, held).reshape(-1)
     order = jnp.argsort(pair_e, stable=True)
     counts = jnp.bincount(pair_e, length=held + 1)[:held]
     most = N * min(top_k, held)
-    rows = min(most, -(-2 * N * top_k * held // n_experts // 128) * 128)
+    rows = min(most, BLOCK_ROWS,
+               -(-2 * N * top_k * held // n_experts // 128) * 128)
     n_blocks = -(-most // rows)
     order = jnp.pad(order, (0, max(n_blocks * rows - order.shape[0], 0)))
     return order, counts, jnp.cumsum(counts), rows, n_blocks
@@ -600,12 +697,15 @@ def _block(x, pair_w, first, order, counts, ends, w_gu, w_d, *, rows, top_k,
         return jnp.zeros(x.shape, F32).at[tok].add(ys)
 
 
-def held_experts(x, frozen, *, offset, n_group, topk_group, top_k, scale,
-                 dtype):
+def held_experts(x, frozen, *, router, offset, dtype):
     """The held experts' part of the layer's output for ``x`` [N, d]
     and what the dispatch counted: ``y`` [N, d] and ``stats`` [2]
     (chosen pairs not computed, which has to be 0; the largest load of
-    a held expert over their mean).
+    a held expert over their mean). ``router`` is the model's own:
+    ``(x, frozen) -> (ids, weights)``, both [N, top_k], over all the
+    ``frozen["router"].shape[1]`` experts (:func:`route` with its groups
+    and bias for Ling); ``frozen["gate_up"]`` and ``frozen["down"]`` are
+    the experts ``offset ..`` held here.
 
     The chosen (token, held expert) pairs are sorted by expert and run
     through one grouped product a projection, a block of rows at a time
@@ -614,13 +714,13 @@ def held_experts(x, frozen, *, offset, n_group, topk_group, top_k, scale,
     w_gu, w_d = frozen["gate_up"], frozen["down"]
     held, n_experts = w_gu.shape[0], frozen["router"].shape[1]
     with jax.named_scope("moe.route"):
-        idx, w = route(x, frozen["router"], frozen["bias"], n_group,
-                       topk_group, top_k, scale)
+        idx, w = router(x, frozen)
     with jax.named_scope("moe.dispatch"):
         order, counts, ends, rows, n_blocks = _dispatch(
-            idx, n_experts, held, offset, top_k)
+            idx, n_experts, held, offset)
         pair_w = jnp.pad(w.reshape(-1), (0, order.shape[0] - w.size))[order]
-    block = functools.partial(_block, rows=rows, top_k=top_k, dtype=dtype)
+    block = functools.partial(_block, rows=rows, top_k=idx.shape[1],
+                              dtype=dtype)
 
     def one(y, first):
         return y + jax.lax.cond(
@@ -638,8 +738,7 @@ def held_experts(x, frozen, *, offset, n_group, topk_group, top_k, scale,
     return y.astype(dtype), stats
 
 
-def held_experts_back(x, g, frozen, *, offset, n_group, topk_group, top_k,
-                      scale, dtype):
+def held_experts_back(x, g, frozen, *, router, offset, dtype):
     """The gradient of ``held_experts``'s ``y`` to its rows, by
     recomputation: the forward pass kept nothing, the base has no
     gradient. Each block is differentiated where it is recomputed (to
@@ -651,13 +750,13 @@ def held_experts_back(x, g, frozen, *, offset, n_group, topk_group, top_k,
     g = g.astype(F32)
     with jax.named_scope("moe.route"):
         w, route_back, idx = jax.vjp(
-            lambda x_: route(x_, frozen["router"], frozen["bias"], n_group,
-                             topk_group, top_k, scale)[::-1], x, has_aux=True)
+            lambda x_: router(x_, frozen)[::-1], x, has_aux=True)
     with jax.named_scope("moe.dispatch"):
         order, counts, ends, rows, n_blocks = _dispatch(
-            idx, n_experts, held, offset, top_k)
+            idx, n_experts, held, offset)
         pair_w = jnp.pad(w.reshape(-1), (0, order.shape[0] - w.size))[order]
-    block = functools.partial(_block, rows=rows, top_k=top_k, dtype=dtype)
+    block = functools.partial(_block, rows=rows, top_k=idx.shape[1],
+                              dtype=dtype)
 
     def one(carry, first):
         dx, dpw = carry
@@ -702,15 +801,22 @@ def _frozen_experts(**static):
 
 
 class ExpertFFN(nn.Module):
+    """The held experts' part of a routed layer plus its one shared
+    expert. ``router`` is the model's own ``(x, frozen) -> (ids,
+    weights)`` over ``frozen["router"]`` [d, n_experts]; without one it
+    is :func:`route` over ``n_group`` groups, which also holds a seeded
+    selection bias."""
+
     n_experts: int
     experts_held: int
     expert_offset: int
     width: int
     shared_width: int
     top_k: int
-    n_group: int
-    topk_group: int
-    scale: float
+    n_group: int = 1
+    topk_group: int = 1
+    scale: float = 1.0
+    router: Any = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -720,24 +826,27 @@ class ExpertFFN(nn.Module):
         he = nn.initializers.variance_scaling(1.0, "fan_in", "normal",
                                               in_axis=-2, out_axis=-1,
                                               batch_axis=(0,))
-        frozen = {
-            "router": self.param("router", nn.initializers.lecun_normal(),
-                                 (d, self.n_experts), self.param_dtype),
+        frozen = {"router": self.param(
+            "router", nn.initializers.lecun_normal(), (d, self.n_experts),
+            self.param_dtype)}
+        router = self.router
+        if router is None:
             # the selection bias: frozen and seeded (trained models balance
             # their load with it; it takes no gradient)
-            "bias": self.param("router_bias", nn.initializers.normal(0.01),
-                               (self.n_experts,), self.param_dtype),
-            "gate_up": self.param("experts_gate_up", he,
-                                  (self.experts_held, d, 2 * self.width),
-                                  self.param_dtype),
-            "down": self.param("experts_down", he,
-                               (self.experts_held, self.width, d),
-                               self.param_dtype),
-        }
-        layer = _frozen_experts(
-            offset=self.expert_offset, n_group=self.n_group,
-            topk_group=self.topk_group, top_k=self.top_k, scale=self.scale,
-            dtype=self.dtype)
+            frozen["bias"] = self.param(
+                "router_bias", nn.initializers.normal(0.01),
+                (self.n_experts,), self.param_dtype)
+            router = functools.partial(
+                route, n_group=self.n_group, topk_group=self.topk_group,
+                top_k=self.top_k, scale=self.scale)
+        frozen["gate_up"] = self.param(
+            "experts_gate_up", he, (self.experts_held, d, 2 * self.width),
+            self.param_dtype)
+        frozen["down"] = self.param(
+            "experts_down", he, (self.experts_held, self.width, d),
+            self.param_dtype)
+        layer = _frozen_experts(router=router, offset=self.expert_offset,
+                                dtype=self.dtype)
         y, stats = layer(x.reshape(B * T, d), frozen)
         with jax.named_scope("moe.shared"):
             shared = _dense(d, "shared_down", self)(swiglu(
@@ -789,58 +898,18 @@ class LingBlock(nn.Module):
         return x + DenseFFN(c["dense_width"], name="ffn", **kw)(h), None
 
 
-class LingLM(nn.Module):
+class CausalLM(nn.Module):
     """Token ids [B, T] -> logits [B, T, vocab] (``__call__``: small
     sizes only), or with labels the mean next-token loss, head and loss a
-    chunk of positions at a time (``loss``)."""
+    chunk of positions at a time (``loss``). A model gives ``vocab``,
+    ``hidden``, ``eps``, ``loss_chunk``, ``dtype`` and ``param_dtype``
+    as fields and in its ``setup`` its ``blocks`` (each ``x -> (x,
+    expert-layer counters or None)``) between :meth:`setup_ends`."""
 
-    vocab: int = 64
-    hidden: int = 32
-    layers: int = 4  # layers kept, taken from ``first_layer`` on
-    first_layer: int = 0  # index in the published stack of the first
-    layer_group: int = 3  # every ``layer_group``-th layer is MLA
-    first_dense: int = 1  # published layers below this have a dense FFN
-    heads: int = 2
-    head_dim: int = 16  # KDA's key and value width
-    nope: int = 16
-    rope: int = 8
-    v_dim: int = 16
-    kv_rank: int = 12
-    theta: float = 6e6
-    conv: int = 4
-    kda_lower_bound: float = -5.0
-    kda_chunk: int = 64
-    dense_width: int = 48
-    n_experts: int = 16
-    experts_held: int = 4
-    expert_offset: int = 0
-    expert_width: int = 8
-    shared_width: int = 8
-    top_k: int = 4
-    n_group: int = 4
-    topk_group: int = 2
-    route_scale: float = 2.5
-    eps: float = 1e-6
-    loss_chunk: int = 1024
-    remat: bool = True
-    dtype: Any = jnp.bfloat16
-    param_dtype: Any = jnp.float32
-
-    def setup(self):
-        cfg = {f: getattr(self, f) for f in (
-            "heads", "head_dim", "nope", "rope", "v_dim", "kv_rank", "theta",
-            "conv", "kda_lower_bound", "kda_chunk", "dense_width", "n_experts",
-            "experts_held", "expert_offset", "expert_width", "shared_width",
-            "top_k", "n_group", "topk_group", "route_scale", "eps", "dtype",
-            "param_dtype")}
-        block = nn.remat(LingBlock) if self.remat else LingBlock
+    def setup_ends(self):
         self.embed = nn.Embed(self.vocab, self.hidden, dtype=self.dtype,
                               param_dtype=self.param_dtype,
                               embedding_init=nn.initializers.normal(1.0))
-        self.blocks = [
-            block("mla" if (i + 1) % self.layer_group == 0 else "kda",
-                  i >= self.first_dense, cfg, name=f"layer_{i}")
-            for i in range(self.first_layer, self.first_layer + self.layers)]
         self.final_norm = RMSNorm(self.eps, dtype=self.dtype,
                                   param_dtype=self.param_dtype)
         self.head = self.param(
@@ -891,6 +960,57 @@ class LingLM(nn.Module):
             aux = {"moe.dropped_pairs": stats[:, 0],
                    "moe.load_max_over_mean": stats[:, 1]}
         return loss, aux
+
+
+class LingLM(CausalLM):
+    """Ling-3.0-flash: ``layers`` published layers from ``first_layer``
+    on, every size a keyword argument."""
+
+    vocab: int = 64
+    hidden: int = 32
+    layers: int = 4  # layers kept, taken from ``first_layer`` on
+    first_layer: int = 0  # index in the published stack of the first
+    layer_group: int = 3  # every ``layer_group``-th layer is MLA
+    first_dense: int = 1  # published layers below this have a dense FFN
+    heads: int = 2
+    head_dim: int = 16  # KDA's key and value width
+    nope: int = 16
+    rope: int = 8
+    v_dim: int = 16
+    kv_rank: int = 12
+    theta: float = 6e6
+    conv: int = 4
+    kda_lower_bound: float = -5.0
+    kda_chunk: int = 64
+    dense_width: int = 48
+    n_experts: int = 16
+    experts_held: int = 4
+    expert_offset: int = 0
+    expert_width: int = 8
+    shared_width: int = 8
+    top_k: int = 4
+    n_group: int = 4
+    topk_group: int = 2
+    route_scale: float = 2.5
+    eps: float = 1e-6
+    loss_chunk: int = 1024
+    remat: bool = True
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        cfg = {f: getattr(self, f) for f in (
+            "heads", "head_dim", "nope", "rope", "v_dim", "kv_rank", "theta",
+            "conv", "kda_lower_bound", "kda_chunk", "dense_width", "n_experts",
+            "experts_held", "expert_offset", "expert_width", "shared_width",
+            "top_k", "n_group", "topk_group", "route_scale", "eps", "dtype",
+            "param_dtype")}
+        block = nn.remat(LingBlock) if self.remat else LingBlock
+        self.setup_ends()
+        self.blocks = [
+            block("mla" if (i + 1) % self.layer_group == 0 else "kda",
+                  i >= self.first_dense, cfg, name=f"layer_{i}")
+            for i in range(self.first_layer, self.first_layer + self.layers)]
 
 
 @register_model("ling-3.0-flash", "ling")

@@ -525,9 +525,13 @@ def note_counted(values: dict) -> None:
         v = np.asarray(v, np.float64)
         v = v.max(axis=0).reshape((-1,) + v.shape[3:])  # [steps, ...]
         with _xla_lock:
-            at = _counted.setdefault(name, {
-                "sum": np.zeros(v.shape[1:]), "steps": 0,
-                "max": np.full(v.shape[1:], -np.inf)})
+            at = _counted.get(name)
+            if at is None or at["sum"].shape != v.shape[1:]:
+                # the first round, or another model's layers under the
+                # same name (two scenarios in one process): a new record
+                at = _counted[name] = {
+                    "sum": np.zeros(v.shape[1:]), "steps": 0,
+                    "max": np.full(v.shape[1:], -np.inf)}
             at["sum"] = at["sum"] + v.sum(axis=0)
             at["max"] = np.maximum(at["max"], v.max(axis=0))
             at["steps"] += v.shape[0]

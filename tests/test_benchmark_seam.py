@@ -188,6 +188,9 @@ def test_harness_finds_the_program_it_drives(harness):
     assert isinstance(pallas_gemm.decisions(), dict)
     assert isinstance(cnn.lowerings(), dict)
     assert isinstance(ling.score_tiles(), dict)
+    # kept by the scope a model's attention runs under
+    # (``readers/swa.score_tiles_share.py``)
+    assert isinstance(ling.score_tiles("swa.attn"), dict)
 
 
 def test_score_tiles_share_reads_the_programs_record(harness, monkeypatch):
@@ -208,6 +211,37 @@ def test_score_tiles_share_reads_the_programs_record(harness, monkeypatch):
         q, k, v, 1.0, block=256, tile=256), one(24), one(24), one(16))
     assert reader.read({}) == 53.125
     monkeypatch.setattr(ling, "_score_tiles", {})  # no such layer traced
+    assert reader.read({}) is None
+    monkeypatch.delattr(ling, "score_tiles")
+    assert reader.read({}) is None
+
+
+def test_window_score_tiles_share_reads_its_own_scopes_record(harness,
+                                                              monkeypatch):
+    """``swa.score_tiles_share``: nothing where no window layer was
+    traced, nothing on a program whose ``score_tiles`` takes no scope
+    (the parent's) or has none, and after a trace of the cell's 8192
+    positions under a window of 512 the 93 tiles the blocks' windows
+    touch over the square's 1024; a trace under another scope does not
+    move it."""
+    import jax
+    import jax.numpy as jnp
+
+    from p2pfl_tpu.models import ling
+
+    reader = harness.load_module(
+        HOME / "readers" / "swa.score_tiles_share.py", "bench_reader")
+    monkeypatch.setattr(ling, "_score_tiles", {})
+    assert reader.read({}) is None
+    one = lambda heads: jax.ShapeDtypeStruct((1, 8192, heads, 8),
+                                             jnp.bfloat16)
+    trace = lambda **how: jax.eval_shape(
+        lambda q, k, v: ling.causal_attention(q, k, v, 1.0, **how),
+        one(9), one(1), one(1))
+    trace(window=512, scope="swa.attn")
+    trace(scope="gqa.attn")
+    assert reader.read({}) == 100.0 * 93 / 1024
+    monkeypatch.setattr(ling, "score_tiles", lambda: {"computed": 1})
     assert reader.read({}) is None
     monkeypatch.delattr(ling, "score_tiles")
     assert reader.read({}) is None

@@ -95,8 +95,11 @@ def test_ling_expert_layer_is_one_grouped_product_over_all_nodes(
     from p2pfl_tpu.models import ling
 
     n, T, d, held, width = 8, 4096, 2560, 64, 768
-    layer = ling._frozen_experts(offset=0, n_group=8, topk_group=4, top_k=8,
-                                 scale=2.5, dtype=jnp.bfloat16)
+    import functools
+
+    layer = ling._frozen_experts(
+        router=functools.partial(ling.route, n_group=8, topk_group=4, top_k=8,
+                                 scale=2.5), offset=0, dtype=jnp.bfloat16)
     bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                                                sharding=one_chip)
     frozen = {"router": bf16(d, 512), "bias": bf16(512),
@@ -216,3 +219,56 @@ def test_ling_attention_forms_no_tile_above_the_diagonal(one_chip,
     assert any("transpose(" in name for name in named)  # the way back
     # 3,399,016,448 with the square (the same program at b4d8916)
     assert compiled.memory_analysis().temp_size_in_bytes <= 3_399_016_448
+
+
+@pytest.mark.parametrize("heads, window, scope, loops", [
+    (72, 512, "swa.attn", 6), (48, None, "gqa.attn", 6)])
+def test_laguna_attention_forms_only_the_tiles_a_block_sees(
+        one_chip, no_persistent_cache, heads, window, scope, loops):
+    """The Laguna cell's two kinds of attention at their own shapes as
+    the round runs them (4 nodes under ``vmap``, a sequence of 8192, 72
+    or 48 query heads of 128 over 8 key heads, bfloat16), value and
+    gradient. A window layer forms 93 of the 1024 score tiles of 256 x
+    256 (its loops start where the block's window starts), a full layer
+    the causal 528; a tile's scores are ``[nodes, 8 key heads, group x
+    256 rows, 256]`` (the group folded into the rows: no array of keys
+    or values repeated for the query heads, which would be ``[4, 72 or
+    48, 8192, 128]`` bfloat16); the output leaves in bfloat16, as the
+    model asks; every device op bears the scope its device time is read
+    by, the hand-written way back's too."""
+    from p2pfl_tpu.models import ling
+
+    n, T, G, D = 4, 8192, 8, 128
+    shaped = lambda dtype, h: jax.ShapeDtypeStruct(
+        (n, 1, T, h, D), dtype, sharding=one_chip)
+
+    def loss(q, k, v, weigh):
+        with jax.named_scope(scope):
+            return jnp.sum(weigh * ling.causal_attention(
+                q, k, v, D ** -0.5, window=window, scope=scope,
+                out_dtype=jnp.bfloat16))
+
+    compiled = jax.jit(jax.vmap(jax.value_and_grad(
+        loss, argnums=(0, 1, 2)))).lower(
+        shaped(jnp.bfloat16, heads), shaped(jnp.bfloat16, G),
+        shaped(jnp.bfloat16, G), shaped(jnp.bfloat16, heads)).compile()
+    hlo = compiled.as_text()
+    tiles = ling.score_tiles(scope)
+    assert (tiles["computed"], tiles["square"]) == (
+        (93, 1024) if window else (528, 1024))
+    assert "convolution" in hlo  # the text is the optimized module
+    rows = heads // G * 256
+    assert f"f32[{n},{G},{rows},256]" in hlo
+    assert f"f32[{n},{G},{rows},{T}]" not in hlo
+    assert len(re.findall(r" while\(", hlo)) == loops
+    # keys and values keep their 8 heads: nothing of theirs is as large
+    # as the queries
+    assert not re.findall(rf"bf16\[{n},(?:1,)?{heads},{T},{D}\]\S* "
+                          r"(?:broadcast|concatenate)\(", hlo)
+    named = [name for name in re.findall(
+        r"= \w+\[\d[\d,]*\]\S* [\w-]+\(.*op_name=\"([^\"]*)\"", hlo)
+        if "/" in name]
+    assert len(named) > 100 and all(scope in name for name in named), [
+        name for name in named if scope not in name]
+    assert any("transpose(" in name for name in named)  # the way back
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9

@@ -85,21 +85,52 @@ def swiglu(gate_up):
 # Kimi Delta Attention, chunk-wise
 
 
-def head_blocks(core, head_block, *arrays):
-    """``core(*arrays)`` over ``head_block`` heads at a time (axis 2 of
-    every array), one block after the other and each recomputed on the
+def head_blocks(core, head_block, rows, frozen=()):
+    """``core(*rows, *frozen)`` over ``head_block`` heads at a time (axis 2
+    of every array), one block after the other and each recomputed on the
     way back: heads do not meet in the delta rule, and its float32
     intermediates for all 32 heads of 8 nodes' sequences are more than a
-    chip holds."""
-    H = arrays[0].shape[2]
-    if H <= head_block or H % head_block:
-        return core(*arrays)
-    blocks = lambda a: jnp.moveaxis(a.reshape(
+    chip holds.
+
+    ``rows`` lead with the batch; ``frozen`` is what every row shares
+    (the base's taps and norm scale, a leading axis of 1). On a FORWARD
+    pass under the round's ``vmap`` over nodes THE NODES ARE THE BATCH:
+    the blocks run once, on the rows of all nodes laid end to end
+    (``ops/fold.py``), and a mapped ``frozen`` is refused. A ``vmap`` of
+    the delta rule's scan puts the node axis behind the chunk axis again
+    (``[NC, n, 1, H, C, K]`` from ``[n, NC, 1, ..]``) and brings back the
+    transposing copies that the chunk-first layout of :func:`kda_heads`
+    saves. Forward passes are two of a training step's three and all of
+    an evaluation's. The way back is reverse mode through the plain loop,
+    each block under ``jax.checkpoint``, under the caller's own ``vmap``:
+    folded too it ran no faster on the v5e and needed 245 MB more of
+    temporaries in a round program that stands at the chip's limit
+    (``PERF.md`` Findings PR 40), and so ``frozen`` takes each node's
+    own gradient."""
+    H = rows[0].shape[2]
+    cut = lambda a: jnp.moveaxis(a.reshape(
         a.shape[:2] + (H // head_block, head_block) + a.shape[3:]), 2, 0)
-    o = jax.lax.map(lambda args: jax.checkpoint(core)(*args),
-                    tuple(map(blocks, arrays)))
-    o = jnp.moveaxis(o, 0, 2)  # [B, T, blocks, head_block, V]
-    return o.reshape(o.shape[:2] + (H, o.shape[-1]))
+
+    def blocks(rows, frozen):
+        if H <= head_block or H % head_block:
+            return core(*rows, *frozen)
+        o = jax.lax.map(
+            lambda args: jax.checkpoint(core)(*args[0], *args[1]),
+            jax.tree.map(cut, (rows, frozen)))
+        o = jnp.moveaxis(o, 0, 2)  # [B, T, blocks, head_block, V]
+        return o.reshape(o.shape[:2] + (H, o.shape[-1]))
+
+    once = fold_rows(blocks, lambda out: True)
+
+    @jax.custom_vjp
+    def folded(rows, frozen):
+        return once(rows, frozen)
+
+    # the way back keeps the inputs alone; ``vjp``'s own forward loop has
+    # no reader (its blocks keep nothing) and XLA drops it
+    folded.defvjp(lambda rows, frozen: (once(rows, frozen), (rows, frozen)),
+                  lambda kept, g: jax.vjp(blocks, *kept)[1](g))
+    return folded(tuple(rows), tuple(frozen))
 
 
 def unit_lower_inverse(A):
@@ -143,16 +174,21 @@ def unit_lower_inverse(A):
 def unit_lower_solve(A, rhs):
     """``X`` of ``(I + A) X = rhs`` for strictly lower-triangular ``A``
     [..., C, C] and ``rhs`` [..., C, W], float32: the inverse
-    (:func:`unit_lower_inverse`), then one product at ``HIGHEST``. The
-    way back keeps the inverse and ``X`` and is two more such products:
-    ``d_rhs = T^T g``, ``d_A = -d_rhs X^T`` below the diagonal."""
+    (:func:`unit_lower_inverse`), then one product at ``HIGHEST``.
+    ``rhs`` may be a tuple of right-hand sides, which share the one
+    inverse and take a product each: side by side in one ``[.., C, 2
+    K]`` array they are a concatenation before and two slices after, a
+    pass over each in HBM that computes nothing. The way back keeps the
+    inverse and every ``X`` and is two more such products a right-hand
+    side: ``d_rhs = T^T g``, ``d_A = -sum d_rhs X^T`` below the
+    diagonal."""
     return _solve(A, rhs)[0]
 
 
 def _solve(A, rhs):
     with jax.named_scope("kda.solve"):
         T = unit_lower_inverse(A)
-        X = jnp.matmul(T, rhs, precision=HI)
+        X = jax.tree.map(lambda r: jnp.matmul(T, r, precision=HI), rhs)
     return X, (T, X)
 
 
@@ -161,9 +197,11 @@ def _solve_back(kept, g):
     # a hand-written way back does not inherit the name stack of the way
     # forward: the scopes the device time is read by are set here
     with jax.named_scope("kda.scan"), jax.named_scope("kda.solve"):
-        d_rhs = jnp.einsum("...ji,...jw->...iw", T, g, precision=HI)
-        d_A = -jnp.tril(jnp.einsum("...iw,...jw->...ij", d_rhs, X,
-                                   precision=HI), -1)
+        d_rhs = jax.tree.map(lambda g_: jnp.einsum(
+            "...ji,...jw->...iw", T, g_, precision=HI), g)
+        d_A = -jnp.tril(sum(
+            jnp.einsum("...iw,...jw->...ij", d, x, precision=HI)
+            for d, x in zip(jax.tree.leaves(d_rhs), jax.tree.leaves(X))), -1)
     return d_A, d_rhs
 
 
@@ -175,7 +213,7 @@ def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16,
     """:func:`kda_heads`, a block of heads at a time."""
     return head_blocks(
         functools.partial(kda_heads, chunk=chunk, sub=sub, dtype=dtype),
-        head_block, q, k, v, g, beta)
+        head_block, (q, k, v, g, beta))
 
 
 def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
@@ -204,7 +242,6 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
     ``dtype`` is; the other products take ``dtype`` operands and
     accumulate in float32."""
     B, T, H, K = q.shape
-    V = v.shape[-1]
     C = chunk
     pad = -T % C
     if pad:  # zero keys, no decay, beta 0: the state passes unchanged
@@ -212,10 +249,10 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
             a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
         q, k, v, g, beta = map(longer, (q, k, v, g, beta))
     NC = (T + pad) // C
-    chunks = lambda a: a.astype(F32).reshape(
-        (B, NC, C) + a.shape[2:]).swapaxes(2, 3)  # [B, NC, H, C, ..]
-    q, k, v, g = map(chunks, (q, k, v, g))
-    beta = beta.astype(F32).reshape(B, NC, C, H).swapaxes(2, 3)  # [B,NC,H,C]
+    # [B, T, H, ..] -> [NC, B, H, C, ..]: the one transposing copy in
+    chunks = lambda a: jnp.moveaxis(a.astype(F32).reshape(
+        (B, NC, C) + a.shape[2:]), (1, 3), (0, 2))
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
     G = jnp.cumsum(g, axis=3)
     mm = functools.partial(jnp.einsum, preferred_element_type=F32)
     lo = lambda a: a.astype(dtype)
@@ -229,22 +266,21 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
         right = lo(k[..., :s1, :] * jnp.exp(jnp.minimum(
             ref - G[..., :s1, :], 80.0)))  # <= 0 before s0, <= 80 inside
         widen = lambda m: jnp.pad(m, ((0, 0),) * 4 + ((0, C - s1),))
-        rows_a.append(widen(mm("bnhik,bnhjk->bnhij",
+        rows_a.append(widen(mm("nbhik,nbhjk->nbhij",
                                lo(k[..., s0:s1, :] * left), right)))
-        rows_b.append(widen(mm("bnhik,bnhjk->bnhij",
+        rows_b.append(widen(mm("nbhik,nbhjk->nbhij",
                                lo(q[..., s0:s1, :] * left), right)))
     i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
     A = jnp.where(j < i, jnp.concatenate(rows_a, axis=3), 0.0) * beta[..., None]
     Bm = jnp.where(j <= i, jnp.concatenate(rows_b, axis=3), 0.0)
 
-    # ---- (I + A) [Wv | Wk] = beta [V | K e^G]
-    rhs = jnp.concatenate([v, k * jnp.exp(G)], axis=-1) * beta[..., None]
-    solved = unit_lower_solve(A, rhs)
-    Wv, Wk = solved[..., :V], solved[..., V:]
+    # ---- (I + A) Wv = beta V, (I + A) Wk = beta K e^G
+    Wv, Wk = unit_lower_solve(
+        A, (v * beta[..., None], k * jnp.exp(G) * beta[..., None]))
     Qp = q * jnp.exp(G)
     G_end = G[..., -1:, :]
     Kd = k * jnp.exp(G_end - G)
-    decay = jnp.exp(G_end[..., 0, :])  # [B, NC, H, K]
+    decay = jnp.exp(G_end[..., 0, :])  # [NC, B, H, K]
 
     # ---- the recurrence over chunks
     def step(S, xs):
@@ -255,11 +291,10 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
         S = decay_c[..., None] * S + mm("bhck,bhcv->bhkv", lo(Kd_c), lo(U))
         return S, O
 
-    first = lambda a: jnp.moveaxis(a, 1, 0)
-    _, O = jax.lax.scan(step, jnp.zeros((B, H, K, V), F32),
-                        tuple(map(first, (Wv, Wk, Qp, Bm, Kd, decay))))
-    # [NC, B, H, C, V] -> [B, T, H, V]
-    O = jnp.moveaxis(O, 0, 1).swapaxes(2, 3).reshape(B, NC * C, H, V)
+    _, O = jax.lax.scan(step, jnp.zeros((B, H, K, v.shape[-1]), F32),
+                        (Wv, Wk, Qp, Bm, Kd, decay))
+    # [NC, B, H, C, V] -> [B, T, H, V]: the one transposing copy out
+    O = jnp.moveaxis(O, (0, 2), (1, 3)).reshape(B, NC * C, H, -1)
     return O[:, :T]
 
 
@@ -285,6 +320,12 @@ class KDAMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        """The projections and the output projection are per node under
+        the round's ``vmap`` (adapters ride on them); everything between
+        them has the base's weights only (the taps, the norm's scale)
+        and on a forward pass runs ONCE over the rows of all nodes, a
+        block of 4 heads at a time (:func:`head_blocks`): the nodes are
+        the delta rule's batch."""
         B, T, _ = x.shape
         H, K = self.heads, self.head_dim
         taps_init = nn.initializers.normal(1.0 / math.sqrt(self.conv))
@@ -299,14 +340,14 @@ class KDAMixer(nn.Module):
         scale = self.param("o_norm", nn.initializers.ones, (K,),
                            self.param_dtype)
 
-        def heads(q, k, v, tq, tk, tv, a, beta, gate):
+        def heads(q, k, v, a, beta, gate, tq, tk, tv, scale):
             # everything between the projections and the output
             # projection, a block of heads at a time: what is float32 (the
             # unit q and k, the log-decay, the state, the output before its
             # norm and gate) is made and used up here
             with jax.named_scope("kda.conv"):
                 conv = lambda y, t: causal_conv_silu(
-                    y.reshape(B, T, -1), t.reshape(self.conv, -1)
+                    y.reshape(y.shape[:2] + (-1,)), t.reshape(self.conv, -1)
                 ).reshape(y.shape)
                 unit = lambda t: t.astype(F32) * jax.lax.rsqrt(jnp.sum(
                     jnp.square(t.astype(F32)), -1, keepdims=True) + 1e-6)
@@ -319,7 +360,10 @@ class KDAMixer(nn.Module):
             return (rms_norm(o, scale, self.eps)
                     * gate[..., None]).astype(self.dtype)
 
-        o = head_blocks(heads, 4, *qkv, *taps, a, beta, gate)
+        # the scale is every head's: given a head axis, to be cut like the taps
+        o = head_blocks(
+            heads, 4, (*qkv, a, beta, gate),
+            (*taps, jnp.broadcast_to(scale, (1, 1, H, K))))
         return _dense(x.shape[-1], "kda_o", self)(o.reshape(B, T, H * K))
 
 

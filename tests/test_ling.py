@@ -157,27 +157,37 @@ def chunk_systems(case, n=6, C=64, K=16, seed=0):
     return A * beta[..., None], rhs * beta[..., None]
 
 
+@pytest.mark.parametrize("sides", [1, 2], ids=["one side", "two sides"])
 @pytest.mark.parametrize("case", ["random", "equal keys", "fastest decay"])
-def test_unit_lower_solve_is_the_triangular_solve(case):
+def test_unit_lower_solve_is_the_triangular_solve(case, sides):
     """The product form against ``jax.lax.linalg.triangular_solve`` in
-    float32, forward and both gradients, on chunks like ``kda_inputs``',
+    float32, forward and every gradient, on chunks like ``kda_inputs``',
     on the near-worst case for cancellation (all keys of a chunk equal,
     ``beta`` at 1, no decay: every entry of ``A`` is near 1 and the
-    inverse is all but bidiagonal) and with the decay at its bound."""
+    inverse is all but bidiagonal) and with the decay at its bound; with
+    one right-hand side, and with the two that ``kda_heads`` hands it
+    apart (``b V`` and ``b K e^G``: one inverse, a product each, and
+    ``A``'s gradient the sum over both)."""
     A, rhs = chunk_systems(case)
-    weigh = jax.random.normal(jax.random.PRNGKey(7), rhs.shape)
-    plain = lambda A, rhs: jax.lax.linalg.triangular_solve(
-        A + jnp.eye(A.shape[-1]), rhs, left_side=True, lower=True,
-        unit_diagonal=True)
+    rhs = tuple(jnp.split(rhs, sides, axis=-1))
+    weigh = tuple(jax.random.normal(jax.random.PRNGKey(7 + i), r.shape)
+                  for i, r in enumerate(rhs))
+    plain = lambda A, rhs: tuple(jax.lax.linalg.triangular_solve(
+        A + jnp.eye(A.shape[-1]), r, left_side=True, lower=True,
+        unit_diagonal=True) for r in rhs)
+    ours = lambda A, rhs: ling.unit_lower_solve(A, rhs) if sides > 1 else (
+        ling.unit_lower_solve(A, rhs[0]),)
     close = lambda a, b: np.testing.assert_allclose(
         a, b, rtol=0, atol=1e-5 * float(jnp.max(jnp.abs(b))))
-    close(ling.unit_lower_solve(A, rhs), plain(A, rhs))
-    got, want = (jax.grad(lambda A, rhs: jnp.sum(f(A, rhs) * weigh),
-                          argnums=(0, 1))(A, rhs)
-                 for f in (ling.unit_lower_solve, plain))
+    for a, b in zip(ours(A, rhs), plain(A, rhs)):
+        close(a, b)
+    got, want = (jax.grad(lambda A, rhs: sum(
+        jnp.sum(x * w) for x, w in zip(f(A, rhs), weigh)),
+        argnums=(0, 1))(A, rhs) for f in (ours, plain))
     assert not np.triu(got[0]).any()  # the gradient to A: below the diagonal
     close(got[0], jnp.tril(want[0], -1))
-    close(got[1], want[1])
+    for a, b in zip(got[1], want[1]):
+        close(a, b)
 
 
 def test_chunk_length_has_to_be_a_power_of_two():
@@ -430,6 +440,60 @@ def test_the_nodes_rows_go_through_one_dispatch(ref):
     want = jnp.stack([jax.grad(lambda xb: jnp.sum(alone(xb) ** 2))(xb)
                       for xb in x])
     np.testing.assert_allclose(jax.grad(loss)(x), want, rtol=1e-4, atol=1e-5)
+
+
+def test_the_nodes_are_the_delta_rules_batch():
+    """Under the round's ``vmap`` over nodes a forward pass of everything
+    between ``KDAMixer``'s projections runs once, over the rows of all
+    nodes (the scan's state is ``[nodes x batch, block of heads, K, V]``,
+    as often in the program as one node's is in its own), and every node
+    gets what it would get alone: the output, and the gradients to its
+    input, to its own projections (where adapters ride) and to what all
+    nodes share (a tap, the norm's scale), which cannot be a node's own."""
+    nodes, B, T, H, K = 3, 2, 70, 8, 16
+    mixer = ling.KDAMixer(H, K, dtype=F32)
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    x = jax.random.normal(ks[0], (nodes, B, T, TOY["hidden"]))
+    weigh = jax.random.normal(ks[1], x.shape)
+    params = mixer.init(ks[2], x[0])["params"]
+    mine = ("kda_q", "kda_k", "kda_v", "kda_o")
+    shared = {k: v for k, v in params.items() if k not in mine}
+    own = jax.tree.map(  # a node's own projections, as under adapters
+        lambda a: a + 0.05 * jax.random.normal(ks[3], (nodes,) + a.shape),
+        {k: params[k] for k in mine})
+    alone = lambda own, xb, shared=shared: mixer.apply(
+        {"params": {**shared, **own}}, xb)
+    both = jax.value_and_grad(
+        lambda own, xb, wb: jnp.sum(alone(own, xb) * wb), argnums=(0, 1))
+    node = lambda tree, i: jax.tree.map(lambda a: a[i], tree)
+    want = [both(node(own, i), x[i], weigh[i]) for i in range(nodes)]
+    got = jax.jit(jax.vmap(both))(own, x, weigh)
+    np.testing.assert_allclose(
+        jax.vmap(alone)(own, x),
+        jnp.stack([alone(node(own, i), x[i]) for i in range(nodes)]),
+        rtol=1e-5, atol=1e-6)
+    for i in range(nodes):
+        for a, b in zip(jax.tree.leaves(node(got, i)),
+                        jax.tree.leaves(want[i])):
+            np.testing.assert_allclose(
+                a, b, rtol=0, atol=2e-5 * float(jnp.max(jnp.abs(b))))
+    text = str(jax.make_jaxpr(jax.vmap(alone))(own, x))
+    one = str(jax.make_jaxpr(alone)(node(own, 0), x[0]))
+    state = lambda batch: f"f32[{batch},4,{K},{K}]"  # the scan's, a block
+    assert text.count(state(nodes * B)) == one.count(state(B)) > 0
+    assert state(B) not in text
+    # the taps and the scale take each node's own gradient all the same
+    to_shared = jax.grad(lambda shared, xb, wb: jnp.sum(
+        alone(node(own, 0), xb, shared) * wb))
+    got = jax.vmap(to_shared, in_axes=(None, 0, 0))(shared, x, weigh)
+    want = to_shared(shared, x[1], weigh[1])
+    for name in ("q_conv", "o_norm"):
+        np.testing.assert_allclose(got[name][1], want[name],
+                                   rtol=1e-4, atol=1e-5)
+    with pytest.raises(ValueError, match="frozen input is mapped"):
+        jax.vmap(lambda taps, xb: alone(
+            node(own, 0), xb, {**shared, "q_conv": taps}))(
+            jnp.stack([shared["q_conv"]] * nodes), x)
 
 
 # --------------------------------------------------------------------------

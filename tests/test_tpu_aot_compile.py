@@ -124,23 +124,38 @@ def test_ling_expert_layer_is_one_grouped_product_over_all_nodes(
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
-def test_ling_delta_rule_holds_no_triangular_solve(one_chip,
-                                                   no_persistent_cache):
-    """The Ling cell's delta rule at its own shapes as the round runs it
-    (8 nodes under ``vmap``, a sequence of 4096, a block of 4 heads of
-    128, float32 in, bfloat16 products), value and gradient. XLA:TPU's
-    ``triangular_solve`` (a custom call that inverts the diagonal blocks,
-    3.5 ms an execution on the v5e at these shapes and a seventh of the
-    cell's round, PERF.md Findings PR 36) is not in the program; the
-    solve's three products on the matrix unit, one forward and two on
-    the way back, and the inverse's block products carry ``kda.solve``,
-    the scope its device time is read by; and the step needs no more
-    temporary memory than it did with the custom call."""
-    from p2pfl_tpu.models import ling
-
+def delta_rule_shapes(one_chip, weighed=False):
+    """The Ling cell's delta rule as the round runs it: 8 nodes under
+    ``vmap``, a sequence of 4096, a block of 4 heads of 128, float32 in:
+    q, k, v, g, beta, and the weights of a weighed sum of the output."""
     n, T, H, K = 8, 4096, 4, 128
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                               sharding=one_chip)
+    return [f32(n, 1, T, H, K)] * 4 + [f32(n, 1, T, H)] + [
+        f32(n, 1, T, H, K)] * weighed
+
+
+def test_ling_delta_rule_holds_no_triangular_solve(one_chip,
+                                                   no_persistent_cache):
+    """The Ling cell's delta rule at its own shapes as the round runs it
+    (bfloat16 products), value and gradient. XLA:TPU's
+    ``triangular_solve`` (a custom call that inverts the diagonal blocks,
+    3.5 ms an execution on the v5e at these shapes and a seventh of the
+    cell's round, PERF.md Findings PR 36) is not in the program; the
+    solve's products on the matrix unit carry ``kda.solve``, the scope its
+    device time is read by, and so do the inverse's block products. Since
+    PR 40 the two right-hand sides go apart: two products forward (on all
+    nodes' chunks at once, the chunk axis leading: ``[64, 8, 4, 64,
+    128]``), the same two again where the way back recomputes the block,
+    and four back (``T^T g`` for each side, ``d X^T`` for each into
+    ``A``'s gradient), none 256 wide (three of ``[.., 64, 256]`` and
+    ``[.., 64, 64]`` until then). The way back keeps the nodes' ``vmap``
+    (``head_blocks``), so the program reads 18.4e9 bytes: 3.7e9 the
+    forward pass with the nodes as its batch and 14.7e9 the block again
+    and back (14.578e9 at the parent, which kept a single block's
+    residuals and ran it once); and it needs less temporary memory than
+    it did with the custom call."""
+    from p2pfl_tpu.models import ling
 
     def loss(q, k, v, g, beta, weigh):
         return jnp.sum(weigh * ling.kda_chunked(q, k, v, g, beta,
@@ -148,8 +163,7 @@ def test_ling_delta_rule_holds_no_triangular_solve(one_chip,
 
     compiled = jax.jit(jax.vmap(jax.value_and_grad(
         loss, argnums=(0, 1, 2, 3, 4)))).lower(
-        *[f32(n, 1, T, H, K)] * 4, f32(n, 1, T, H), f32(n, 1, T, H, K)
-    ).compile()
+        *delta_rule_shapes(one_chip, weighed=True)).compile()
     hlo = compiled.as_text()
     assert "convolution" in hlo  # the text is the optimized module
     assert not [target for target in re.findall(
@@ -158,21 +172,50 @@ def test_ling_delta_rule_holds_no_triangular_solve(one_chip,
         r"= f32\[([\d,]+)\]\S* (\w+)\(.*op_name=\"([^\"]*kda\.solve[^\"]*)\"",
         hlo)
     back = lambda name: "transpose(" in name
-    products = sorted((dims, back(name)) for dims, op, name in scoped
-                      if op == "convolution")
-    chunks = f"{n},{T // 64},{H},64"
-    assert products == [(f"{chunks},256", False), (f"{chunks},256", True),
-                        (f"{chunks},64", True)], products
+    products = [(dims.split(","), back(name)) for dims, op, name in scoped
+                if op == "convolution"]
+    assert (["64", "8", "4", "64", "128"], False) in products, products
+    assert all(dims[-2] == "64" and dims[-1] in ("64", "128")
+               for dims, _ in products), products
     # the inverse's block products: multiply and sum, on the way forward
     assert any(op == "reduce" and not back(name) for _, op, name in scoped)
     # they are the program's products of float32 operands in six passes
-    # (the others take bfloat16 operands)
+    # (the others take bfloat16 operands): 2 forward, 2 again, 4 back
     highest = re.findall(r"operand_precision=\{highest,highest\}.*"
                          r"op_name=\"([^\"]*)\"", hlo)
-    assert len(highest) == 3 and all(
+    assert len(highest) == 8 and all(
         "kda.solve" in name for name in highest), highest
-    # 1,662,749,184 with the custom call (the same program at 24068e8)
-    assert compiled.memory_analysis().temp_size_in_bytes <= 1_662_749_184
+    assert compiled.cost_analysis()["bytes accessed"] <= 18.8e9
+    # 1,662,749,184 with the custom call (the same program at 24068e8),
+    # 1,609 MB at the parent of PR 40, 1,310 MB since
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1_400_000_000
+
+
+def test_ling_delta_rule_forward_stages_its_operands_once(
+        one_chip, no_persistent_cache):
+    """A forward pass of the delta rule as the model calls it from the
+    round's ``vmap`` over 8 nodes (two of a step's three passes and all of
+    an evaluation's): the nodes are its batch and the chunk axis leads
+    from ``chunks()`` to the scan, so every staged operand is ``[64, 8, 4,
+    64, 128]``; none is laid out batch-first (``[8, 64, 4, 64, 128]``: the
+    parent's form, eight float32 copies of ``[8, 1, 64, 64, 4, 128]``
+    between its stages) or with the nodes behind the chunks (``[64, 8, 1,
+    4, 64, 128]``: what a ``vmap`` of the scan makes); and the program
+    reads at most 3.8e9 bytes (``cost_analysis()``; 3.70e9 now, 4.395e9
+    at the parent, 5.47 ms at the HBM's rate for 5.90 ms measured an
+    execution there: ``PERF.md`` section 7, the yardstick)."""
+    from p2pfl_tpu.models import ling
+
+    compiled = jax.jit(jax.vmap(lambda *rows: ling.kda_chunked(
+        *rows, dtype=jnp.bfloat16))).lower(
+        *delta_rule_shapes(one_chip)).compile()
+    hlo = compiled.as_text()
+    assert "convolution" in hlo  # the text is the optimized module
+    assert "f32[64,8,4,64,128]" in hlo
+    for staged in ("f32[8,64,4,64,128]", "f32[8,1,64,4,64,128]",
+                   "f32[64,8,1,4,64,128]", "f32[8,1,64,64,4,128]"):
+        assert staged not in hlo, staged
+    assert compiled.cost_analysis()["bytes accessed"] <= 3.8e9
 
 
 def test_ling_attention_forms_no_tile_above_the_diagonal(one_chip,

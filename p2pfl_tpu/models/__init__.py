@@ -3,9 +3,11 @@
 Reference inventory (SURVEY.md §2.4, fedstellar/learning/pytorch/*):
 MNIST MLP/CNN, FEMNIST CNN, CIFAR10 ResNet9/18/34/50 + two MobileNets,
 SYSCALL MLP/Autoencoder/One-class-SVM, WADI MLP — plus ViT-Tiny for the
-stretch config in BASELINE.json and two sparse-expert language models as
-frozen bases under adapters: Ling-3.0-flash (linear and latent attention)
-and Laguna-S-2.1 (window and full grouped-query attention).
+stretch config in BASELINE.json and three sparse-expert language models
+as frozen bases under adapters: Ling-3.0-flash (linear and latent
+attention), Laguna-S-2.1 (window and full grouped-query attention) and
+LFM2-8B-A1B (gated short convolutions among grouped-query attention, a
+tied head).
 
 TPU-first design notes:
 - Normalization is **GroupNorm**, not BatchNorm: batch statistics are
@@ -26,6 +28,7 @@ from p2pfl_tpu.models.syscall import SyscallModelAutoencoder, SyscallModelOneCla
 from p2pfl_tpu.models.vit import ViT
 from p2pfl_tpu.models.ling import LingLM
 from p2pfl_tpu.models.laguna import LagunaLM
+from p2pfl_tpu.models.lfm2 import Lfm2LM
 
 __all__ = [
     "get_model",
@@ -46,4 +49,5 @@ __all__ = [
     "ViT",
     "LingLM",
     "LagunaLM",
+    "Lfm2LM",
 ]

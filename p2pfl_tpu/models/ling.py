@@ -298,14 +298,22 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
     return O[:, :T]
 
 
-def causal_conv_silu(x, taps):
-    """Depthwise causal convolution over positions, then SiLU. ``x``
-    [B, T, D], ``taps`` [k, D]: ``y_t = sum_i taps[i] x_(t - k + 1 + i)``."""
+def causal_conv(x, taps):
+    """Depthwise causal convolution over positions, the convolution
+    alone: ``x`` [B, T, D], ``taps`` [k, D], ``y_t = sum_i taps[i] x_(t -
+    k + 1 + i)`` with zeros before position 0, no bias, no activation
+    (any ``T``, shorter than the taps too). The one causal convolution in
+    the tree: KDA's (:func:`causal_conv_silu`) and the short-convolution
+    mixer of ``models/lfm2.py`` both call it."""
     k = taps.shape[0]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     T = x.shape[1]
-    y = sum(xp[:, i:i + T] * taps[i] for i in range(k))
-    return jax.nn.silu(y)
+    return sum(xp[:, i:i + T] * taps[i] for i in range(k))
+
+
+def causal_conv_silu(x, taps):
+    """:func:`causal_conv`, then SiLU."""
+    return jax.nn.silu(causal_conv(x, taps))
 
 
 class KDAMixer(nn.Module):
@@ -846,10 +854,15 @@ def _frozen_experts(**static):
 
 class ExpertFFN(nn.Module):
     """The held experts' part of a routed layer plus its one shared
-    expert. ``router`` is the model's own ``(x, frozen) -> (ids,
-    weights)`` over ``frozen["router"]`` [d, n_experts]; without one it
-    is :func:`route` over ``n_group`` groups, which also holds a seeded
-    selection bias."""
+    expert of width ``shared_width``; ``shared_width`` 0 is a layer with
+    NO shared expert: no shared projection is made and nothing is added.
+    ``router`` is the model's own ``(x, frozen) -> (ids, weights)`` over
+    ``frozen["router"]`` [d, n_experts]; without one it is :func:`route`
+    over ``n_group`` groups, which also holds a seeded selection bias
+    (``n_group`` 1 with ``topk_group`` 1 is the plain biased sigmoid
+    top-k: the one group is always kept). With ``experts_held ==
+    n_experts`` (and ``expert_offset`` 0) the layer holds every expert
+    it routes over and its output is the whole layer's."""
 
     n_experts: int
     experts_held: int
@@ -892,6 +905,8 @@ class ExpertFFN(nn.Module):
         layer = _frozen_experts(router=router, offset=self.expert_offset,
                                 dtype=self.dtype)
         y, stats = layer(x.reshape(B * T, d), frozen)
+        if not self.shared_width:
+            return y.reshape(B, T, d), stats
         with jax.named_scope("moe.shared"):
             shared = _dense(d, "shared_down", self)(swiglu(
                 _dense(2 * self.shared_width, "shared_gate_up", self)(x)))
@@ -945,20 +960,33 @@ class LingBlock(nn.Module):
 class CausalLM(nn.Module):
     """Token ids [B, T] -> logits [B, T, vocab] (``__call__``: small
     sizes only), or with labels the mean next-token loss, head and loss a
-    chunk of positions at a time (``loss``). A model gives ``vocab``,
+    chunk of ``loss_chunk`` positions at a time (``loss``: a chunk's
+    float32 logits are ``[B, loss_chunk, vocab]``, so a model with a
+    wide vocabulary sets a small chunk). A model gives ``vocab``,
     ``hidden``, ``eps``, ``loss_chunk``, ``dtype`` and ``param_dtype``
     as fields and in its ``setup`` its ``blocks`` (each ``x -> (x,
-    expert-layer counters or None)``) between :meth:`setup_ends`."""
+    expert-layer counters or None)``) between :meth:`setup_ends`. With
+    ``tie_head`` the logits are ``h E^T`` over the embedding's own
+    matrix ``E`` and no ``head`` parameter is made: a frozen base then
+    holds one matrix, used twice, seeded at a head's scale (``1 /
+    sqrt(hidden)`` a row element: with an embedding's 1.0 the seed's
+    logits would have a deviation of ``sqrt(hidden)`` and its loss
+    would be a saturated softmax's)."""
+
+    tie_head: bool = False
 
     def setup_ends(self):
-        self.embed = nn.Embed(self.vocab, self.hidden, dtype=self.dtype,
-                              param_dtype=self.param_dtype,
-                              embedding_init=nn.initializers.normal(1.0))
+        self.embed = nn.Embed(
+            self.vocab, self.hidden, dtype=self.dtype,
+            param_dtype=self.param_dtype,
+            embedding_init=nn.initializers.normal(
+                self.hidden ** -0.5 if self.tie_head else 1.0))
         self.final_norm = RMSNorm(self.eps, dtype=self.dtype,
                                   param_dtype=self.param_dtype)
-        self.head = self.param(
-            "head", nn.initializers.lecun_normal(), (self.hidden, self.vocab),
-            self.param_dtype)
+        if not self.tie_head:
+            self.head = self.param(
+                "head", nn.initializers.lecun_normal(),
+                (self.hidden, self.vocab), self.param_dtype)
 
     def hidden_states(self, tokens):
         x = self.embed(tokens.astype(jnp.int32))
@@ -970,6 +998,10 @@ class CausalLM(nn.Module):
         return self.final_norm(x), stats
 
     def _logits(self, h):
+        if self.tie_head:
+            return jnp.einsum("...d,vd->...v", h,
+                              self.embed.embedding.astype(self.dtype),
+                              preferred_element_type=F32)
         return jnp.dot(h, self.head.astype(self.dtype),
                        preferred_element_type=F32)
 

@@ -21,6 +21,7 @@ import importlib.util
 import inspect
 import json
 import pathlib
+import re
 import sys
 
 import pytest
@@ -245,3 +246,74 @@ def test_window_score_tiles_share_reads_its_own_scopes_record(harness,
     assert reader.read({}) is None
     monkeypatch.delattr(ling, "score_tiles")
     assert reader.read({}) is None
+
+
+def scopes_read(path):
+    """The scope names a reader hands ``scopework`` (every string it
+    passes, ``also=`` apart: those name the compiler's ops)."""
+    names = []
+    for node in ast.walk(ast.parse(pathlib.Path(path).read_text())):
+        if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                and isinstance(node.func.value, ast.Name)
+                and node.func.value.id == "scopework"):
+            names += [a.value for a in node.args
+                      if isinstance(a, ast.Constant) and isinstance(a.value, str)]
+    return names
+
+
+def test_lfm2_readers_scopes_are_in_the_programs_lowering():
+    """The ``lfm2.*`` readers find device time by the program's own
+    ``jax.named_scope``s (``lfm2.conv``, ``gqa.attn``, ``moe.*``,
+    ``lora.side``, ``lm.head_loss``): each name a reader passes is in the
+    name stack of the model's lowered step, and the new one,
+    ``lfm2.conv``, on the way back too (attention's and the expert
+    layer's ways back are written by hand and set their scopes bare); a
+    renamed scope would leave its reader silent on the chip and pass
+    every other test here. Nothing is shared in this model's expert
+    layer, so no reader of its cell names ``moe.shared``."""
+    import jax
+    import jax.numpy as jnp
+
+    from p2pfl_tpu.learning.lora import wrap_model
+    from p2pfl_tpu.models import get_model
+
+    model = get_model("lfm2-8b-a1b", layer_types=["conv", "full_attention"],
+                      dense_layers=1)
+    x = jnp.zeros((1, 16), jnp.int32)
+    lm = wrap_model(model, "lfm2-8b-a1b", 2, sample_x=x)
+    step = jax.grad(lambda a: lm.apply(a, x, x, None, method="loss")[0])
+    text = jax.jit(step).lower(
+        lm.init(jax.random.PRNGKey(0), x)).as_text(debug_info=True)
+    readers = sorted((HOME / "readers").glob("lfm2.*.py"))
+    scopes = {s for path in readers for s in scopes_read(path)}
+    assert {"lfm2.conv", "gqa.attn", "moe.experts", "lora.side",
+            "lm.head_loss"} <= scopes and "moe.shared" not in scopes
+    for scope in sorted(scopes):
+        assert f"/{scope}/" in text, f"no op of the step bears {scope}"
+    assert re.search(r'"[^"]*transpose\([^"]*/lfm2\.conv/[^"]*"', text)
+    assert "moe.shared" not in text
+
+
+def test_lfm2_cell_names_what_the_program_has(harness):
+    """The cell's data files against the program: the model and the data
+    set by name, the adapters' default targets, and a frozen tree with no
+    ``head`` (``frozen.from`` is ``model.base``; the tied embedding is
+    its one vocabulary-sized leaf)."""
+    from p2pfl_tpu.datasets.sources import token_spec
+    from p2pfl_tpu.models import list_models
+    from p2pfl_tpu.models.base import default_lora_targets
+
+    cell = harness.Cell("lfm2-8b-a1b.dfl16-full-lora-s2048", False)
+    model = cell.scenario["model"]
+    assert model["model"] in list_models() and model["kwargs"]["tie_head"]
+    assert token_spec(cell.scenario["data"]["dataset"]) == (65536, 2048)
+    assert set(default_lora_targets(model["model"])) == {
+        "conv_in", "conv_out", "attn_q", "attn_k", "attn_v", "attn_o"}
+    assert cell.config["frozen"]["from"] == "model.base"
+    names = set(cell.config["frozen"]["param_map"].values())
+    assert "embed" in names and "head" not in names
+    # every trained leaf sits on a frozen kernel of the map
+    kernels = {p[:-len("/kernel")] for p in cell.config["frozen"]["param_map"]
+               if p.endswith("/kernel")}
+    assert {p.rsplit("/kernel/", 1)[0] for p in cell.config["param_map"]} \
+        <= kernels

@@ -315,3 +315,71 @@ def test_laguna_attention_forms_only_the_tiles_a_block_sees(
         name for name in named if scope not in name]
     assert any("transpose(" in name for name in named)  # the way back
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_lfm2_cells_round_program_fits_the_chip(one_chip, no_persistent_cache,
+                                                monkeypatch):
+    """The WHOLE round program of ``lfm2-8b-a1b.dfl16-full-lora-s2048`` at
+    the cell's own sizes (published layers 1 to 9 at the published
+    widths, 16 nodes, 2 steps of one sequence of 2048, the frozen 6.3 GB
+    base an argument), built by ``Scenario`` from the cell's own files
+    with the base left abstract, compiles for the described v5e: XLA:TPU
+    refuses a program that does not fit the chip's 15.75 GiB. It stood at
+    14.16 GiB when the cell came (8.92 GB of temporaries over 6.62 GB of
+    arguments). The expert layers' pairs are two blocks of 65,536 rows
+    (every one of the 32 experts held, 4 a token), the head's logits are
+    never wider than a chunk of 128 positions, and nothing is shared:
+    no op bears ``moe.shared``."""
+    import importlib.util
+    import pathlib
+
+    import numpy as np
+
+    from p2pfl_tpu.federation import Scenario
+    from p2pfl_tpu.learning import lora
+    from p2pfl_tpu.parallel import transport
+
+    home = pathlib.Path(__file__).resolve().parent.parent / "benchmark"
+    monkeypatch.syspath_prepend(str(home))
+    spec = importlib.util.spec_from_file_location("benchmark_run_aot",
+                                                  home / "run.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+
+    # the base stays shapes: nothing of 6.3 GB is made or placed here
+    abstract = lambda a: isinstance(a, jax.ShapeDtypeStruct)
+    monkeypatch.setattr(lora, "base_params_for", lambda model, seed, x:
+                        jax.eval_shape(model.init, jax.random.PRNGKey(seed), x))
+    asarray, place, ready = (jnp.asarray, transport.MeshTransport._place,
+                             jax.block_until_ready)
+    monkeypatch.setattr(jnp, "asarray", lambda a, *args, **kw:
+                        a if abstract(a) else asarray(a, *args, **kw))
+    monkeypatch.setattr(transport.MeshTransport, "_place", lambda self, x, s:
+                        x if abstract(x) else place(self, x, s))
+    monkeypatch.setattr(jax, "block_until_ready", lambda tree: tree if any(
+        map(abstract, jax.tree.leaves(tree))) else ready(tree))
+
+    cell = bench.Cell("lfm2-8b-a1b.dfl16-full-lora-s2048", False)
+    sc = Scenario(cell.scenario_config(7))
+    try:
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one_chip),
+            (sc.fed, *sc._data_args, *sc._plan_args(None), *sc._frozen))
+        base = sum(np.prod(l.shape) * l.dtype.itemsize
+                   for l in jax.tree.leaves(sc._frozen))
+        assert 6.2e9 < base < 6.4e9  # 3,136M parameters in bfloat16
+        compiled = sc._round_fn.lower(*args).compile()
+    finally:
+        sc.close()
+    m = compiled.memory_analysis()
+    held = (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert held < 15.75 * 2 ** 30, held
+    assert m.argument_size_in_bytes > 0.25 * 16e9  # the driver's floor
+    hlo = compiled.as_text()
+    grouped = re.findall(r"ragged-dot\S* = \w+\[(\d+),(\d+)\]\S* custom-call",
+                         hlo)
+    assert grouped and {rows for rows, _ in grouped} == {"65536"}, grouped
+    assert "f32[16,128,65536]" in hlo and "f32[16,2048,65536]" not in hlo
+    assert "lfm2.conv" in hlo and "moe.shared" not in hlo

@@ -25,7 +25,11 @@ One chip holds its share of a deployment: ``experts_held`` of the
 ``expert_offset ..``), and a slice of the vocabulary. The layer routes
 over all experts and computes its own experts' part of the result; what
 the absent experts would add is left out. No chosen (token, held
-expert) pair is dropped, whatever the imbalance.
+expert) pair is dropped, whatever the imbalance. The frozen layer keeps
+nothing for the way back: each block of sorted pairs forms its
+up-projection again and goes back by hand in three grouped products
+(``_block_back``), the down-projection's output never formed a second
+time: a training step executes 8/3 of a forward's expert FLOPs.
 
 ``models/laguna.py`` builds its model from the parts here that are not
 Ling's alone: ``causal_attention`` (its window, its grouped query heads
@@ -725,11 +729,11 @@ def _dispatch(idx, n_experts, held, offset):
     return order, counts, jnp.cumsum(counts), rows, n_blocks
 
 
-def _block(x, pair_w, first, order, counts, ends, w_gu, w_d, *, rows, top_k,
-           dtype):
-    """Rows ``first .. first + rows`` of the sorted pairs: gather their
-    tokens, one grouped product a projection, weigh, scatter back.
-    ``pair_w`` [rows]: the pairs' weights. Returns [N, d] float32."""
+def _block_rows(x, first, order, counts, ends, *, rows, top_k, dtype):
+    """Rows ``first .. first + rows`` of the sorted pairs: which are
+    ``live``, their tokens ``tok``, the tokens' rows of ``x`` gathered
+    (``xs``, in ``dtype``) and how many rows each held expert has in the
+    block (``sizes``)."""
     with jax.named_scope("moe.dispatch"):
         # rows past the last chosen pair compute nothing and give and
         # take nothing (a grouped product leaves them unspecified)
@@ -738,6 +742,16 @@ def _block(x, pair_w, first, order, counts, ends, w_gu, w_d, *, rows, top_k,
         xs = jnp.where(live, x[tok], 0).astype(dtype)
         sizes = (jnp.clip(ends - first, 0, rows)
                  - jnp.clip(ends - counts - first, 0, rows)).astype(jnp.int32)
+    return live, tok, xs, sizes
+
+
+def _block(x, pair_w, first, order, counts, ends, w_gu, w_d, *, rows, top_k,
+           dtype):
+    """Rows ``first .. first + rows`` of the sorted pairs: gather their
+    tokens, one grouped product a projection, weigh, scatter back.
+    ``pair_w`` [rows]: the pairs' weights. Returns [N, d] float32."""
+    live, tok, xs, sizes = _block_rows(x, first, order, counts, ends,
+                                       rows=rows, top_k=top_k, dtype=dtype)
     with jax.named_scope("moe.experts"):
         h = swiglu(jax.lax.ragged_dot(
             xs, w_gu.astype(dtype), sizes,
@@ -747,6 +761,39 @@ def _block(x, pair_w, first, order, counts, ends, w_gu, w_d, *, rows, top_k,
     with jax.named_scope("moe.combine"):
         ys = jnp.where(live, ys * pair_w[:, None], 0.0)
         return jnp.zeros(x.shape, F32).at[tok].add(ys)
+
+
+def _block_back(x, pair_w, g, first, order, counts, ends, w_gu, w_d, *, rows,
+                top_k, dtype):
+    """The way back of :func:`_block` from ``g`` [N, d] float32, the
+    gradient of its output, to ``x`` and to ``pair_w``, in three grouped
+    products. The up-projection is formed again; the down-projection is
+    not: with ``G = g[tok]`` and ``ys = h W_d``, the pair weights'
+    gradient ``rowsum(G * ys)`` is ``rowsum((G W_d^T) * h)``, and ``G
+    W_d^T`` times the pair's weight is the gradient of ``h``. The third
+    product takes the SwiGLU's gradient back through ``W_gu^T``. The
+    transposed products are of a float32 gradient with the ``dtype``
+    weights, and the rows' gradient is summed over a token's pairs in
+    ``x``'s type, as reverse mode through ``_block`` has them. Returns
+    ``dx`` [N, d] and ``dpw`` [rows] float32."""
+    live, tok, xs, sizes = _block_rows(x, first, order, counts, ends,
+                                       rows=rows, top_k=top_k, dtype=dtype)
+    w_gu, w_d = w_gu.astype(dtype), w_d.astype(dtype)
+    grouped = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                                preferred_element_type=F32)
+    # a hand-written way back does not inherit the name stack of the way
+    # forward: each part is under the scope of the part it undoes
+    with jax.named_scope("moe.combine"):
+        G = jnp.where(live, g[tok], 0.0)
+    with jax.named_scope("moe.experts"):
+        act, act_back = jax.vjp(swiglu, grouped(xs, w_gu))
+        dh = grouped(G, jnp.swapaxes(w_d, 1, 2))
+        dpw = jnp.sum(jnp.where(live, dh * act.astype(dtype), 0.0), axis=-1)
+        dgu, = act_back(dh * pair_w[:, None])
+        dxs = grouped(dgu, jnp.swapaxes(w_gu, 1, 2))
+    with jax.named_scope("moe.dispatch"):
+        dxs = jnp.where(live, dxs.astype(x.dtype), 0)
+        return jnp.zeros(x.shape, x.dtype).at[tok].add(dxs), dpw
 
 
 def held_experts(x, frozen, *, router, offset, dtype):
@@ -793,10 +840,10 @@ def held_experts(x, frozen, *, router, offset, dtype):
 def held_experts_back(x, g, frozen, *, router, offset, dtype):
     """The gradient of ``held_experts``'s ``y`` to its rows, by
     recomputation: the forward pass kept nothing, the base has no
-    gradient. Each block is differentiated where it is recomputed (to
-    its rows and its pairs' weights), so that nothing is kept from block
-    to block either; the weights' gradients then go back through the
-    router's scores."""
+    gradient. Each block goes back where its up-projection is formed
+    again (``_block_back``: three grouped products, to its rows and its
+    pairs' weights), so that nothing is kept from block to block either;
+    the weights' gradients then go back through the router's scores."""
     w_gu, w_d = frozen["gate_up"], frozen["down"]
     held, n_experts = w_gu.shape[0], frozen["router"].shape[1]
     g = g.astype(F32)
@@ -807,18 +854,16 @@ def held_experts_back(x, g, frozen, *, router, offset, dtype):
         order, counts, ends, rows, n_blocks = _dispatch(
             idx, n_experts, held, offset)
         pair_w = jnp.pad(w.reshape(-1), (0, order.shape[0] - w.size))[order]
-    block = functools.partial(_block, rows=rows, top_k=idx.shape[1],
-                              dtype=dtype)
+    back = functools.partial(_block_back, rows=rows, top_k=idx.shape[1],
+                             dtype=dtype)
 
     def one(carry, first):
         dx, dpw = carry
 
         def run():
-            _, back = jax.vjp(
-                lambda x_, pw_: block(x_, pw_, first, order, counts, ends,
-                                      w_gu, w_d),
-                x, jax.lax.dynamic_slice(pair_w, (first,), (rows,)))
-            dx_b, dpw_b = back(g)
+            dx_b, dpw_b = back(
+                x, jax.lax.dynamic_slice(pair_w, (first,), (rows,)), g,
+                first, order, counts, ends, w_gu, w_d)
             return (dx + dx_b.astype(F32),
                     jax.lax.dynamic_update_slice(dpw, dpw_b, (first,)))
 

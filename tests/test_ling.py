@@ -442,6 +442,80 @@ def test_the_nodes_rows_go_through_one_dispatch(ref):
     np.testing.assert_allclose(jax.grad(loss)(x), want, rtol=1e-4, atol=1e-5)
 
 
+def sorted_pairs(case, monkeypatch):
+    """Tokens, the gradient of a block's output, and the chosen pairs
+    sorted into three blocks of 128 rows (``_block``'s arguments behind
+    its rows and weights). ``every``: 2 of 8 experts a token, all held:
+    three full blocks. ``subset``: 4 of 16, experts 4 to 7 held, absent
+    experts' pairs sorted last; 40 tokens choose the held four and 10
+    more one of them, so the first block is full, the second has 42 of
+    its rows in use and the third is past the last pair."""
+    monkeypatch.setattr(ling, "BLOCK_ROWS", 128)
+    d, W = 16, 24
+    key = jax.random.split(jax.random.PRNGKey(7), 6)
+    if case == "every":
+        N, E, held, offset, top_k = 192, 8, 8, 0, 2
+        idx = jnp.argsort(jax.random.uniform(key[0], (N, E)))[:, :top_k]
+    else:
+        N, E, held, offset, top_k = 96, 16, 4, 4, 4
+        absent = jnp.array([0, 1, 2, 3, 8, 9, 10, 11, 12, 13, 14, 15])
+        idx = absent[jnp.argsort(jax.random.uniform(key[0], (N, 12)))[:, :4]]
+        idx = idx.at[:40].set(jnp.arange(4, 8)).at[40:50, 2].set(
+            4 + jnp.arange(10) % 4)
+    x = jax.random.normal(key[1], (N, d))
+    g = jax.random.normal(key[2], (N, d))
+    w = jax.random.uniform(key[3], (N, top_k), minval=0.1)
+    w_gu = jax.random.normal(key[4], (held, d, 2 * W)) / d ** 0.5
+    w_d = jax.random.normal(key[5], (held, W, d)) / W ** 0.5
+    order, counts, ends, rows, n_blocks = ling._dispatch(idx, E, held, offset)
+    assert (rows, n_blocks) == (128, 3)
+    pair_w = jnp.pad(w.reshape(-1), (0, order.shape[0] - w.size))[order]
+    return (x, pair_w, g, (order, counts, ends, w_gu, w_d),
+            dict(rows=rows, top_k=top_k, dtype=F32))
+
+
+@pytest.mark.parametrize("case, block, used", [
+    ("every", 0, 128), ("every", 1, 128), ("every", 2, 128),
+    ("subset", 1, 42), ("subset", 2, 0),
+], ids=["every expert held, the first full block", "the second full block",
+        "the third full block", "a held subset, a part-filled block",
+        "a block past the last pair"])
+def test_a_blocks_way_back_is_reverse_mode_through_the_block(
+        case, block, used, monkeypatch):
+    """``_block_back``, three grouped products, against ``jax.vjp`` of
+    ``_block``, four: the gradient to the rows and to the pairs' weights
+    agree to float32 rounding, and rows past the last pair give and take
+    nothing."""
+    x, pair_w, g, rest, static = sorted_pairs(case, monkeypatch)
+    first = block * 128
+    assert min(max(int(rest[2][-1]) - first, 0), 128) == used
+    pw = pair_w[first:first + 128]
+    _, back = jax.vjp(lambda x_, pw_: ling._block(
+        x_, pw_, first, *rest, **static), x, pw)
+    want_dx, want_dpw = back(g)
+    dx, dpw = ling._block_back(x, pw, g, first, *rest, **static)
+    np.testing.assert_allclose(dx, want_dx, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(dpw, want_dpw, rtol=1e-5, atol=1e-5)
+    assert not np.asarray(dpw[used:]).any()
+    assert np.asarray(dpw[:used]).all() and np.asarray(dx).any() == bool(used)
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "no remat"])
+def test_a_sparse_layers_gradient_holds_five_grouped_products(remat):
+    """A training step of one sparse layer (one block of rows): two
+    grouped products forward and three on the way back, which forms the
+    up-projection again and the down-projection not at all. ``remat``
+    adds none: its copy of the expert forward has no reader on the way
+    back."""
+    sizes = {**TOY, "layers": 2, "remat": remat}  # a dense FFN, then experts
+    model = get_model("ling-3.0-flash", dtype=F32, **sizes)
+    rows = tokens(0, (2, 24))
+    params = model.init(jax.random.PRNGKey(1), rows)
+    loss = lambda p: model.apply(p, rows, rows, method="loss")[0]
+    text = str(jax.make_jaxpr(jax.grad(loss))(params))
+    assert text.count("= ragged_dot_general[") == 5
+
+
 def test_the_nodes_are_the_delta_rules_batch():
     """Under the round's ``vmap`` over nodes a forward pass of everything
     between ``KDAMixer``'s projections runs once, over the rows of all

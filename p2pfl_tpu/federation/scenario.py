@@ -70,6 +70,20 @@ from p2pfl_tpu.utils.metrics import MetricsLogger
 from p2pfl_tpu.utils.monitor import publish_status
 from p2pfl_tpu.utils.telemetry import resource_snapshot
 
+#: the spans of ``run()`` outside its rounds, and the parts of
+#: ``scenario.log``: names the benchmark's readers import
+#: (``benchmark/hostspans.py``). ``SPAN_RUN`` is the root of everything a
+#: ``run()`` does: the rounds and the closing ``scenario.evaluate`` are
+#: its children, ``SPAN_RUN_ENTER`` what comes before the first round,
+#: ``SPAN_RUN_EXIT`` (two of them) what lies between the last round and
+#: the closing evaluation and what follows that evaluation
+SPAN_RUN = "scenario.run"
+SPAN_RUN_ENTER = "scenario.run.enter"
+SPAN_RUN_EXIT = "scenario.run.exit"
+SPAN_LOG_METRICS = "scenario.log.metrics"
+SPAN_LOG_RESOURCES = "scenario.log.resources"
+SPAN_LOG_WRITE = "scenario.log.write"
+
 
 @dataclasses.dataclass
 class ScenarioResult:
@@ -655,8 +669,8 @@ class Scenario(Observable):
     @obs_trace.program_scope()
     def run(self, rounds: int | None = None,
             target_accuracy: float | None = None) -> ScenarioResult:
-        cfg = self.config
-        rounds = rounds if rounds is not None else cfg.training.rounds
+        rounds = (rounds if rounds is not None
+                  else self.config.training.rounds)
         # obs: span tracer (P2PFL_TRACE, or a live profiler session);
         # program_scope installed the recompile counter, so a mid-run
         # recompile storm (perf.md §7b) shows up as
@@ -665,24 +679,36 @@ class Scenario(Observable):
             default_dir=(self.logger.dir / "trace")
             if self.logger.dir else None,
         )
-        if self.logger.dir is not None:
-            flight.configure(dump_dir=self.logger.dir / "flight")
-        round_times: list[float] = []
-        self.round_times_s = round_times  # _publish_statuses reads p95
-        rounds_to_target = None
-        ev = None
-        ev_round = -1  # round the last evaluation reflects
-        start_round = int(self._node_host(self.fed.round))
-        # profile ONE steady-state round (the second of the run when
-        # there is one — the first carries compile time), host work and
-        # all, so the trace holds its scenario.* spans beside the device
-        # ops; SURVEY §5.1's jax.profiler hook. try/finally: an
-        # exception mid-profiled-round must not leave the profiler
-        # running.
-        profile_round = None
-        if cfg.profile_dir and self._proc0:
-            profile_round = start_round + (1 if rounds > 1 else 0)
-        tracing = False
+        # the root span's arguments: ``start_round`` comes from the
+        # device, under SPAN_RUN_ENTER, so the ring's record has it and
+        # the profiler's annotation, made here, has not
+        run_args = {"rounds": rounds}
+        with tracer.watch(), tracer.span(SPAN_RUN, args=run_args):
+            return self._run(rounds, target_accuracy, tracer, run_args)
+
+    def _run(self, rounds: int, target_accuracy: float | None, tracer,
+             run_args: dict) -> ScenarioResult:
+        cfg = self.config
+        with tracer.span(SPAN_RUN_ENTER):
+            if self.logger.dir is not None:
+                flight.configure(dump_dir=self.logger.dir / "flight")
+            round_times: list[float] = []
+            self.round_times_s = round_times  # _publish_statuses reads p95
+            rounds_to_target = None
+            ev = None
+            ev_round = -1  # round the last evaluation reflects
+            start_round = int(self._node_host(self.fed.round))
+            run_args["start_round"] = start_round
+            # profile ONE steady-state round (the second of the run when
+            # there is one — the first carries compile time), host work
+            # and all, so the trace holds its scenario.* spans beside the
+            # device ops; SURVEY §5.1's jax.profiler hook. try/finally:
+            # an exception mid-profiled-round must not leave the profiler
+            # running.
+            profile_round = None
+            if cfg.profile_dir and self._proc0:
+                profile_round = start_round + (1 if rounds > 1 else 0)
+            tracing = False
         try:
             for r in range(start_round, start_round + rounds):
                 t0 = time.monotonic()
@@ -757,7 +783,8 @@ class Scenario(Observable):
                         # resumed run re-reads the same spend (r counts
                         # from the checkpoint's round, not zero)
                         self.accountant.steps = r + 1
-                    with tracer.span("scenario.log"):
+                    with tracer.span("scenario.log"), \
+                            tracer.span(SPAN_LOG_METRICS):
                         for i in range(cfg.n_nodes):
                             rec = {"Train/loss": float(train_loss[i]),
                                    "Train/round_time_s": dt}
@@ -790,10 +817,13 @@ class Scenario(Observable):
                                 and ev["mean_accuracy"] >= target_accuracy):
                             rounds_to_target = r + 1
                     with tracer.span("scenario.log"):
-                        self.logger.log_metrics(resource_snapshot(),
-                                                step=self.global_step,
-                                                round=r)
-                        self.logger.round_marker(r, self.global_step)
+                        with tracer.span(SPAN_LOG_RESOURCES):
+                            resources = resource_snapshot()
+                        with tracer.span(SPAN_LOG_WRITE):
+                            self.logger.log_metrics(resources,
+                                                    step=self.global_step,
+                                                    round=r)
+                            self.logger.round_marker(r, self.global_step)
                     if (cfg.checkpoint_every
                             and (r + 1) % cfg.checkpoint_every == 0):
                         if cfg.checkpoint_dir:
@@ -807,10 +837,11 @@ class Scenario(Observable):
                     jax.profiler.stop_trace()
                     tracing = False
         finally:
-            if tracing:  # exception mid-profiled-round
-                jax.profiler.stop_trace()
-            if tracer.enabled and self._proc0:
-                tracer.export(process_name=f"scenario[{cfg.name}]")
+            with tracer.span(SPAN_RUN_EXIT):
+                if tracing:  # exception mid-profiled-round
+                    jax.profiler.stop_trace()
+                if tracer.enabled and self._proc0:
+                    tracer.export(process_name=f"scenario[{cfg.name}]")
 
         last_round = start_round + rounds - 1
         if ev is None or ev_round != last_round:  # don't report stale eval
@@ -818,16 +849,17 @@ class Scenario(Observable):
             if (target_accuracy is not None and rounds_to_target is None
                     and ev["mean_accuracy"] >= target_accuracy):
                 rounds_to_target = last_round + 1
-        self.notify(Events.LEARNING_FINISHED, {})
-        return ScenarioResult(
-            final_accuracy=ev["mean_accuracy"],
-            per_node_accuracy=ev["per_node_accuracy"],
-            rounds_run=rounds,
-            round_times_s=round_times,
-            history=self.logger.history,
-            rounds_to_target=rounds_to_target,
-            min_accuracy=ev["min_accuracy"],
-        )
+        with tracer.span(SPAN_RUN_EXIT):
+            self.notify(Events.LEARNING_FINISHED, {})
+            return ScenarioResult(
+                final_accuracy=ev["mean_accuracy"],
+                per_node_accuracy=ev["per_node_accuracy"],
+                rounds_run=rounds,
+                round_times_s=round_times,
+                history=self.logger.history,
+                rounds_to_target=rounds_to_target,
+                min_accuracy=ev["min_accuracy"],
+            )
 
     def close(self) -> None:
         self.logger.close()
@@ -1095,16 +1127,26 @@ class CrossDeviceScenario(Observable):
     @obs_trace.program_scope()
     def run(self, rounds: int | None = None,
             target_accuracy: float | None = None) -> ScenarioResult:
+        rounds = (rounds if rounds is not None
+                  else self.config.training.rounds)
+        tracer = obs_trace.get_tracer()
+        # Scenario.run's root span, stall watch and SPAN_RUN_* spans
+        run_args = {"rounds": rounds}
+        with tracer.watch(), tracer.span(SPAN_RUN, args=run_args):
+            return self._run(rounds, target_accuracy, tracer, run_args)
+
+    def _run(self, rounds: int, target_accuracy: float | None, tracer,
+             run_args: dict) -> ScenarioResult:
         cfg = self.config
         cd = self.cd
-        rounds = rounds if rounds is not None else cfg.training.rounds
-        tracer = obs_trace.get_tracer()
-        round_times: list[float] = []
-        rounds_to_target = None
-        ev = None
-        ev_round = -1
-        start_round = int(np.asarray(self.fed.round))
-        tr = self.transport
+        with tracer.span(SPAN_RUN_ENTER):
+            round_times: list[float] = []
+            rounds_to_target = None
+            ev = None
+            ev_round = -1
+            start_round = int(np.asarray(self.fed.round))
+            run_args["start_round"] = start_round
+            tr = self.transport
         for r in range(start_round, start_round + rounds):
             t0 = time.monotonic()
             # Scenario.run's spans; the streamed arm dispatches (and
@@ -1170,13 +1212,14 @@ class CrossDeviceScenario(Observable):
                     len(sampled) / dt, 2) if dt > 0 else None
                 with tracer.span("scenario.log"):
                     self._publish_crossdev_status(r, mean_loss)
-                    self.logger.log_metrics(
-                        {"Train/loss": mean_loss,
-                         "Train/round_time_s": dt,
-                         "CrossDev/clients_sampled": int(len(sampled)),
-                         "CrossDev/clients_alive": int(live.sum())},
-                        step=r, round=r,
-                    )
+                    with tracer.span(SPAN_LOG_METRICS):
+                        self.logger.log_metrics(
+                            {"Train/loss": mean_loss,
+                             "Train/round_time_s": dt,
+                             "CrossDev/clients_sampled": int(len(sampled)),
+                             "CrossDev/clients_alive": int(live.sum())},
+                            step=r, round=r,
+                        )
                 if (cfg.training.eval_every
                         and (r + 1) % cfg.training.eval_every == 0):
                     ev = self.evaluate()
@@ -1198,16 +1241,17 @@ class CrossDeviceScenario(Observable):
             if (target_accuracy is not None and rounds_to_target is None
                     and ev["mean_accuracy"] >= target_accuracy):
                 rounds_to_target = last_round + 1
-        self.notify(Events.LEARNING_FINISHED, {})
-        return ScenarioResult(
-            final_accuracy=ev["mean_accuracy"],
-            per_node_accuracy=ev["per_node_accuracy"],
-            rounds_run=rounds,
-            round_times_s=round_times,
-            history=self.logger.history,
-            rounds_to_target=rounds_to_target,
-            min_accuracy=ev["min_accuracy"],
-        )
+        with tracer.span(SPAN_RUN_EXIT):
+            self.notify(Events.LEARNING_FINISHED, {})
+            return ScenarioResult(
+                final_accuracy=ev["mean_accuracy"],
+                per_node_accuracy=ev["per_node_accuracy"],
+                rounds_run=rounds,
+                round_times_s=round_times,
+                history=self.logger.history,
+                rounds_to_target=rounds_to_target,
+                min_accuracy=ev["min_accuracy"],
+            )
 
     def close(self) -> None:
         self.logger.close()

@@ -36,6 +36,12 @@ anything. Per-message sites of the socket plane gate on ``enabled``
 alone. No span name starts with ``bench.``: the benchmark's trace
 reduction takes those for its own.
 
+``Tracer.watch()`` puts a stall watch beside the caller by the same
+rule (a thread while the tracer records, ``NULL_SPAN`` while it does
+not): a pause that stops every thread of the process is in the ring as
+``host.stall``, on the spans' clock, with what the operating system
+says of the same stretch.
+
 Export is Chrome trace-event JSON (the ``{"traceEvents": [...]}``
 object form) — loadable in ``chrome://tracing`` / Perfetto directly,
 or merged first via ``python -m p2pfl_tpu.obs.traceview``.
@@ -64,6 +70,14 @@ from p2pfl_tpu.obs.records import make_record
 
 ENV_VAR = "P2PFL_TRACE"
 _RING_MAX = 1 << 16  # spans kept per process; oldest evicted first
+
+#: the stall watch's record (``Tracer.watch``): a ring span, on a lane of
+#: its own. Not a ``scenario.*`` name: the benchmark's trace reduction
+#: names a device's idle gaps by the ``scenario.*`` span that began last
+STALL_SPAN = "host.stall"
+STALL_LANE = "watch"
+STALL_PERIOD_S = 0.005  # the watch thread's sleep
+STALL_THRESHOLD_S = 0.020  # a wake-up later than this is a stall
 
 
 class _NullSpan:
@@ -132,6 +146,106 @@ class _Span:
         return False
 
 
+def _thread_counters():
+    """A reader of what the operating system counts over a stretch of
+    the CALLING thread's life (so: made by the thread it is to read),
+    and the reader's ``close``: ``run_delay_s``, seconds the thread was
+    runnable and not run (``/proc/thread-self/schedstat``, second
+    field); ``nivcsw``, its involuntary context switches
+    (``RUSAGE_THREAD``); ``cpu_s``, the CPU seconds of the whole process
+    (``time.process_time``), which every platform has: the machine this
+    repo is measured on has neither of the first two. A platform
+    without one leaves that key out."""
+    try:
+        fd = os.open("/proc/thread-self/schedstat", os.O_RDONLY)
+    except OSError:
+        fd = None
+    try:
+        import resource
+
+        who = resource.RUSAGE_THREAD
+    except (ImportError, AttributeError):
+        resource = who = None
+
+    def read() -> dict[str, float]:
+        out = {"cpu_s": time.process_time()}
+        if fd is not None:
+            out["run_delay_s"] = int(os.pread(fd, 64, 0).split()[1]) * 1e-9
+        if who is not None:
+            out["nivcsw"] = resource.getrusage(who).ru_nivcsw
+        return out
+
+    def close() -> None:
+        if fd is not None:
+            os.close(fd)
+
+    return read, close
+
+
+class _StallWatch:
+    """A daemon thread that sleeps ``STALL_PERIOD_S`` at a time and,
+    whenever it wakes more than ``STALL_THRESHOLD_S`` after it meant to,
+    appends one ``STALL_SPAN`` to the tracer's ring, dated back: ``t0``
+    the wake-up it meant, ``dur`` the lateness, on the clock of every
+    ring span. ``args`` is the difference, over that late sleep, of the
+    thread's ``_thread_counters``: a thread that was runnable and not
+    run (``run_delay_s`` about the lateness) sat on a busy host; one
+    that was not even runnable while the clock moved belongs to a
+    stopped process or a paused machine, and so does a process that
+    used no CPU meanwhile (``cpu_s`` near 0); ``cpu_s`` about the
+    lateness or more is a thread of this process that computed and did
+    not hand the interpreter's lock over. A context manager: the thread
+    lives from ``__enter__`` to ``__exit__``."""
+
+    def __init__(self, tracer: "Tracer", clock=time.perf_counter):
+        self._tracer = tracer
+        self._clock = clock
+        self._counters = dict  # the thread's own reader, once it runs
+        self._stop = threading.Event()
+        self._thread: threading.Thread | None = None
+        self._due = 0.0
+        self._seen: dict[str, float] = {}
+
+    def arm(self) -> None:
+        """Note when the sleep that starts now means to end, and the
+        counters it starts from."""
+        self._seen = self._counters()
+        self._due = self._clock() + STALL_PERIOD_S
+
+    def tick(self) -> None:
+        """On waking: record the sleep that just ended if it ended
+        late, and arm the next."""
+        due, seen = self._due, self._seen
+        late = self._clock() - due
+        self.arm()  # reads the counters: once a tick, late or not
+        if late > STALL_THRESHOLD_S:
+            self._tracer._events.append(
+                (STALL_SPAN, STALL_LANE, due, late,
+                 {k: v - seen[k] for k, v in self._seen.items()} or None))
+
+    def _run(self) -> None:
+        self._counters, close = _thread_counters()
+        try:
+            self.arm()
+            stopped = False
+            while not stopped:  # the last sleep, cut short, is looked at too
+                stopped = self._stop.wait(STALL_PERIOD_S)
+                self.tick()
+        finally:
+            close()
+
+    def __enter__(self) -> "_StallWatch":
+        self._thread = threading.Thread(
+            target=self._run, name="p2pfl-stall-watch", daemon=True)
+        self._thread.start()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._stop.set()
+        self._thread.join()
+        return False
+
+
 class Tracer:
     """Span ring + counters + high-water gauges for one process.
 
@@ -195,6 +309,16 @@ class Tracer:
         if not (self.enabled or profiling):
             return NULL_SPAN
         return _Span(self, name, lane, args, annotate=profiling)
+
+    def watch(self):
+        """Context manager under which a stall watch runs beside the
+        caller (``_StallWatch``): whatever stops every thread of this
+        process for longer than ``STALL_THRESHOLD_S`` leaves a
+        ``STALL_SPAN`` in the ring. By ``span()``'s rule: disabled and
+        no profiler session live, the shared NULL_SPAN, and no thread."""
+        if not (self.enabled or _profiling()):
+            return NULL_SPAN
+        return _StallWatch(self)
 
     def next_span_id(self) -> str:
         """Mint a globally-unique span id (``<trace_id>.<n>``) for a
@@ -392,27 +516,49 @@ _TRACE_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
 _CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _program_depth = 0
 # tracing nests (a jit traced inside a jit's trace reports its own
-# duration too, inner first): the disjoint (start, end) stretches seen so
-# far, so that seconds are counted once
-_trace_lower_spans: list[tuple[float, float]] = []
+# duration too, inner first): the disjoint stretches seen so far, so that
+# seconds are counted once, each (start, end, function, traces): the
+# function whose event closed it, the outermost's since the inner ones
+# merged into it, and 1 where that event was a trace, not a lowering.
+# jax fires the trace event around a jaxpr it finds in its cache too (a
+# call that missed the dispatch fast path: tens of microseconds, where
+# tracing a program anew takes a thousand times that): under
+# _TRACED_ANEW_S the seconds count and the trace does not
+_TRACED_ANEW_S = 1e-3
+_trace_lower_spans: list[tuple[float, float, str, int]] = []
+_compile_s_by_function: dict[str, float] = {}
 _cache_load_s = 0.0
 _stage_s: dict[str, float] = {}
 _counted: dict[str, dict] = {}
 
 
-def _add_trace_lower(duration: float) -> None:
+def _function_of(fun_name: str) -> str:
+    """jax reports a trace under the function's own name and its
+    lowering and compile under the module's, ``jit(<name>)``."""
+    if fun_name.endswith(")") and "(" in fun_name:
+        return fun_name[fun_name.index("(") + 1:-1]
+    return fun_name
+
+
+def _add_trace_lower(duration: float, function: str, traces: int) -> None:
     """Note the stretch that ends now and lasted ``duration``. Events
     arrive in order of their ends, so only the newest stretches can lie
-    inside (or run into) this one: they merge into it."""
+    inside (or run into) this one: they merge into it. A function's
+    lowering follows its trace: should the clocks' last digits make the
+    two touch, the lowering keeps the trace it swallowed."""
     end = time.perf_counter()
     start = end - duration
     spans = _trace_lower_spans
     while spans and spans[-1][1] > start:
-        start = min(start, spans.pop()[0])
-    spans.append((start, end))
+        start0, _, function0, traces0 = spans.pop()
+        start = min(start, start0)
+        if not traces and function0 == function:
+            traces = traces0
+    spans.append((start, end, function, traces))
 
 
-def _on_xla_event(event: str, duration: float, **_kw) -> None:
+def _on_xla_event(event: str, duration: float, fun_name: str = "",
+                  **_kw) -> None:
     # the compile counters key on backend_compile specifically:
     # jaxpr tracing/lowering events fire even for programs that then
     # hit the compile cache, and internal array-building programs
@@ -422,13 +568,20 @@ def _on_xla_event(event: str, duration: float, **_kw) -> None:
         with _xla_lock:
             _xla_recompiles += 1
             _xla_compile_s += duration
+            if _program_depth:
+                function = _function_of(fun_name)
+                _compile_s_by_function[function] = (
+                    _compile_s_by_function.get(function, 0.0) + duration)
         if _TRACER.enabled:
             _TRACER.count("xla/backend_compiles")
             _TRACER.count("xla/backend_compile_s", duration)
     elif _program_depth:
         if event in _TRACE_LOWER_EVENTS:
             with _xla_lock:
-                _add_trace_lower(duration)
+                _add_trace_lower(
+                    duration, _function_of(fun_name),
+                    int(event == _TRACE_LOWER_EVENTS[0]
+                        and duration >= _TRACED_ANEW_S))
         elif event == _CACHE_LOAD_EVENT:
             with _xla_lock:
                 _cache_load_s += duration
@@ -517,8 +670,7 @@ def note_counted(values: dict) -> None:
     node, so the node axis is reduced by its largest; per name the
     running ``sum`` and ``max`` over steps (trailing axes kept: a
     layer) and the ``steps`` seen. Kept since the process started,
-    whether or not anything is tracing; also counters/gauges of the
-    tracer when it is enabled."""
+    whether or not anything is tracing."""
     import numpy as np
 
     for name, v in values.items():
@@ -535,8 +687,6 @@ def note_counted(values: dict) -> None:
             at["sum"] = at["sum"] + v.sum(axis=0)
             at["max"] = np.maximum(at["max"], v.max(axis=0))
             at["steps"] += v.shape[0]
-        _TRACER.count(name, float(v.sum()))
-        _TRACER.high_water(name + ".max", float(v.max()))
 
 
 def counted() -> dict[str, dict]:
@@ -552,7 +702,28 @@ def trace_lower_seconds() -> float:
     traces counted once, and whatever runs at trace time (the kernel
     gate's measurements) with them."""
     with _xla_lock:
-        return sum(end - start for start, end in _trace_lower_spans)
+        return sum(end - start for start, end, _, _ in _trace_lower_spans)
+
+
+def trace_lower_by_function() -> dict[str, dict]:
+    """``trace_lower_seconds()`` by the outermost jitted function:
+    ``{function: {"s", "traces"}}``, the seconds of the stretches its
+    events closed (whatever it traced inside itself with them) and how
+    many times it was traced anew (``_TRACED_ANEW_S``); the ``s`` sum to
+    ``trace_lower_seconds()``. A function that reached the backend's
+    compiler inside the program's calls has ``compile_s`` too, the
+    seconds of its ``backend_compile`` events (a load from the
+    persistent cache among them)."""
+    out: dict[str, dict] = {}
+    with _xla_lock:
+        for start, end, function, traces in _trace_lower_spans:
+            at = out.setdefault(function, {"s": 0.0, "traces": 0})
+            at["s"] += end - start
+            at["traces"] += traces
+        for function, seconds in _compile_s_by_function.items():
+            out.setdefault(function, {"s": 0.0, "traces": 0})[
+                "compile_s"] = seconds
+    return out
 
 
 def cache_load_seconds() -> float:

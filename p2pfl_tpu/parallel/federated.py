@@ -574,7 +574,7 @@ def build_round_fn_sparse(
         stale=(Pn, Pn) if staged else None,
     )
 
-    def round_body(fed: FederatedState, x, y, smask, n_samples, mix, adopt, trains):
+    def round_fn(fed: FederatedState, x, y, smask, n_samples, mix, adopt, trains):
         # every block arrives with a leading node axis of size 1
         del adopt  # identity by contract (DFL)
         alive = fed.alive
@@ -621,7 +621,7 @@ def build_round_fn_sparse(
     # check_vma off: the round mixes collectives the replication
     # checker rejects spuriously
     return jax.shard_map(
-        round_body,
+        round_fn,
         mesh=mesh,
         in_specs=(fed_spec, Pn, Pn, Pn, Pn, Pn, Pn, Pn),
         out_specs=(fed_spec, {"train_loss": Pn, "alive": Pn}),

@@ -34,6 +34,14 @@ from p2pfl_tpu.parallel.mesh import (
 )
 from p2pfl_tpu.topology.topology import Topology
 
+#: the names under which jax reports the two programs a transport jits
+#: (``compile_round``, ``compile_eval``): the ``__name__`` every round
+#: and evaluation builder of ``parallel/federated.py`` gives its
+#: function, which ``lora.frozen_argument`` keeps. What
+#: ``obs.trace.trace_lower_by_function()`` files their tracing under
+ROUND_PROGRAM = "round_fn"
+EVAL_PROGRAM = "eval_fn"
+
 
 def edge_offsets(topology: Topology) -> list[int]:
     """Distinct circulant offsets present in the adjacency matrix.

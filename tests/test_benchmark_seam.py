@@ -194,6 +194,45 @@ def test_harness_finds_the_program_it_drives(harness):
     assert isinstance(ling.score_tiles("swa.attn"), dict)
 
 
+def test_host_span_names_are_names_the_program_emits(harness):
+    """The readers of the host's side of a run (``benchmark/hostspans.py``)
+    spell no new span name themselves: each is a constant of the
+    program's, and each constant is a name the program hands
+    ``tracer.span()`` in ``federation/scenario.py`` (the stall watch's:
+    the name its tick appends to the ring). A constant that stayed while
+    its span went would leave its reader silent on the chip."""
+    import hostspans
+    import spans
+
+    from p2pfl_tpu.federation import scenario
+    from p2pfl_tpu.obs import trace as obs_trace
+    from p2pfl_tpu.parallel import transport
+
+    emitted = set()
+    for node in ast.walk(ast.parse(inspect.getsource(scenario))):
+        if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "span" and node.args):
+            name = node.args[0]
+            emitted.add(getattr(scenario, name.id) if isinstance(
+                name, ast.Name) else name.value)
+    assert {scenario.SPAN_RUN, scenario.SPAN_RUN_ENTER,
+            scenario.SPAN_RUN_EXIT} <= emitted
+    asked = {hostspans.RUN, hostspans.LOG_METRICS, hostspans.LOG_RESOURCES,
+             hostspans.LOG_WRITE, hostspans.WAIT, hostspans.LOG,
+             spans.ROUND, spans.EVALUATE}
+    assert None not in asked and asked <= emitted, asked - emitted
+    assert hostspans.STALL == obs_trace.STALL_SPAN
+    assert "STALL_SPAN" in inspect.getsource(obs_trace._StallWatch.tick)
+    assert not obs_trace.STALL_SPAN.startswith(("scenario.", "bench."))
+    # the two programs' names are the builders' own
+    from p2pfl_tpu.parallel import federated
+
+    source = inspect.getsource(federated)
+    for program in (hostspans.ROUND_PROGRAM, hostspans.EVAL_PROGRAM):
+        assert program in (transport.ROUND_PROGRAM, transport.EVAL_PROGRAM)
+        assert f"def {program}(" in source
+
+
 def test_score_tiles_share_reads_the_programs_record(harness, monkeypatch):
     """``mla.score_tiles_share``: nothing on a program without the
     record (the parent's ``models/ling.py``), and after a trace of the

@@ -5,7 +5,10 @@ set-up, and the named scopes of the device work."""
 
 import contextlib
 import glob
+import os
 import re
+import sys
+import threading
 import time
 
 import jax
@@ -13,9 +16,12 @@ import numpy as np
 import pytest
 
 from p2pfl_tpu.config.schema import DataConfig, ScenarioConfig, TrainingConfig
+from p2pfl_tpu.federation import scenario as scenario_module
+from p2pfl_tpu.federation.events import Events
 from p2pfl_tpu.federation.scenario import Scenario
 from p2pfl_tpu.obs import trace as obs_trace
 from p2pfl_tpu.obs.trace import NULL_SPAN
+from p2pfl_tpu.parallel import transport
 
 ROUND_CHILDREN = {"scenario.plan", "scenario.dispatch", "scenario.wait",
                   "scenario.fetch", "scenario.log", "scenario.status"}
@@ -54,10 +60,23 @@ def tracer(monkeypatch):
 
 
 def inside(parent, spans):
-    """Spans lying within ``parent``'s interval, in order of start."""
+    """The driver's spans (the watch's lie on a lane of their own)
+    within ``parent``'s interval, in order of start."""
     lo, hi = parent[2], parent[2] + parent[3]
-    return sorted((s for s in spans if s is not parent and s[2] >= lo
-                   and s[2] + s[3] <= hi), key=lambda s: s[2])
+    return sorted((s for s in spans if s is not parent and s[1] is None
+                   and s[2] >= lo and s[2] + s[3] <= hi),
+                  key=lambda s: s[2])
+
+
+def children(parent, spans):
+    """Those of them directly inside ``parent``: in no other."""
+    kids = inside(parent, spans)
+    return [k for k in kids
+            if not any(k in inside(other, kids) for other in kids)]
+
+
+def named(spans, name):
+    return [s for s in spans if s[0] == name]
 
 
 def test_round_span_is_the_parent_of_the_rounds_host_work(toy, tracer):
@@ -71,7 +90,7 @@ def test_round_span_is_the_parent_of_the_rounds_host_work(toy, tracer):
     rounds = [s for s in spans if s[0] == "scenario.round"]
     assert [s[4]["round"] for s in rounds] == [start, start + 1]
     for parent in rounds:
-        kids = inside(parent, spans)
+        kids = children(parent, spans)
         assert {k[0] for k in kids} == ROUND_CHILDREN
         assert [k[0] for k in kids][:4] == [
             "scenario.plan", "scenario.dispatch", "scenario.wait",
@@ -112,17 +131,173 @@ def test_cross_device_rounds_carry_the_same_spans(tracer, prefetch):
     rounds = [s for s in spans if s[0] == "scenario.round"]
     assert [s[4]["round"] for s in rounds] == [0, 1]
     for parent in rounds:
-        assert [k[0] for k in inside(parent, spans)] == [
+        kids = children(parent, spans)
+        assert [k[0] for k in kids] == [
             "scenario.plan", "scenario.dispatch", "scenario.wait",
             "scenario.fetch", "scenario.log"]
+        assert [k[0] for k in children(kids[-1], spans)] == [
+            scenario_module.SPAN_LOG_METRICS]
     assert [s[0] for s in spans].count("scenario.evaluate") == 1
+    # the root, what comes before the first round and after the last
+    (run,) = named(spans, scenario_module.SPAN_RUN)
+    assert run[4] == {"rounds": 2, "start_round": 0}
+    assert [k[0] for k in children(run, spans)] == [
+        scenario_module.SPAN_RUN_ENTER, "scenario.round", "scenario.round",
+        "scenario.evaluate", scenario_module.SPAN_RUN_EXIT]
+    assert _watch_threads() == []
 
 
-def test_off_and_no_profiler_records_nothing(toy, tracer):
+def test_run_span_is_the_root_of_what_run_does(toy, tracer):
+    tracer.configure(enabled=True)
+    start = int(np.asarray(toy.fed.round))
+    toy.run(rounds=2)
+    spans = tracer.spans()
+    (run,) = named(spans, scenario_module.SPAN_RUN)
+    assert run[4] == {"rounds": 2, "start_round": start}
+    kids = children(run, spans)
+    assert [k[0] for k in kids] == [
+        scenario_module.SPAN_RUN_ENTER, "scenario.round", "scenario.round",
+        scenario_module.SPAN_RUN_EXIT, "scenario.evaluate",
+        scenario_module.SPAN_RUN_EXIT]
+    # every round and the closing evaluation, and nothing of them outside
+    assert [k for k in kids if k[0] == "scenario.round"] == named(
+        spans, "scenario.round")
+    assert [k for k in kids if k[0] == "scenario.evaluate"] == named(
+        spans, "scenario.evaluate")
+    enter, first = kids[0], kids[1]
+    assert first[4] == {"round": start}
+    assert 0.0 <= first[2] - (enter[2] + enter[3]) < 0.05
+    assert enter[2] - run[2] < 0.05 and kids[-1][3] < 0.05
+
+
+def test_the_parts_of_scenario_log_lie_inside_it(toy, tracer):
+    tracer.configure(enabled=True)
+    toy.run(rounds=2)
+    spans = tracer.spans()
+    logs = named(spans, "scenario.log")
+    assert len(logs) == 4  # two a round
+    parts = [[k[0] for k in children(log, spans)] for log in logs]
+    assert parts == 2 * [[scenario_module.SPAN_LOG_METRICS],
+                         [scenario_module.SPAN_LOG_RESOURCES,
+                          scenario_module.SPAN_LOG_WRITE]]
+    for log in logs:
+        kids = children(log, spans)
+        assert 0.0 <= log[3] - sum(k[3] for k in kids) < 0.05
+    # they are the logs' and not the round's: its direct children are
+    # the names they were
+    for parent in named(spans, "scenario.round"):
+        assert {k[0] for k in children(parent, spans)} == ROUND_CHILDREN
+
+
+def _watch_threads():
+    return [t for t in threading.enumerate()
+            if t.name == "p2pfl-stall-watch"]
+
+
+def test_off_and_no_profiler_records_nothing(toy, tracer, monkeypatch):
+    """... and starts no thread and opens nothing under ``/proc``: the
+    driver's untraced runs must not see the watch."""
     assert tracer.span("scenario.round", args={"round": 0}) is NULL_SPAN
-    toy.run(rounds=1)
+    assert tracer.watch() is NULL_SPAN
+    threads, opened = [], []
+    real_open = os.open
+
+    def spying_open(path, *args, **kwargs):
+        opened.append(str(path))
+        return real_open(path, *args, **kwargs)
+
+    def no_counters():
+        raise AssertionError("the watch's reader was made")
+
+    monkeypatch.setattr(os, "open", spying_open)
+    monkeypatch.setattr(obs_trace, "_thread_counters", no_counters)
+    before = set(threading.enumerate())
+    toy.add_observer(lambda event, payload: threads.append(
+        set(threading.enumerate()) - before))
+    try:
+        toy.run(rounds=1)
+    finally:
+        toy._observers.pop()
+    assert threads and not any(threads)
+    assert not [p for p in opened if p.startswith("/proc")]
     assert tracer.spans() == []
     assert tracer.enabled is False
+
+
+def test_the_watch_lives_as_long_as_run_does(toy, tracer):
+    tracer.configure(enabled=True)
+    seen = []
+    toy.add_observer(lambda event, payload: seen.append(
+        (event, len(_watch_threads()))))
+    try:
+        toy.run(rounds=1)
+    finally:
+        toy._observers.pop()
+    assert {n for _, n in seen} == {1}
+    assert seen[-1][0] is Events.LEARNING_FINISHED
+    assert _watch_threads() == []
+
+    def failing(event, payload):
+        if event is Events.AGGREGATION_FINISHED:
+            raise RuntimeError("an observer fails mid-round")
+
+    tracer.reset()
+    toy.add_observer(failing)
+    try:
+        with pytest.raises(RuntimeError):
+            toy.run(rounds=1)
+    finally:
+        toy._observers.pop()
+    assert _watch_threads() == []
+    # the root closed over the failure, and so did what was open in it
+    names = [s[0] for s in tracer.spans()]
+    assert names[-3:] == ["scenario.round", scenario_module.SPAN_RUN_EXIT,
+                          scenario_module.SPAN_RUN]
+
+
+def test_a_stall_during_run_lands_in_the_ring_beside_the_wait(
+        toy, tracer, monkeypatch):
+    """Every thread of the process made to stand still inside one
+    round's wait for the device (the interpreter's lock held and not
+    handed over, as a pause of the machine would hold them): the ring
+    has a ``host.stall`` on the watch's lane that overlaps that round's
+    ``scenario.wait``."""
+    tracer.configure(enabled=True)
+    real = jax.block_until_ready
+
+    def wait_after_a_pause(x):
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.12:
+            pass
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", wait_after_a_pause)
+    handed_over = sys.getswitchinterval()
+    sys.setswitchinterval(10.0)
+    try:
+        toy.run(rounds=1)
+    finally:
+        sys.setswitchinterval(handed_over)
+    spans = tracer.spans()
+    (wait,) = named(spans, "scenario.wait")
+    stalls = named(spans, obs_trace.STALL_SPAN)
+    assert stalls and {s[1] for s in stalls} == {obs_trace.STALL_LANE}
+    overlap = sum(max(0.0, min(s[2] + s[3], wait[2] + wait[3])
+                      - max(s[2], wait[2])) for s in stalls)
+    assert overlap >= 0.05
+
+
+def test_the_programs_names_are_the_transports(toy, tracer):
+    """``trace_lower_by_function()`` files the two programs' tracing
+    under the names ``parallel/transport.py`` states."""
+    assert toy._round_fn.__name__ == transport.ROUND_PROGRAM
+    assert toy._eval_fn.__name__ == transport.EVAL_PROGRAM
+    by_function = obs_trace.trace_lower_by_function()
+    for program in (transport.ROUND_PROGRAM, transport.EVAL_PROGRAM):
+        assert by_function[program]["traces"] >= 1
+        assert by_function[program]["s"] > 0.0
+    assert sum(f["s"] for f in by_function.values()) == pytest.approx(
+        obs_trace.trace_lower_seconds())
 
 
 def test_configure_from_env_zero_still_switches_off(tracer):

@@ -3,11 +3,12 @@
 Reference inventory (SURVEY.md §2.4, fedstellar/learning/pytorch/*):
 MNIST MLP/CNN, FEMNIST CNN, CIFAR10 ResNet9/18/34/50 + two MobileNets,
 SYSCALL MLP/Autoencoder/One-class-SVM, WADI MLP — plus ViT-Tiny for the
-stretch config in BASELINE.json and three sparse-expert language models
+stretch config in BASELINE.json and four sparse-expert language models
 as frozen bases under adapters: Ling-3.0-flash (linear and latent
-attention), Laguna-S-2.1 (window and full grouped-query attention) and
+attention), Laguna-S-2.1 (window and full grouped-query attention),
 LFM2-8B-A1B (gated short convolutions among grouped-query attention, a
-tied head).
+tied head) and Kimi-Linear-48B-A3B (Kimi Delta Attention with an
+unbounded decay among NoPE latent attention).
 
 TPU-first design notes:
 - Normalization is **GroupNorm**, not BatchNorm: batch statistics are
@@ -29,6 +30,7 @@ from p2pfl_tpu.models.vit import ViT
 from p2pfl_tpu.models.ling import LingLM
 from p2pfl_tpu.models.laguna import LagunaLM
 from p2pfl_tpu.models.lfm2 import Lfm2LM
+from p2pfl_tpu.models.kimi_linear import KimiLinearLM
 
 __all__ = [
     "get_model",
@@ -50,4 +52,5 @@ __all__ = [
     "LingLM",
     "LagunaLM",
     "Lfm2LM",
+    "KimiLinearLM",
 ]

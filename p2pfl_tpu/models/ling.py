@@ -31,14 +31,18 @@ up-projection again and goes back by hand in three grouped products
 (``_block_back``), the down-projection's output never formed a second
 time: a training step executes 8/3 of a forward's expert FLOPs.
 
-``models/laguna.py`` builds its model from the parts here that are not
-Ling's alone: ``causal_attention`` (its window, its grouped query heads
-and its scope are arguments; on the TPU it runs the fused kernels of
+``models/laguna.py``, ``models/lfm2.py`` and ``models/kimi_linear.py``
+build their models from the parts here that are not Ling's alone:
+``causal_attention`` (its window, its grouped query heads and its scope
+are arguments; on the TPU it runs the fused kernels of
 ``ops/flash_attention.py``, elsewhere its own tiled form), ``ExpertFFN``
 over ``held_experts`` (the router is an argument), ``RMSNorm``,
-``DenseFFN`` and ``CausalLM`` (the embedding, the head and the chunked
-loss). A change to one of them is
-measured in both models' cells.
+``DenseFFN``, ``CausalLM`` (the embedding, the head, the chunked loss,
+the counters), ``causal_conv``; Kimi Linear's also ``head_blocks``,
+``kda_heads`` (its ``floor`` chooses the form: Ling's gate is bounded
+below, Kimi Linear's is not) and ``MLAMixer`` (without its rotary
+embedding, q/k norms and gate). A change to one of them is measured in
+every cell that uses it.
 """
 
 from __future__ import annotations
@@ -57,6 +61,9 @@ from p2pfl_tpu.ops.fold import fold_rows
 
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
+#: the delta rule's sub-chunk, and the largest exponent its bounded form
+#: allows inside one (Ling's gate: 16 steps of at most 5)
+SUB, CLIP = 16, 80.0
 
 
 def _dense(features, name, mod):
@@ -215,39 +222,123 @@ def _solve_back(kept, g):
 unit_lower_solve.defvjp(_solve, _solve_back)
 
 
-def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16,
-                head_block=8):
+def _sub_rows(a, sub):
+    """[.., C, K] -> [.., C / sub, sub, K]"""
+    return a.reshape(a.shape[:-2] + (a.shape[-2] // sub, sub, a.shape[-1]))
+
+
+def _decays(G, sub):
+    """``e^(G_i - G_j)`` of every row ``i`` of each sub-chunk against its
+    every key ``j``, ``[.., sub (i), sub (j), K]``, 0 where ``j > i``:
+    every exponent ``<= 0``. Only ever an operand of a sum over ``K`` or
+    over a side of the block, which XLA forms inside the sum's fusion:
+    the compiled program holds no such array
+    (``tests/test_tpu_aot_compile.py``)."""
+    i = jnp.arange(sub)
+    seen = (i[:, None] >= i[None, :])[:, :, None]
+    return jnp.exp(jnp.where(seen, G[..., :, None, :] - G[..., None, :, :],
+                             -jnp.inf))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _diag_pairs(q, k, G, sub):
+    """The diagonal ``sub x sub`` blocks of :func:`kda_heads`' two
+    intra-chunk matrices without ``b``, from the pairwise differences of
+    the running sums: ``[.., C / sub, sub (i), sub (j)]`` of ``sum_c k_ic
+    k_jc e^(G_ic - G_jc)`` and of ``sum_c q_ic k_jc e^(G_ic - G_jc)`` for
+    ``j <= i``, 0 above the diagonal; ``q, k, G`` [.., C, K] float32. Each
+    is one sum over ``K`` of the factors (:func:`_decays`), and the way
+    back, which keeps the inputs alone, forms them again inside each of
+    its sums: ``sub`` times ``q``'s size as a whole array, they never are
+    one (formed a key at a time instead, XLA kept the 16 keys' factors of
+    a block of heads for the way back, 3.1 GB more than the round program
+    of the chip's share had, ``PERF.md`` Findings, Kimi Linear)."""
+    return _diag_pairs_fwd(q, k, G, sub)[0]
+
+
+def _diag_pairs_fwd(q, k, G, sub):
+    qs, ks, Gs = (_sub_rows(a, sub) for a in (q, k, G))
+    e = _decays(Gs, sub)
+    kj = ks[..., None, :, :] * e
+    return (jnp.sum(ks[..., :, None, :] * kj, axis=-1),
+            jnp.sum(qs[..., :, None, :] * kj, axis=-1)), (q, k, G)
+
+
+def _diag_pairs_back(sub, kept, grads):
+    # a hand-written way back does not inherit the name stack of the way
+    # forward: the scopes the device time is read by are set here
+    with jax.named_scope("kda.scan"), jax.named_scope("kda.diag"):
+        qs, ks, Gs = (_sub_rows(a, sub) for a in kept)
+        d_a, d_b = (d[..., None] for d in grads)  # [.., i, j, 1]
+        e = _decays(Gs, sub)
+        kj = ks[..., None, :, :]
+        # the products' gradients to ``k_i`` and ``q_i`` (rows), to the
+        # factor ``k_j e_ij`` (``w``), and through ``e_ij`` to ``G_i`` and
+        # ``G_j``
+        dk = jnp.sum(d_a * kj * e, axis=-2)
+        dq = jnp.sum(d_b * kj * e, axis=-2)
+        w = (d_a * ks[..., :, None, :] + d_b * qs[..., :, None, :]) * e
+        dk = dk + jnp.sum(w, axis=-3)
+        t = w * kj
+        dG = jnp.sum(t, axis=-2) - jnp.sum(t, axis=-3)
+        whole = lambda a: a.reshape(kept[0].shape)
+        return whole(dq), whole(dk), whole(dG)
+
+
+_diag_pairs.defvjp(_diag_pairs_fwd, _diag_pairs_back)
+
+
+def kda_chunked(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
+                head_block=8, floor=None):
     """:func:`kda_heads`, a block of heads at a time."""
     return head_blocks(
-        functools.partial(kda_heads, chunk=chunk, sub=sub, dtype=dtype),
+        functools.partial(kda_heads, chunk=chunk, sub=sub, dtype=dtype,
+                          floor=floor),
         head_block, (q, k, v, g, beta))
 
 
-def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
+def kda_heads(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
+              floor=None):
     """The delta rule with a decay per channel, a chunk at a time.
 
     ``q, k`` [B, T, H, K] (normalised, q scaled), ``v`` [B, T, H, V],
-    ``g`` [B, T, H, K] the log-decay (in ``(-80 / sub, 0]``), ``beta``
-    [B, T, H]. Per head: ``S_t = (I - b_t k_t k_t^T) Diag(exp g_t)
-    S_{t-1} + b_t k_t v_t^T``, ``o_t = S_t^T q_t``. Within a chunk, with
-    ``G`` the running sum of ``g`` and ``S_0`` the state the chunk
-    starts from, the updates ``u_i = b_i (v_i - k_i^T Diag(a_i)
-    S_{i-1})`` solve the unit lower-triangular system ``(I + A) U =
-    b (V - (K e^G) S_0)``, ``A_ij = b_i sum_c k_ic k_jc e^(G_ic - G_jc)``
-    for ``j < i``; then ``O = (Q e^G) S_0 + B U`` with ``B_ij = sum_c
-    q_ic k_jc e^(G_ic - G_jc)`` for ``j <= i``, and ``S_C = e^(G_C) S_0
-    + (K e^(G_C - G))^T U``. ``e^(G_i - G_j)`` is never formed from
-    ``e^(-G_j)`` alone, which overflows within a chunk: rows are taken
-    a sub-chunk of ``sub`` at a time against the running sum at that
-    sub-chunk's start, so that each factor's exponent lies within
-    ``sub`` steps of decay. The system is solved by forming ``(I +
-    A)^-1`` block by block and one product with the right-hand side
-    (:func:`unit_lower_solve`; ``chunk`` a power of two), not by the
-    powers of ``A``: with ``|A_ij|`` up to ``b_i`` < 1 they reach
-    binomial size over 64 rows and cancel in float32. The state, the
-    running sums, the exponentials and the solve are float32 whatever
-    ``dtype`` is; the other products take ``dtype`` operands and
-    accumulate in float32."""
+    ``g`` [B, T, H, K] the log-decay (finite, ``<= 0``), ``beta`` [B, T,
+    H]. Per head: ``S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_{t-1} +
+    b_t k_t v_t^T``, ``o_t = S_t^T q_t``. Within a chunk, with ``G`` the
+    running sum of ``g`` and ``S_0`` the state the chunk starts from, the
+    updates ``u_i = b_i (v_i - k_i^T Diag(a_i) S_{i-1})`` solve the unit
+    lower-triangular system ``(I + A) U = b (V - (K e^G) S_0)``, ``A_ij =
+    b_i sum_c k_ic k_jc e^(G_ic - G_jc)`` for ``j < i``; then ``O = (Q
+    e^G) S_0 + B U`` with ``B_ij = sum_c q_ic k_jc e^(G_ic - G_jc)`` for
+    ``j <= i``, and ``S_C = e^(G_C) S_0 + (K e^(G_C - G))^T U``.
+
+    ``e^(G_i - G_j)`` is never formed from ``e^(-G_j)`` alone, which
+    overflows within a chunk: rows are taken a sub-chunk of ``sub`` at a
+    time against the running sum ``ref`` at that sub-chunk's start, as
+    ``e^(G_i - ref) e^(ref - G_j)``, both exponents ``<= 0`` for a key
+    ``j`` before the sub-chunk. Inside it, where ``ref - G_j`` is the
+    decay over up to ``sub`` steps, ``floor`` (static, the model's own, as
+    ``window`` is attention's) chooses one of two forms:
+
+    - a gate bounded below by ``floor`` with ``sub * -floor <= 80``
+      (Ling's safe gate, -5 over 16 steps): the same product over the
+      whole row of the sub-chunk, the second exponent clipped at 80,
+      which it never reaches (at Ling's shapes a third fewer bytes
+      accessed than the other form, forward and back);
+    - any other ``floor``, ``None`` among them (Kimi Linear's softplus
+      gate, unbounded below): the diagonal ``sub x sub`` blocks from the
+      pairwise differences ``e^(G_i - G_j)``, ``j <= i``, which never
+      exceed 1, float32 (:func:`_diag_pairs`, scope ``kda.diag``): exact
+      for any finite decay; the ``[.., sub, sub, K]`` factors exist only
+      inside the fusions of the sums over them, never as an array.
+
+    The system is solved by forming ``(I + A)^-1`` block by block and one
+    product with the right-hand side (:func:`unit_lower_solve`; ``chunk``
+    a power of two), not by the powers of ``A``: with ``|A_ij|`` up to
+    ``b_i`` < 1 they reach binomial size over 64 rows and cancel in
+    float32. The state, the running sums, the exponentials and the solve
+    are float32 whatever ``dtype`` is; the other products take ``dtype``
+    operands and accumulate in float32."""
     B, T, H, K = q.shape
     C = chunk
     pad = -T % C
@@ -263,6 +354,10 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
     G = jnp.cumsum(g, axis=3)
     mm = functools.partial(jnp.einsum, preferred_element_type=F32)
     lo = lambda a: a.astype(dtype)
+    bounded = floor is not None and sub * -floor <= CLIP
+    if not bounded:
+        with jax.named_scope("kda.diag"):
+            diag_a, diag_b = _diag_pairs(q, k, G, sub)
 
     # ---- the two intra-chunk matrices, a sub-chunk of rows at a time
     rows_a, rows_b = [], []
@@ -270,9 +365,20 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=16, dtype=jnp.bfloat16):
         s0, s1 = a * sub, (a + 1) * sub
         ref = G[..., s0 - 1:s0, :] if a else jnp.zeros_like(G[..., :1, :])
         left = jnp.exp(G[..., s0:s1, :] - ref)  # exponents <= 0
-        right = lo(k[..., :s1, :] * jnp.exp(jnp.minimum(
-            ref - G[..., :s1, :], 80.0)))  # <= 0 before s0, <= 80 inside
         widen = lambda m: jnp.pad(m, ((0, 0),) * 4 + ((0, C - s1),))
+        if not bounded:
+            # keys before the sub-chunk on the matrix unit (none in the
+            # first), its own from the pairwise differences; one trace a
+            # sub-chunk, every shape static
+            right = lo(k[..., :s0, :] * jnp.exp(ref - G[..., :s0, :]))
+            for x, rows, diag in ((k, rows_a, diag_a), (q, rows_b, diag_b)):
+                before = mm("nbhik,nbhjk->nbhij", lo(x[..., s0:s1, :] * left),
+                            right)
+                rows.append(widen(jnp.concatenate(  # fedlint: disable=recompile-hazard
+                    [before, diag[..., a, :, :]], axis=-1)))
+            continue
+        right = lo(k[..., :s1, :] * jnp.exp(jnp.minimum(
+            ref - G[..., :s1, :], CLIP)))  # <= 0 before s0, <= 80 inside
         rows_a.append(widen(mm("nbhik,nbhjk->nbhij",
                                lo(k[..., s0:s1, :] * left), right)))
         rows_b.append(widen(mm("nbhik,nbhjk->nbhij",
@@ -371,7 +477,7 @@ class KDAMixer(nn.Module):
             g = self.lower_bound * jax.nn.sigmoid(a.astype(F32))
             with jax.named_scope("kda.scan"):
                 o = kda_heads(q, k, v, g, beta, chunk=self.chunk,
-                              dtype=self.dtype)
+                              dtype=self.dtype, floor=self.lower_bound)
             return (rms_norm(o, scale, self.eps)
                     * gate[..., None]).astype(self.dtype)
 
@@ -693,6 +799,15 @@ _attend.defvjp(_attend_kept, _attend_back)
 
 
 class MLAMixer(nn.Module):
+    """Latent attention: ``q = x W_q``; ``[c | k_R] = x W_dkv``, ``c``
+    normalised; ``[k_N | v] = c W_ukv``; ``k = [k_N | k_R]`` with ``k_R``
+    every head's. Ling's form (the default) puts an RMSNorm over each head
+    of q and of k, the interleaved rotary embedding on the last ``rope``
+    dimensions of both, and one sigmoid output gate a head. With
+    ``use_nope`` (Kimi Linear's ``mla_use_nope``) there is none of the
+    three and their parameters are not made: the ``rope`` dimensions
+    enter the score as plain dimensions."""
+
     heads: int
     nope: int = 128
     rope: int = 64
@@ -702,11 +817,13 @@ class MLAMixer(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    use_nope: bool = False
 
     @nn.compact
     def __call__(self, x):
         B, T, _ = x.shape
         H, N, R, Dv = self.heads, self.nope, self.rope, self.v_dim
+        ling = not self.use_nope
         norm = lambda name, width: self.param(
             name, nn.initializers.ones, (width,), self.param_dtype)
         q = _dense(H * (N + R), "mla_q", self)(x).reshape(B, T, H, N + R)
@@ -716,16 +833,21 @@ class MLAMixer(nn.Module):
         kv = _dense(H * (N + Dv), "mla_ukv", self)(c).reshape(B, T, H, N + Dv)
         k = jnp.concatenate([kv[..., :N], jnp.broadcast_to(
             ckr[:, :, None, self.kv_rank:], (B, T, H, R))], axis=-1)
-        q = rms_norm(q, norm("q_norm", N + R), self.eps)
-        k = rms_norm(k, norm("k_norm", N + R), self.eps)
-        turn = lambda a: jnp.concatenate(
-            [a[..., :N], rope_interleaved(a[..., N:], self.theta)],
-            axis=-1).astype(self.dtype)
-        gate = jax.nn.sigmoid(_dense(H, "mla_g", self)(x).astype(F32))
+        if ling:
+            q = rms_norm(q, norm("q_norm", N + R), self.eps)
+            k = rms_norm(k, norm("k_norm", N + R), self.eps)
+        turn = lambda a: (jnp.concatenate(
+            [a[..., :N], rope_interleaved(a[..., N:], self.theta)], axis=-1)
+            if ling else a).astype(self.dtype)
+        if ling:
+            gate = jax.nn.sigmoid(_dense(H, "mla_g", self)(x).astype(F32))
         with jax.named_scope("mla.attn"):
             o = causal_attention(turn(q), turn(k), kv[..., N:],
-                                 (N + R) ** -0.5)
-        o = (o * gate[..., None]).astype(self.dtype).reshape(B, T, H * Dv)
+                                 (N + R) ** -0.5,
+                                 out_dtype=F32 if ling else self.dtype)
+        if ling:
+            o = o * gate[..., None]
+        o = o.astype(self.dtype).reshape(B, T, H * Dv)
         return _dense(x.shape[-1], "mla_o", self)(o)
 
 
@@ -1128,12 +1250,16 @@ class CausalLM(nn.Module):
                 for t in range(0, T, self.loss_chunk)) / T
         m = jnp.ones(per_row.shape, F32) if mask is None else mask.astype(F32)
         loss = jnp.sum(per_row * m) / jnp.maximum(jnp.sum(m), 1.0)
-        aux = {}
-        if stats:
-            stats = jnp.stack(stats)
-            aux = {"moe.dropped_pairs": stats[:, 0],
-                   "moe.load_max_over_mean": stats[:, 1]}
-        return loss, aux
+        return loss, self.counters(stats)
+
+    def counters(self, stats):
+        """The counters by name [layers] from what the blocks returned
+        (every one that is not None): here the expert layers' [2]."""
+        if not stats:
+            return {}
+        stats = jnp.stack(stats)
+        return {"moe.dropped_pairs": stats[:, 0],
+                "moe.load_max_over_mean": stats[:, 1]}
 
 
 class LingLM(CausalLM):

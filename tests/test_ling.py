@@ -114,14 +114,16 @@ def kda_inputs(T, B=2, H=2, K=16, seed=0):
 def test_chunked_kda_is_the_recurrence_forward_and_backward(ref, T):
     """Chunk 64, lengths that are no multiple of it, decays at their
     fastest (``g`` near -5 a step: 320 a chunk, past what ``exp`` holds
-    unless the sub-chunks keep the exponents apart)."""
+    unless the sub-chunks keep the exponents apart), through the form of
+    Ling's gate (bounded below by -5)."""
     args = kda_inputs(T)
     weigh = jax.random.normal(jax.random.PRNGKey(9), (2, T, 2, 16))
-    chunked = lambda *a: jnp.sum(ling.kda_chunked(*a, dtype=F32) * weigh)
+    chunked = lambda *a: jnp.sum(ling.kda_chunked(
+        *a, dtype=F32, floor=-5.0) * weigh)
     plain = lambda *a: jnp.sum(ref.kda_recurrence(*a) * weigh)
     np.testing.assert_allclose(
-        ling.kda_chunked(*args, dtype=F32), ref.kda_recurrence(*args),
-        rtol=2e-4, atol=2e-5)
+        ling.kda_chunked(*args, dtype=F32, floor=-5.0),
+        ref.kda_recurrence(*args), rtol=2e-4, atol=2e-5)
     got = jax.grad(chunked, argnums=(0, 1, 2, 3, 4))(*args)
     want = jax.grad(plain, argnums=(0, 1, 2, 3, 4))(*args)
     for a, b in zip(got, want):
@@ -129,8 +131,8 @@ def test_chunked_kda_is_the_recurrence_forward_and_backward(ref, T):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4)
     # a block of heads at a time is the same thing
     np.testing.assert_allclose(
-        ling.kda_chunked(*args, dtype=F32, head_block=1),
-        ling.kda_chunked(*args, dtype=F32), rtol=1e-5, atol=1e-6)
+        ling.kda_chunked(*args, dtype=F32, head_block=1, floor=-5.0),
+        ling.kda_chunked(*args, dtype=F32, floor=-5.0), rtol=1e-5, atol=1e-6)
 
 
 def chunk_systems(case, n=6, C=64, K=16, seed=0):

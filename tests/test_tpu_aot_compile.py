@@ -159,7 +159,8 @@ def test_ling_delta_rule_holds_no_triangular_solve(one_chip,
 
     def loss(q, k, v, g, beta, weigh):
         return jnp.sum(weigh * ling.kda_chunked(q, k, v, g, beta,
-                                                dtype=jnp.bfloat16))
+                                                dtype=jnp.bfloat16,
+                                                floor=-5.0))
 
     compiled = jax.jit(jax.vmap(jax.value_and_grad(
         loss, argnums=(0, 1, 2, 3, 4)))).lower(
@@ -207,7 +208,7 @@ def test_ling_delta_rule_forward_stages_its_operands_once(
     from p2pfl_tpu.models import ling
 
     compiled = jax.jit(jax.vmap(lambda *rows: ling.kda_chunked(
-        *rows, dtype=jnp.bfloat16))).lower(
+        *rows, dtype=jnp.bfloat16, floor=-5.0))).lower(
         *delta_rule_shapes(one_chip)).compile()
     hlo = compiled.as_text()
     assert "convolution" in hlo  # the text is the optimized module
@@ -337,23 +338,13 @@ def test_laguna_attention_forms_only_the_tiles_a_block_sees(
     assert compiled.memory_analysis().temp_size_in_bytes < temp
 
 
-def test_lfm2_cells_round_program_fits_the_chip(one_chip, no_persistent_cache,
-                                                monkeypatch):
-    """The WHOLE round program of ``lfm2-8b-a1b.dfl16-full-lora-s2048`` at
-    the cell's own sizes (published layers 1 to 9 at the published
-    widths, 16 nodes, 2 steps of one sequence of 2048, the frozen 6.3 GB
-    base an argument), built by ``Scenario`` from the cell's own files
-    with the base left abstract, compiles for the described v5e: XLA:TPU
-    refuses a program that does not fit the chip's 15.75 GiB. It stood at
-    14.16 GiB when the cell came (8.92 GB of temporaries over 6.62 GB of
-    arguments). The expert layers' pairs are two blocks of 65,536 rows
-    (every one of the 32 experts held, 4 a token), the head's logits are
-    never wider than a chunk of 128 positions, and nothing is shared:
-    no op bears ``moe.shared``."""
+def abstract_cell(name, monkeypatch):
+    """The cell's ``Scenario``, built from the cell's own files with the
+    frozen base left abstract (nothing of its GBs is made or placed
+    here), for a chip whose path the models trace: the patches hold for
+    the test's own duration."""
     import importlib.util
     import pathlib
-
-    import numpy as np
 
     from p2pfl_tpu.federation import Scenario
     from p2pfl_tpu.learning import lora
@@ -368,7 +359,6 @@ def test_lfm2_cells_round_program_fits_the_chip(one_chip, no_persistent_cache,
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
 
-    # the base stays shapes: nothing of 6.3 GB is made or placed here
     abstract = lambda a: isinstance(a, jax.ShapeDtypeStruct)
     monkeypatch.setattr(lora, "base_params_for", lambda model, seed, x:
                         jax.eval_shape(model.init, jax.random.PRNGKey(seed), x))
@@ -380,24 +370,49 @@ def test_lfm2_cells_round_program_fits_the_chip(one_chip, no_persistent_cache,
                         x if abstract(x) else place(self, x, s))
     monkeypatch.setattr(jax, "block_until_ready", lambda tree: tree if any(
         map(abstract, jax.tree.leaves(tree))) else ready(tree))
+    return Scenario(bench.Cell(name, False).scenario_config(7))
 
-    cell = bench.Cell("lfm2-8b-a1b.dfl16-full-lora-s2048", False)
-    sc = Scenario(cell.scenario_config(7))
+
+def on_device(tree, sharding):
+    import numpy as np
+
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        np.shape(a), a.dtype, sharding=sharding), tree)
+
+
+def held_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_lfm2_cells_round_program_fits_the_chip(one_chip, no_persistent_cache,
+                                                monkeypatch):
+    """The WHOLE round program of ``lfm2-8b-a1b.dfl16-full-lora-s2048`` at
+    the cell's own sizes (published layers 1 to 9 at the published
+    widths, 16 nodes, 2 steps of one sequence of 2048, the frozen 6.3 GB
+    base an argument), built by ``Scenario`` from the cell's own files
+    with the base left abstract, compiles for the described v5e: XLA:TPU
+    refuses a program that does not fit the chip's 15.75 GiB. It stood at
+    14.16 GiB when the cell came (8.92 GB of temporaries over 6.62 GB of
+    arguments). The expert layers' pairs are two blocks of 65,536 rows
+    (every one of the 32 experts held, 4 a token), the head's logits are
+    never wider than a chunk of 128 positions, and nothing is shared:
+    no op bears ``moe.shared``."""
+    import numpy as np
+
+    sc = abstract_cell("lfm2-8b-a1b.dfl16-full-lora-s2048", monkeypatch)
     try:
-        args = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
-                                           sharding=one_chip),
-            (sc.fed, *sc._data_args, *sc._plan_args(None), *sc._frozen))
+        args = on_device((sc.fed, *sc._data_args, *sc._plan_args(None),
+                          *sc._frozen), one_chip)
         base = sum(np.prod(l.shape) * l.dtype.itemsize
                    for l in jax.tree.leaves(sc._frozen))
         assert 6.2e9 < base < 6.4e9  # 3,136M parameters in bfloat16
         compiled = sc._round_fn.lower(*args).compile()
     finally:
         sc.close()
+    assert held_bytes(compiled) < 15.75 * 2 ** 30, held_bytes(compiled)
     m = compiled.memory_analysis()
-    held = (m.temp_size_in_bytes + m.argument_size_in_bytes
-            + m.output_size_in_bytes - m.alias_size_in_bytes)
-    assert held < 15.75 * 2 ** 30, held
     assert m.argument_size_in_bytes > 0.25 * 16e9  # the driver's floor
     hlo = compiled.as_text()
     grouped = re.findall(r"ragged-dot\S* = \w+\[(\d+),(\d+)\]\S* custom-call",
@@ -405,3 +420,105 @@ def test_lfm2_cells_round_program_fits_the_chip(one_chip, no_persistent_cache,
     assert grouped and {rows for rows, _ in grouped} == {"65536"}, grouped
     assert "f32[16,128,65536]" in hlo and "f32[16,2048,65536]" not in hlo
     assert "lfm2.conv" in hlo and "moe.shared" not in hlo
+
+
+def unfused(hlo):
+    """The shapes of the instructions an optimized module holds outside
+    its fusions' computations: the arrays that are written to memory."""
+    shapes, inside = [], False
+    for line in hlo.splitlines():
+        if line and not line.startswith(" "):
+            inside = "fused" in line.split("(")[0] or "fusion" in line.split(
+                "(")[0]
+        elif not inside:
+            shapes += re.findall(r"= (\w+\[[\d,]*\])", line)
+    return shapes
+
+
+def test_kimi_cells_programs_fit_the_chip(one_chip, no_persistent_cache,
+                                          monkeypatch):
+    """The WHOLE round and evaluation programs of
+    ``kimi-linear-48b-a3b.dfl2-full-lora-s16384`` at the cell's own sizes
+    (published layers 1 to 8 at the published widths, 2 nodes, 2 steps of
+    one sequence of 16,384, the frozen 4.19 GB base an argument) compile
+    for the described v5e under its 15.75 GiB (the round program at 15.66
+    GiB when the cell came: 12.55 GB of temporaries over 4.27 GB of
+    arguments). The delta rule's diagonal sub-blocks are formed from the
+    pairwise differences inside the fusions of their sums (``kda.diag``,
+    forward and on the way back): the factors of all pairs of a block of
+    heads, ``f32[.., 16, 16, 128]``, are no array of the program; formed
+    a key at a time they were, and the round program did not fit."""
+    sc = abstract_cell("kimi-linear-48b-a3b.dfl2-full-lora-s16384",
+                       monkeypatch)
+    try:
+        round_args = on_device((sc.fed, *sc._data_args, *sc._plan_args(None),
+                                *sc._frozen), one_chip)
+        eval_args = on_device((sc.fed, sc._x_test, sc._y_test, *sc._frozen),
+                              one_chip)
+        programs = {"round": sc._round_fn.lower(*round_args).compile(),
+                    "eval": sc._eval_fn.lower(*eval_args).compile()}
+    finally:
+        sc.close()
+    for name, compiled in programs.items():
+        assert held_bytes(compiled) < 15.75 * 2 ** 30, (name,
+                                                         held_bytes(compiled))
+        # the benchmark's floor: the base alone is over a quarter of the chip
+        assert compiled.memory_analysis().argument_size_in_bytes > 0.25 * 16e9
+    hlo = programs["round"].as_text()
+    assert re.search(r'op_name="[^"]*/kda\.diag/', hlo)
+    assert re.search(r'op_name="[^"]*/kda\.scan/kda\.diag/', hlo)  # way back
+    whole = [s for s in unfused(hlo) if re.search(r"\[[\d,]*,16,16,128\]", s)]
+    assert not whole, whole
+    assert "mla.attn" in hlo and "moe.shared" in hlo
+
+
+def test_kimi_cells_reference_fits_the_chip(one_chip, no_persistent_cache,
+                                            monkeypatch):
+    """The plain reference's epoch of ``kimi-linear-48b-a3b.dfl2-full-lora-
+    s16384`` (``benchmark/reference/kimi_linear.py`` through
+    ``reference/federation.py``, the two nodes one after the other, the
+    4.19 GB base an argument) compiles for the described v5e: with each
+    KDA layer's recurrence inputs kept for the way back (1.3 GB a layer at
+    16,384 positions) it needed 18.85 GiB and every run of the cell failed
+    there, after its window (``PERF.md`` Findings, Kimi Linear). Each
+    layer recomputed whole on the way back left it at 16.41 GiB with the
+    base it holds beside its temporaries; with a KDA layer's inputs formed
+    once more inside that, 15.21 GiB; 15.30 GiB with attention's queries
+    in four groups of key prefixes (17.72 GiB in eight)."""
+    import pathlib
+
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as PS
+
+    home = pathlib.Path(__file__).resolve().parent.parent / "benchmark"
+    monkeypatch.syspath_prepend(str(home))
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("benchmark_run_ref",
+                                                  home / "run.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    from reference import federation
+
+    cell = bench.Cell("kimi-linear-48b-a3b.dfl2-full-lora-s16384", False)
+    ref = cell.reference_model()
+    ref.configure(cell.config["scenario"]["model"]["kwargs"],
+                  cell.config["scenario"]["lora"])
+    monkeypatch.setattr(federation.jax, "devices",
+                        lambda *a: [one_chip.device_set.pop()])
+    fed = federation.Federation(ref, cell.reference_spec(), chips=1)
+    n, T = 2, 16384
+    at = lambda spec_: NamedSharding(fed.mesh, spec_)
+    sds = lambda shape, dtype, spec_: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=at(spec_))
+    P = {k: sds((n,) + v, jnp.float32, PS("d")) for k, v in ref.SHAPES.items()}
+    frozen = {k: sds(v, jnp.bfloat16, PS())
+              for k, v in ref.FROZEN_SHAPES.items()}
+    rows = lambda dtype: sds((n, 2, T), dtype, PS("d"))
+    compiled = fed._epoch.lower(
+        P, {"m": P, "v": P, "t": sds((n,), jnp.float32, PS("d"))},
+        sds((n, 2), jnp.uint32, PS("d")), rows(jnp.int32), rows(jnp.int32),
+        sds((n, 2), jnp.bool_, PS("d")), frozen).compile()
+    # the base is an argument and is not donated: it is held beside the
+    # temporaries, and what the chip refuses is the sum
+    assert held_bytes(compiled) < 15.75 * 2 ** 30, held_bytes(compiled)

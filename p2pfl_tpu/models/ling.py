@@ -40,9 +40,10 @@ over ``held_experts`` (the router is an argument), ``RMSNorm``,
 ``DenseFFN``, ``CausalLM`` (the embedding, the head, the chunked loss,
 the counters), ``causal_conv``; Kimi Linear's also ``head_blocks``,
 ``kda_heads`` (its ``floor`` chooses the form: Ling's gate is bounded
-below, Kimi Linear's is not) and ``MLAMixer`` (without its rotary
-embedding, q/k norms and gate). A change to one of them is measured in
-every cell that uses it.
+below, Kimi Linear's is not; on the TPU the unbounded form's diagonal
+sub-blocks are the kernels of ``ops/kda_diag.py``) and ``MLAMixer``
+(without its rotary embedding, q/k norms and gate). A change to one of
+them is measured in every cell that uses it.
 """
 
 from __future__ import annotations
@@ -56,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from p2pfl_tpu.models.base import register_lora_targets, register_model
+from p2pfl_tpu.ops import kda_diag
 from p2pfl_tpu.ops.flash_attention import flash_attention, padded, visits
 from p2pfl_tpu.ops.fold import fold_rows
 
@@ -240,20 +242,46 @@ def _decays(G, sub):
                              -jnp.inf))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _diag_pairs(q, k, G, sub):
+_diag_forms: dict[str, Any] = {}
+
+
+def diag_forms() -> dict[str, Any]:
+    """The last :func:`_diag_pairs` traced: ``{"kernel": whether the
+    Pallas kernels of ``ops/kda_diag.py`` form the blocks, "sub": the
+    sub-chunk's rows, "chunk": the chunk's}``; empty where none was.
+    Recorded at trace time, as :func:`score_tiles` is."""
+    return dict(_diag_forms)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _diag_pairs(q, k, G, sub, kernel=False):
     """The diagonal ``sub x sub`` blocks of :func:`kda_heads`' two
     intra-chunk matrices without ``b``, from the pairwise differences of
     the running sums: ``[.., C / sub, sub (i), sub (j)]`` of ``sum_c k_ic
     k_jc e^(G_ic - G_jc)`` and of ``sum_c q_ic k_jc e^(G_ic - G_jc)`` for
-    ``j <= i``, 0 above the diagonal; ``q, k, G`` [.., C, K] float32. Each
-    is one sum over ``K`` of the factors (:func:`_decays`), and the way
-    back, which keeps the inputs alone, forms them again inside each of
-    its sums: ``sub`` times ``q``'s size as a whole array, they never are
-    one (formed a key at a time instead, XLA kept the 16 keys' factors of
-    a block of heads for the way back, 3.1 GB more than the round program
-    of the chip's share had, ``PERF.md`` Findings, Kimi Linear)."""
-    return _diag_pairs_fwd(q, k, G, sub)[0]
+    ``j <= i``, 0 above the diagonal; ``q, k, G`` [.., C, K] float32. The
+    way back keeps the inputs alone and forms the factors again. By one
+    of two forms, as ``kernel`` (static, the platform's: :func:`on_tpu`)
+    says:
+
+    - the Pallas kernels of ``ops/kda_diag.py``, one a direction: a
+      sub-chunk's factors are formed once in VMEM and every sum of the
+      direction is taken from them;
+    - the XLA form (the CPU's, and the kernels' parity oracle):
+      :func:`_diag_pairs_fwd`, one sum over ``K`` of the factors
+      (:func:`_decays`) a block, and :func:`_diag_pairs_back`, which forms
+      them again inside each of its five sums. ``sub`` times ``q``'s size
+      as a whole array, they never are one (formed a key at a time
+      instead, XLA kept the 16 keys' factors of a block of heads for the
+      way back, 3.1 GB more than the round program of the chip's share
+      had, ``PERF.md`` Findings, Kimi Linear)."""
+    return _diag_pairs_kept(q, k, G, sub, kernel)[0]
+
+
+def _diag_pairs_kept(q, k, G, sub, kernel):
+    if kernel:
+        return kda_diag.forward(q, k, G, sub=sub), (q, k, G)
+    return _diag_pairs_fwd(q, k, G, sub)
 
 
 def _diag_pairs_fwd(q, k, G, sub):
@@ -264,28 +292,34 @@ def _diag_pairs_fwd(q, k, G, sub):
             jnp.sum(qs[..., :, None, :] * kj, axis=-1)), (q, k, G)
 
 
-def _diag_pairs_back(sub, kept, grads):
+def _diag_pairs_grads(sub, kernel, kept, grads):
     # a hand-written way back does not inherit the name stack of the way
     # forward: the scopes the device time is read by are set here
     with jax.named_scope("kda.scan"), jax.named_scope("kda.diag"):
-        qs, ks, Gs = (_sub_rows(a, sub) for a in kept)
-        d_a, d_b = (d[..., None] for d in grads)  # [.., i, j, 1]
-        e = _decays(Gs, sub)
-        kj = ks[..., None, :, :]
-        # the products' gradients to ``k_i`` and ``q_i`` (rows), to the
-        # factor ``k_j e_ij`` (``w``), and through ``e_ij`` to ``G_i`` and
-        # ``G_j``
-        dk = jnp.sum(d_a * kj * e, axis=-2)
-        dq = jnp.sum(d_b * kj * e, axis=-2)
-        w = (d_a * ks[..., :, None, :] + d_b * qs[..., :, None, :]) * e
-        dk = dk + jnp.sum(w, axis=-3)
-        t = w * kj
-        dG = jnp.sum(t, axis=-2) - jnp.sum(t, axis=-3)
-        whole = lambda a: a.reshape(kept[0].shape)
-        return whole(dq), whole(dk), whole(dG)
+        if kernel:
+            return kda_diag.backward(*kept, *grads, sub=sub)
+        return _diag_pairs_back(sub, kept, grads)
 
 
-_diag_pairs.defvjp(_diag_pairs_fwd, _diag_pairs_back)
+def _diag_pairs_back(sub, kept, grads):
+    qs, ks, Gs = (_sub_rows(a, sub) for a in kept)
+    d_a, d_b = (d[..., None] for d in grads)  # [.., i, j, 1]
+    e = _decays(Gs, sub)
+    kj = ks[..., None, :, :]
+    # the products' gradients to ``k_i`` and ``q_i`` (rows), to the
+    # factor ``k_j e_ij`` (``w``), and through ``e_ij`` to ``G_i`` and
+    # ``G_j``
+    dk = jnp.sum(d_a * kj * e, axis=-2)
+    dq = jnp.sum(d_b * kj * e, axis=-2)
+    w = (d_a * ks[..., :, None, :] + d_b * qs[..., :, None, :]) * e
+    dk = dk + jnp.sum(w, axis=-3)
+    t = w * kj
+    dG = jnp.sum(t, axis=-2) - jnp.sum(t, axis=-3)
+    whole = lambda a: a.reshape(kept[0].shape)
+    return whole(dq), whole(dk), whole(dG)
+
+
+_diag_pairs.defvjp(_diag_pairs_kept, _diag_pairs_grads)
 
 
 def kda_chunked(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
@@ -329,8 +363,10 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
       gate, unbounded below): the diagonal ``sub x sub`` blocks from the
       pairwise differences ``e^(G_i - G_j)``, ``j <= i``, which never
       exceed 1, float32 (:func:`_diag_pairs`, scope ``kda.diag``): exact
-      for any finite decay; the ``[.., sub, sub, K]`` factors exist only
-      inside the fusions of the sums over them, never as an array.
+      for any finite decay; the ``[.., sub, sub, K]`` factors are never
+      an array: on the TPU the kernels of ``ops/kda_diag.py`` form them
+      in VMEM, elsewhere they exist only inside the fusions of the sums
+      over them.
 
     The system is solved by forming ``(I + A)^-1`` block by block and one
     product with the right-hand side (:func:`unit_lower_solve`; ``chunk``
@@ -356,8 +392,10 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
     lo = lambda a: a.astype(dtype)
     bounded = floor is not None and sub * -floor <= CLIP
     if not bounded:
+        kernel = on_tpu()
+        _diag_forms.update(kernel=kernel, sub=sub, chunk=C)
         with jax.named_scope("kda.diag"):
-            diag_a, diag_b = _diag_pairs(q, k, G, sub)
+            diag_a, diag_b = _diag_pairs(q, k, G, sub, kernel)
 
     # ---- the two intra-chunk matrices, a sub-chunk of rows at a time
     rows_a, rows_b = [], []

@@ -8,6 +8,7 @@ block of queries at a time, a loop over experts), which imports nothing
 of the program. float32 compute here, so that a wrong term shows and
 rounding does not."""
 
+import functools
 import hashlib
 import importlib.util
 import json
@@ -20,6 +21,7 @@ import pytest
 
 from p2pfl_tpu.learning.lora import LoraModel, wrap_model
 from p2pfl_tpu.models import get_model, kimi_linear, ling
+from p2pfl_tpu.ops import kda_diag
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 CONFIG = json.loads(
@@ -195,6 +197,71 @@ def test_the_diagonals_way_back_is_reverse_mode_through_its_forward():
     for x, y in zip(jax.grad(loss(fast), argnums=(0, 1, 2))(q, k, G),
                     jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, G)):
         close(x, y, 1e-5)
+
+
+def interpreted(monkeypatch):
+    """The chip's path of ``kda_heads`` on the CPU: ``on_tpu`` true and the
+    kernels of ``ops/kda_diag.py`` through Pallas's interpreter."""
+    monkeypatch.setattr(ling, "on_tpu", lambda: True)
+    for name in ("forward", "backward"):
+        monkeypatch.setattr(kda_diag, name, functools.partial(
+            getattr(kda_diag, name), interpret=True))
+
+
+@pytest.mark.parametrize("deepest, lead", [(40.0, (2, 2, 3)),
+                                           (0.5, (2, 4, 20))])
+def test_the_diagonal_kernels_are_the_xla_form(deepest, lead):
+    """``ops/kda_diag.py``'s two kernels (interpreted) against the XLA form
+    they replace on the chip, ``_diag_pairs_fwd`` and ``_diag_pairs_back``,
+    at 128 channels: the blocks, 0 above the diagonal, and the gradients
+    to ``q``, ``k`` and ``G``, for decays down to -40 a step (past what a
+    product of ``e^G`` and ``e^-G`` holds) and shallow ones, over 12
+    (chunk, batch, head) triples (one grid step of 128, padded) and 160
+    (two)."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    shape = lead + (64, 128)  # [chunks, batch, heads, C, K]
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], shape)) * 128 ** -0.5
+    k = unit(jax.random.normal(ks[1], shape))
+    G = jnp.cumsum(-deepest * jax.random.uniform(ks[2], shape), axis=-2)
+    (want_a, want_b), kept = ling._diag_pairs_fwd(q, k, G, 16)
+    a, b = kda_diag.forward(q, k, G, sub=16, interpret=True)
+    assert a.shape == want_a.shape == lead + (4, 16, 16)
+    close(a, want_a, 1e-6)
+    close(b, want_b, 1e-6)
+    assert not np.triu(np.asarray(a), 1).any()
+    assert not np.triu(np.asarray(b), 1).any()
+    da, db = (jax.random.normal(x, a.shape) for x in ks[3:])
+    got = kda_diag.backward(q, k, G, da, db, sub=16, interpret=True)
+    for x, y in zip(got, ling._diag_pairs_back(16, kept, (da, db))):
+        assert np.isfinite(np.asarray(x)).all()
+        close(x, y, 1e-6)
+
+
+@pytest.mark.parametrize("T", [100, 7])
+def test_unbounded_kda_through_the_kernels_is_the_recurrence(ref, T,
+                                                             monkeypatch):
+    """The chip's path of the form for an unbounded decay (the diagonal
+    blocks by the kernels, interpreted) is the recurrence, value and every
+    gradient, at 128 channels and decays down to -40 a step, and the
+    trace-time record says which form ran; the CPU's path says the XLA
+    form did."""
+    args = kda_inputs(T, K=128)
+    weigh = jax.random.normal(jax.random.PRNGKey(9), (2, T, 2, 128))
+    ling.kda_chunked(*args, dtype=F32)
+    assert ling.diag_forms() == {"kernel": False, "sub": 16, "chunk": 64}
+    interpreted(monkeypatch)
+    got = ling.kda_chunked(*args, dtype=F32)
+    assert ling.diag_forms() == {"kernel": True, "sub": 16, "chunk": 64}
+    np.testing.assert_allclose(got, ref.kda_recurrence(*args), rtol=2e-4,
+                               atol=2e-5)
+    d_got = jax.grad(lambda *a: jnp.sum(ling.kda_chunked(*a, dtype=F32)
+                                        * weigh), argnums=(0, 1, 2, 3, 4))(*args)
+    d_want = jax.grad(lambda *a: jnp.sum(ref.kda_recurrence(*a) * weigh),
+                      argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(d_got, d_want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4)
 
 
 def test_lings_bounded_path_is_the_parents_to_the_bit():

@@ -443,11 +443,15 @@ def test_kimi_cells_programs_fit_the_chip(one_chip, no_persistent_cache,
     one sequence of 16,384, the frozen 4.19 GB base an argument) compile
     for the described v5e under its 15.75 GiB (the round program at 15.66
     GiB when the cell came: 12.55 GB of temporaries over 4.27 GB of
-    arguments). The delta rule's diagonal sub-blocks are formed from the
-    pairwise differences inside the fusions of their sums (``kda.diag``,
-    forward and on the way back): the factors of all pairs of a block of
-    heads, ``f32[.., 16, 16, 128]``, are no array of the program; formed
-    a key at a time they were, and the round program did not fit."""
+    arguments). The delta rule's diagonal sub-blocks are formed by the
+    Pallas kernels of ``ops/kda_diag.py``, whose custom calls bear the
+    scope the device time is read by: ``kda.scan/kda.diag`` on the way
+    forward (three a KDA layer and step: the folded forward, the layer's
+    recomputation and the head block's) and under ``transpose(jvp(..))``
+    on the way back (one a KDA layer), where the hand-written rule sets
+    it. The factors of all pairs of a block of heads, ``f32[.., 16, 16,
+    128]``, are no array of the program; formed a key at a time they
+    were, and the round program did not fit."""
     sc = abstract_cell("kimi-linear-48b-a3b.dfl2-full-lora-s16384",
                        monkeypatch)
     try:
@@ -465,8 +469,14 @@ def test_kimi_cells_programs_fit_the_chip(one_chip, no_persistent_cache,
         # the benchmark's floor: the base alone is over a quarter of the chip
         assert compiled.memory_analysis().argument_size_in_bytes > 0.25 * 16e9
     hlo = programs["round"].as_text()
-    assert re.search(r'op_name="[^"]*/kda\.diag/', hlo)
-    assert re.search(r'op_name="[^"]*/kda\.scan/kda\.diag/', hlo)  # way back
+    calls = {"kda_diag_fwd": [], "kda_diag_bwd": []}
+    for kernel, name in re.findall(r'%(kda_diag_fwd|kda_diag_bwd)\S* = .* '
+                                   r'custom-call\(.*op_name="([^"]*)"', hlo):
+        calls[kernel].append(name)
+    assert len(calls["kda_diag_fwd"]) == 18 and len(calls["kda_diag_bwd"]) == 6
+    assert all("/kda.scan/kda.diag/" in n for n in calls["kda_diag_fwd"])
+    assert all("transpose(jvp(" in n and "/kda.scan/kda.diag/" in n
+               for n in calls["kda_diag_bwd"])  # the way back
     whole = [s for s in unfused(hlo) if re.search(r"\[[\d,]*,16,16,128\]", s)]
     assert not whole, whole
     assert "mla.attn" in hlo and "moe.shared" in hlo

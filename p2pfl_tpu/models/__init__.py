@@ -7,8 +7,9 @@ stretch config in BASELINE.json and four sparse-expert language models
 as frozen bases under adapters: Ling-3.0-flash (linear and latent
 attention), Laguna-S-2.1 (window and full grouped-query attention),
 LFM2-8B-A1B (gated short convolutions among grouped-query attention, a
-tied head) and Kimi-Linear-48B-A3B (Kimi Delta Attention with an
-unbounded decay among NoPE latent attention).
+tied head), Kimi-Linear-48B-A3B (Kimi Delta Attention with an
+unbounded decay among NoPE latent attention) and Qwen3-Next-80B-A3B
+(Gated DeltaNet with a decay a head among gated attention).
 
 TPU-first design notes:
 - Normalization is **GroupNorm**, not BatchNorm: batch statistics are
@@ -31,6 +32,7 @@ from p2pfl_tpu.models.ling import LingLM
 from p2pfl_tpu.models.laguna import LagunaLM
 from p2pfl_tpu.models.lfm2 import Lfm2LM
 from p2pfl_tpu.models.kimi_linear import KimiLinearLM
+from p2pfl_tpu.models.qwen3_next import Qwen3NextLM
 
 __all__ = [
     "get_model",
@@ -53,4 +55,5 @@ __all__ = [
     "LagunaLM",
     "Lfm2LM",
     "KimiLinearLM",
+    "Qwen3NextLM",
 ]

@@ -31,8 +31,9 @@ up-projection again and goes back by hand in three grouped products
 (``_block_back``), the down-projection's output never formed a second
 time: a training step executes 8/3 of a forward's expert FLOPs.
 
-``models/laguna.py``, ``models/lfm2.py`` and ``models/kimi_linear.py``
-build their models from the parts here that are not Ling's alone:
+``models/laguna.py``, ``models/lfm2.py``, ``models/kimi_linear.py`` and
+``models/qwen3_next.py`` build their models from the parts here that are
+not Ling's alone:
 ``causal_attention`` (its window, its grouped query heads and its scope
 are arguments; on the TPU it runs the fused kernels of
 ``ops/flash_attention.py``, elsewhere its own tiled form), ``ExpertFFN``
@@ -42,7 +43,10 @@ the counters), ``causal_conv``; Kimi Linear's also ``head_blocks``,
 ``kda_heads`` (its ``floor`` chooses the form: Ling's gate is bounded
 below, Kimi Linear's is not; on the TPU the unbounded form's diagonal
 sub-blocks are the kernels of ``ops/kda_diag.py``) and ``MLAMixer``
-(without its rotary embedding, q/k norms and gate). A change to one of
+(without its rotary embedding, q/k norms and gate); Qwen3-Next's
+``head_blocks`` and ``kda_heads`` with a decay a head (its scalar form,
+key heads fewer than value heads), ``causal_attention`` at heads of 256
+and ``ExpertFFN`` with a gate on its shared expert. A change to one of
 them is measured in every cell that uses it.
 """
 
@@ -186,8 +190,8 @@ def unit_lower_inverse(A):
     return jnp.moveaxis(T[0], -1, 0).reshape(A.shape)
 
 
-@jax.custom_vjp
-def unit_lower_solve(A, rhs):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def unit_lower_solve(A, rhs, scope="kda.scan"):
     """``X`` of ``(I + A) X = rhs`` for strictly lower-triangular ``A``
     [..., C, C] and ``rhs`` [..., C, W], float32: the inverse
     (:func:`unit_lower_inverse`), then one product at ``HIGHEST``.
@@ -197,22 +201,23 @@ def unit_lower_solve(A, rhs):
     pass over each in HBM that computes nothing. The way back keeps the
     inverse and every ``X`` and is two more such products a right-hand
     side: ``d_rhs = T^T g``, ``d_A = -sum d_rhs X^T`` below the
-    diagonal."""
-    return _solve(A, rhs)[0]
+    diagonal. ``scope`` is the one the caller opens around the delta
+    rule, which the hand-written way back sets by hand."""
+    return _solve(A, rhs, scope)[0]
 
 
-def _solve(A, rhs):
+def _solve(A, rhs, scope):
     with jax.named_scope("kda.solve"):
         T = unit_lower_inverse(A)
         X = jax.tree.map(lambda r: jnp.matmul(T, r, precision=HI), rhs)
     return X, (T, X)
 
 
-def _solve_back(kept, g):
+def _solve_back(scope, kept, g):
     T, X = kept
     # a hand-written way back does not inherit the name stack of the way
     # forward: the scopes the device time is read by are set here
-    with jax.named_scope("kda.scan"), jax.named_scope("kda.solve"):
+    with jax.named_scope(scope), jax.named_scope("kda.solve"):
         d_rhs = jax.tree.map(lambda g_: jnp.einsum(
             "...ji,...jw->...iw", T, g_, precision=HI), g)
         d_A = -jnp.tril(sum(
@@ -242,15 +247,34 @@ def _decays(G, sub):
                              -jnp.inf))
 
 
-_diag_forms: dict[str, Any] = {}
+#: what :func:`kda_heads` traced, by the caller's scope (the one record):
+#: ``{"forms": {"scalar" | "channel": traces}, "chunk", "sub", "diag"}``,
+#: the last three the last trace's, ``diag`` how its diagonal sub-blocks
+#: were formed (``"kernel"``, ``"xla"``, or None where the form has none);
+#: the scope traced last is the record's last
+_chunk_forms: dict[str, dict[str, Any]] = {}
+
+
+def chunk_forms() -> dict[str, dict[str, Any]]:
+    """The intra-chunk forms :func:`kda_heads` traced, by the caller's
+    scope: how many traces took each form (a decay a head: ``scalar``; a
+    channel: ``channel``) since the process started, and the last trace's
+    chunk, sub-chunk and diagonal blocks. Recorded at trace time, as
+    :func:`score_tiles` is."""
+    return {scope: dict(rec, forms=dict(rec["forms"]))
+            for scope, rec in _chunk_forms.items()}
 
 
 def diag_forms() -> dict[str, Any]:
-    """The last :func:`_diag_pairs` traced: ``{"kernel": whether the
-    Pallas kernels of ``ops/kda_diag.py`` form the blocks, "sub": the
-    sub-chunk's rows, "chunk": the chunk's}``; empty where none was.
-    Recorded at trace time, as :func:`score_tiles` is."""
-    return dict(_diag_forms)
+    """The last :func:`_diag_pairs` traced, from :func:`chunk_forms`:
+    ``{"kernel": whether the Pallas kernels of ``ops/kda_diag.py`` form
+    the blocks, "sub": the sub-chunk's rows, "chunk": the chunk's}``;
+    empty where none was."""
+    for rec in reversed(_chunk_forms.values()):
+        if rec["diag"]:
+            return {"kernel": rec["diag"] == "kernel", "sub": rec["sub"],
+                    "chunk": rec["chunk"]}
+    return {}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -332,19 +356,30 @@ def kda_chunked(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
 
 
 def kda_heads(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
-              floor=None):
-    """The delta rule with a decay per channel, a chunk at a time.
+              floor=None, scope="kda.scan"):
+    """The delta rule with a decay per channel or per head, a chunk at a
+    time.
 
-    ``q, k`` [B, T, H, K] (normalised, q scaled), ``v`` [B, T, H, V],
-    ``g`` [B, T, H, K] the log-decay (finite, ``<= 0``), ``beta`` [B, T,
-    H]. Per head: ``S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_{t-1} +
-    b_t k_t v_t^T``, ``o_t = S_t^T q_t``. Within a chunk, with ``G`` the
-    running sum of ``g`` and ``S_0`` the state the chunk starts from, the
-    updates ``u_i = b_i (v_i - k_i^T Diag(a_i) S_{i-1})`` solve the unit
-    lower-triangular system ``(I + A) U = b (V - (K e^G) S_0)``, ``A_ij =
-    b_i sum_c k_ic k_jc e^(G_ic - G_jc)`` for ``j < i``; then ``O = (Q
-    e^G) S_0 + B U`` with ``B_ij = sum_c q_ic k_jc e^(G_ic - G_jc)`` for
-    ``j <= i``, and ``S_C = e^(G_C) S_0 + (K e^(G_C - G))^T U``.
+    ``q, k`` [B, T, Hk, K] (normalised, q scaled), ``v`` [B, T, H, V],
+    ``g`` [B, T, H, K] or [B, T, H] the log-decay (finite, ``<= 0``),
+    ``beta`` [B, T, H]. Value head ``h`` reads key head ``h // (H /
+    Hk)``; with a decay a channel ``Hk`` is ``H``. Per head: ``S_t = (I -
+    b_t k_t k_t^T) Diag(exp g_t) S_{t-1} + b_t k_t v_t^T``, ``o_t = S_t^T
+    q_t``. Within a chunk, with ``G`` the running sum of ``g`` and ``S_0``
+    the state the chunk starts from, the updates ``u_i = b_i (v_i -
+    k_i^T Diag(a_i) S_{i-1})`` solve the unit lower-triangular system
+    ``(I + A) U = b (V - (K e^G) S_0)``, ``A_ij = b_i sum_c k_ic k_jc
+    e^(G_ic - G_jc)`` for ``j < i``; then ``O = (Q e^G) S_0 + B U`` with
+    ``B_ij = sum_c q_ic k_jc e^(G_ic - G_jc)`` for ``j <= i``, and ``S_C
+    = e^(G_C) S_0 + (K e^(G_C - G))^T U``.
+
+    A decay with no channel axis (one number a value head and position,
+    Gated DeltaNet's) takes the scalar form (:func:`_scalar_pairs`):
+    ``e^(G_i - G_j)`` leaves the sum over channels, so ``A`` and ``B`` are
+    ``K K^T`` and ``Q K^T``, one product a KEY head over the whole chunk,
+    times the ``[C, C]`` matrix of pairwise decays of each value head;
+    every exponent ``<= 0``, exact for any finite decay, no sub-chunks.
+    The per-channel forms follow.
 
     ``e^(G_i - G_j)`` is never formed from ``e^(-G_j)`` alone, which
     overflows within a chunk: rows are taken a sub-chunk of ``sub`` at a
@@ -374,9 +409,20 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
     ``b_i`` < 1 they reach binomial size over 64 rows and cancel in
     float32. The state, the running sums, the exponentials and the solve
     are float32 whatever ``dtype`` is; the other products take ``dtype``
-    operands and accumulate in float32."""
-    B, T, H, K = q.shape
+    operands and accumulate in float32. ``scope`` is the one the caller
+    opens around this call (the solve's hand-written way back sets it);
+    the form each call traced is recorded by it (:func:`chunk_forms`)."""
+    B, T = q.shape[:2]
     C = chunk
+    scalar = g.ndim == 3
+    bounded = floor is not None and sub * -floor <= CLIP
+    kernel = on_tpu()
+    record = _chunk_forms.pop(scope, {"forms": {}})
+    form = "scalar" if scalar else "channel"
+    record["forms"][form] = record["forms"].get(form, 0) + 1
+    record.update(chunk=C, sub=sub, diag=None if scalar or bounded
+                  else "kernel" if kernel else "xla")
+    _chunk_forms[scope] = record
     pad = -T % C
     if pad:  # zero keys, no decay, beta 0: the state passes unchanged
         longer = lambda a: jnp.pad(
@@ -390,10 +436,15 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
     G = jnp.cumsum(g, axis=3)
     mm = functools.partial(jnp.einsum, preferred_element_type=F32)
     lo = lambda a: a.astype(dtype)
-    bounded = floor is not None and sub * -floor <= CLIP
+    if scalar:
+        A, Bm = _scalar_pairs(q, k, G, beta, lo, mm)
+        # what follows is elementwise a value head: each reads its key
+        # head, and the decay is one column
+        q, k = (jnp.repeat(a, v.shape[2] // a.shape[2], axis=2)
+                for a in (q, k))
+        return _across_chunks(q, k, v, G[..., None], beta, A, Bm, lo, mm,
+                              scope)[:, :T]
     if not bounded:
-        kernel = on_tpu()
-        _diag_forms.update(kernel=kernel, sub=sub, chunk=C)
         with jax.named_scope("kda.diag"):
             diag_a, diag_b = _diag_pairs(q, k, G, sub, kernel)
 
@@ -424,14 +475,43 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
     i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
     A = jnp.where(j < i, jnp.concatenate(rows_a, axis=3), 0.0) * beta[..., None]
     Bm = jnp.where(j <= i, jnp.concatenate(rows_b, axis=3), 0.0)
+    return _across_chunks(q, k, v, G, beta, A, Bm, lo, mm, scope)[:, :T]
 
+
+def _scalar_pairs(q, k, G, beta, lo, mm):
+    """:func:`kda_heads`' two intra-chunk matrices for a decay that is
+    one number a value head: ``q, k`` [NC, B, Hk, C, K], ``G``, ``beta``
+    [NC, B, H, C]; returns ``A`` and ``B`` [NC, B, H, C, C]. ``K K^T``
+    and ``Q K^T`` are one product a key head, whole chunks on the matrix
+    unit; the pairwise decays ``e^(G_i - G_j)``, ``j <= i``, are one
+    ``[C, C]`` matrix a value head, which never exceeds 1."""
+    NC, B, Hk, C, _ = q.shape
+    H = G.shape[2]
+    by_key = lambda a: a.reshape((NC, B, Hk, H // Hk) + a.shape[3:])
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    Gk = by_key(G)
+    D = jnp.exp(jnp.where(j <= i, Gk[..., :, None] - Gk[..., None, :],
+                          -jnp.inf))
+    kk, qk = (mm("nbhik,nbhjk->nbhij", lo(x), lo(k))[:, :, :, None]
+              for x in (k, q))
+    A = jnp.where(j < i, kk * D, 0.0) * by_key(beta)[..., None]
+    whole = lambda m: m.reshape((NC, B, H, C, C))
+    return whole(A), whole(qk * D)
+
+
+def _across_chunks(q, k, v, G, beta, A, Bm, lo, mm, scope):
+    """The rest of :func:`kda_heads`, every array a value head's: the
+    solve within each chunk and the recurrence over chunks. ``G`` is the
+    running log-decay [NC, B, H, C, K], or [.., C, 1] a head; returns
+    [B, NC x C, H, V]."""
+    NC, B, H, C, K = k.shape
     # ---- (I + A) Wv = beta V, (I + A) Wk = beta K e^G
     Wv, Wk = unit_lower_solve(
-        A, (v * beta[..., None], k * jnp.exp(G) * beta[..., None]))
+        A, (v * beta[..., None], k * jnp.exp(G) * beta[..., None]), scope)
     Qp = q * jnp.exp(G)
     G_end = G[..., -1:, :]
     Kd = k * jnp.exp(G_end - G)
-    decay = jnp.exp(G_end[..., 0, :])  # [NC, B, H, K]
+    decay = jnp.exp(G_end[..., 0, :])  # [NC, B, H, K], or [.., 1]
 
     # ---- the recurrence over chunks
     def step(S, xs):
@@ -445,8 +525,7 @@ def kda_heads(q, k, v, g, beta, chunk=64, sub=SUB, dtype=jnp.bfloat16,
     _, O = jax.lax.scan(step, jnp.zeros((B, H, K, v.shape[-1]), F32),
                         (Wv, Wk, Qp, Bm, Kd, decay))
     # [NC, B, H, C, V] -> [B, T, H, V]: the one transposing copy out
-    O = jnp.moveaxis(O, (0, 2), (1, 3)).reshape(B, NC * C, H, -1)
-    return O[:, :T]
+    return jnp.moveaxis(O, (0, 2), (1, 3)).reshape(B, NC * C, H, -1)
 
 
 def causal_conv(x, taps):
@@ -568,8 +647,11 @@ def kernel_tiles(T, head_dim):
     form, so none keeps it), neither past the power of two at or over
     ``T`` nor under the 128 lanes a tile of scores spans. At heads of 64
     and 128 blocks and tiles of 1024 ran slower; Ling's heads of 192 ran
-    fastest at 1024."""
-    block, tile = (1024, 1024) if head_dim > 128 else (512, 512)
+    fastest at 1024; at Qwen3-Next's heads of 256 (8 query heads a key
+    head, 4 x 8192 positions) 512 x 512 took 62 ms forward and back
+    against 104 at 512 x 1024 and about 72 at 1024 x 1024 (the cell's own
+    trace)."""
+    block, tile = (1024, 1024) if 128 < head_dim <= 192 else (512, 512)
     whole = max(128, 1 << (T - 1).bit_length())
     return min(block, whole), min(tile, whole)
 
@@ -1114,6 +1196,8 @@ class ExpertFFN(nn.Module):
     """The held experts' part of a routed layer plus its one shared
     expert of width ``shared_width``; ``shared_width`` 0 is a layer with
     NO shared expert: no shared projection is made and nothing is added.
+    With ``shared_gate`` the shared expert's output is scaled by
+    ``sigmoid(x w_sg)``, one number a token from a projection of its own.
     ``router`` is the model's own ``(x, frozen) -> (ids, weights)`` over
     ``frozen["router"]`` [d, n_experts]; without one it is :func:`route`
     over ``n_group`` groups, which also holds a seeded selection bias
@@ -1134,6 +1218,7 @@ class ExpertFFN(nn.Module):
     router: Any = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -1168,6 +1253,9 @@ class ExpertFFN(nn.Module):
         with jax.named_scope("moe.shared"):
             shared = _dense(d, "shared_down", self)(swiglu(
                 _dense(2 * self.shared_width, "shared_gate_up", self)(x)))
+            if self.shared_gate:
+                shared = (shared * jax.nn.sigmoid(_dense(
+                    1, "shared_gate", self)(x).astype(F32))).astype(self.dtype)
         return y.reshape(B, T, d) + shared, stats
 
 
@@ -1229,18 +1317,19 @@ class CausalLM(nn.Module):
     holds one matrix, used twice, seeded at a head's scale (``1 /
     sqrt(hidden)`` a row element: with an embedding's 1.0 the seed's
     logits would have a deviation of ``sqrt(hidden)`` and its loss
-    would be a saturated softmax's)."""
+    would be a saturated softmax's). ``norm`` is the final norm's
+    module, made as ``norm(eps, dtype=, param_dtype=)``."""
 
     tie_head: bool = False
 
-    def setup_ends(self):
+    def setup_ends(self, norm=RMSNorm):
         self.embed = nn.Embed(
             self.vocab, self.hidden, dtype=self.dtype,
             param_dtype=self.param_dtype,
             embedding_init=nn.initializers.normal(
                 self.hidden ** -0.5 if self.tie_head else 1.0))
-        self.final_norm = RMSNorm(self.eps, dtype=self.dtype,
-                                  param_dtype=self.param_dtype)
+        self.final_norm = norm(self.eps, dtype=self.dtype,
+                               param_dtype=self.param_dtype)
         if not self.tie_head:
             self.head = self.param(
                 "head", nn.initializers.lecun_normal(),

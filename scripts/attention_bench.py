@@ -2,7 +2,8 @@
 language-model cell's own shapes (``PERF.md`` section 5, "Since PR 44").
 
 For every caller of ``models/ling.py`` ``causal_attention`` (Laguna's
-window and full layers, LFM2's attention, Ling's latent attention): the
+window and full layers, LFM2's attention, Ling's latent attention,
+Qwen3-Next's gated attention at heads of 256): the
 forward pass and the value and gradient to ``q``, ``k`` and ``v``, under
 the nodes' ``vmap`` and inside the caller's scope, of the tiled form
 (block and tile 256) and of the kernel at each candidate ``(block,
@@ -47,6 +48,8 @@ SHAPES = {
     "mla": (8, 4096, 32, 32, 192, 128, None, "mla.attn", jnp.float32,
             [(256, 512), (512, 512), (512, 1024), (1024, 1024),
              (1024, 2048), (2048, 1024)]),
+    "gattn": (4, 8192, 16, 2, 256, 256, None, "gattn.attn", BF16,
+              [(256, 512), (512, 512), (512, 1024), (1024, 1024)]),
 }
 
 

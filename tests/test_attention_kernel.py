@@ -24,8 +24,9 @@ BF16 = jnp.bfloat16
         (256, 2, 2, 192, 128, None, 128, 128),  # MLA's key width apart
         (512, 8, 2, 64, 64, None, 128, 256),  # LFM2's group of 4 at 64
         (700, 4, 2, 128, 128, 200, 128, 256),  # a window, T padded
+        (256, 8, 1, 256, 256, None, 128, 128),  # Qwen3-Next's group of 8 at 256
     ], ids=["group1-pad", "group3-window", "mla-widths", "group4-d64",
-            "window-pad"])
+            "window-pad", "group8-d256"])
 def test_the_kernel_is_the_tiled_form(T, heads, kv_heads, D, Dv, window,
                                       block, tile):
     """Value, output and the gradients to q, k and v, each pass of the
